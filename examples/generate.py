@@ -1,10 +1,16 @@
 """Incremental decoding demo: prefill a prompt, then stream tokens.
 
 The KV cache is sharded over the mesh's seq axis; every step merges shard
-partials with tree attention (arXiv 2408.04093).  Runs on a TPU slice or a
-simulated CPU mesh:
+partials with tree attention (arXiv 2408.04093).  Uses every device jax
+reports and says which at start (platform, device_kind, device count);
+pass --fake-devices 8 for a simulated CPU mesh:
 
   python examples/generate.py --fake-devices 8 --steps 16
+
+The 2^20-token decode shape of docs/hwlogs/results.jsonl (one v5e chip):
+
+  python examples/generate.py --dim 512 --depth 2 --heads 8 --kv-heads 2 \
+      --dim-head 64 --bf16 --use-pallas --max-len 1048576 --prompt-len 4096
 """
 
 from __future__ import annotations
@@ -22,9 +28,25 @@ except ModuleNotFoundError:  # running from a source checkout, any cwd
     )
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run the decoder; returns what it measured (``chip_smoke.py`` drives
+    this in-process): the ``tokens``, ``compile_seconds`` (prefill and
+    decode step, ahead of time), ``prefill_seconds``, the gaps between
+    tokens in seconds and the cache ``.sharding``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--fake-devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="use only the first N devices jax reports "
+                         "(default: all) — 1 runs the one-chip path on a "
+                         "multi-chip host")
+    ap.add_argument("--dim", type=int, default=128)
+    ap.add_argument("--depth", type=int, default=2)
+    ap.add_argument("--heads", type=int, default=4)
+    ap.add_argument("--dim-head", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="grouped-query attention: kv heads (default: "
+                         "heads)")
+    ap.add_argument("--bf16", action="store_true")
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-len", type=int, default=128)
@@ -39,9 +61,6 @@ def main() -> None:
                          "generate() (0 = greedy token-by-token streaming)")
     ap.add_argument("--top-k", type=int, default=None)
     ap.add_argument("--top-p", type=float, default=None)
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent XLA compilation cache directory: "
-                         "repeated runs skip recompiles (utils/benchtime.py)")
     ap.add_argument("--metrics-dir", default=None,
                     help="telemetry: append decode-throughput JSONL rows "
                          "(tok/s, ms/token, prefill length) for "
@@ -50,7 +69,7 @@ def main() -> None:
                     help="span tracing: one span per decoded token plus "
                          "prefill, merged with tools/cluster_timeline.py "
                          "(docs/observability.md §6)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.temperature <= 0.0 and (args.top_k is not None
                                     or args.top_p is not None):
@@ -78,9 +97,9 @@ def main() -> None:
     from ring_attention_tpu import RingTransformer, create_mesh
     from ring_attention_tpu.utils import compat, enable_compile_cache
 
-    if args.compile_cache_dir:
-        # before any jit: every compile from here on lands in the cache
-        enable_compile_cache(args.compile_cache_dir)
+    # before any jit: every compile from here on lands in the cache
+    # (placed by JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache_tpu)
+    enable_compile_cache()
     # CPU dev boxes can't honor donation; the hint is still correct on TPU
     warnings.filterwarnings(
         "ignore", message="Some donated buffers were not usable"
@@ -92,16 +111,23 @@ def main() -> None:
         tracing.configure(args.trace_dir, process=jax.process_index())
     tracer = tracing.get_tracer()
 
-    n_dev = len(jax.devices())
-    mesh = create_mesh(ring_size=n_dev) if n_dev > 1 else None
+    devices = jax.devices()[:args.devices] if args.devices else None
+    n_dev = len(devices or jax.devices())
+    dev0 = jax.devices()[0]
+    print(f"platform={dev0.platform} device_kind={dev0.device_kind!r} "
+          f"devices={n_dev} (of {len(jax.devices())})")
+    mesh = (create_mesh(ring_size=n_dev, devices=devices)
+            if n_dev > 1 else None)
     model = RingTransformer(
-        num_tokens=256, dim=128, depth=2, heads=4, dim_head=32,
+        num_tokens=256, dim=args.dim, depth=args.depth, heads=args.heads,
+        dim_head=args.dim_head, kv_heads=args.kv_heads,
         causal=True, bucket_size=64, mesh=mesh, use_ring=mesh is not None,
         use_pallas=args.use_pallas, quantize_cache=args.q8_cache,
+        dtype=jnp.bfloat16 if args.bf16 else None,
     )
     rng = np.random.default_rng(0)
     prompt = jnp.asarray(rng.integers(0, 256, (1, args.prompt_len)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), prompt)
 
     def log_decode(**fields):
         if args.metrics_dir is None:
@@ -133,28 +159,44 @@ def main() -> None:
                    sampled=True, compile_included=True)
         if args.trace_dir:
             tracing.shutdown()
-        return
+        return {"tokens": toks}
 
-    # prefill once, then jit one decode step and stream
-    with tracer.span("decode/prefill", prompt_len=args.prompt_len):
-        cache = model.apply(params, 1, args.max_len, method=RingTransformer.init_cache)
-        logits, cache = model.apply(params, prompt, cache, method=RingTransformer.prefill)
-
-    # donate the KV cache: each step's updated cache reuses the previous
-    # step's buffers instead of double-allocating the whole cache
+    # prefill and the decode step are each compiled once, ahead of time
+    # (compile seconds are set-up, reported on their own) and DONATE the KV
+    # cache: the updated cache reuses the previous buffers instead of
+    # double-allocating the whole cache
+    cache = model.apply(params, 1, args.max_len, method=RingTransformer.init_cache)
+    cache_shardings = sorted(
+        {str(x.sharding) for x in jax.tree.leaves(cache)})
+    print(f"sharding cache: {'; '.join(cache_shardings)}")
+    t0 = time.perf_counter()
+    prefill = compat.jit(
+        lambda p, t, c: model.apply(p, t, c, method=RingTransformer.prefill),
+        donate_argnums=(2,),
+    ).lower(params, prompt, cache).compile()
+    tok0 = jnp.zeros((1,), jnp.int32)
     step = compat.jit(
         lambda p, tok, c, i: model.apply(
             p, tok, c, i, method=RingTransformer.decode_step
         ),
         donate_argnums=(2,),
-    )
-    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    toks = [int(tok[0])]
+    ).lower(params, tok0, cache, jnp.int32(0)).compile()
+    compile_seconds = time.perf_counter() - t0
+    print(f"compile: {compile_seconds:.1f} s (prefill + decode step, cache "
+          f"{jax.config.jax_compilation_cache_dir})")
+
+    t0 = time.perf_counter()
+    with tracer.span("decode/prefill", prompt_len=args.prompt_len):
+        logits, cache = prefill(params, prompt, cache)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        toks = [int(tok[0])]  # the value fetch waits for the device
+    prefill_seconds = time.perf_counter() - t0
+    print(f"prefill: {args.prompt_len} tokens in {prefill_seconds:.3f} s")
     # per-token latency distribution: each iteration is a traced span
     # AND a histogram sample (the `int(tok[0])` conversion syncs on the
-    # device, so the span covers the real token latency, first-token
-    # compile included in sample 0)
+    # device, so the span covers the real token latency)
     hist = tracing.LatencyHistogram()
+    gaps = []
     t0 = time.perf_counter()
     for i in range(args.steps - 1):
         ts = time.perf_counter()
@@ -163,7 +205,8 @@ def main() -> None:
                                  jnp.int32(args.prompt_len + i))
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             toks.append(int(tok[0]))
-        hist.record(time.perf_counter() - ts)
+        gaps.append(time.perf_counter() - ts)
+        hist.record(gaps[-1])
     dt = time.perf_counter() - t0
     print(f"devices={n_dev}  generated {len(toks)} tokens "
           f"({(len(toks) - 1) / dt:.1f} tok/s after prefill)")
@@ -183,6 +226,9 @@ def main() -> None:
                    sampled=False, compile_included=False)
     if args.trace_dir:
         tracing.shutdown()
+    return {"tokens": toks, "compile_seconds": compile_seconds,
+            "prefill_seconds": prefill_seconds, "token_gaps": gaps,
+            "cache_shardings": cache_shardings}
 
 
 if __name__ == "__main__":

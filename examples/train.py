@@ -1,10 +1,16 @@
 """Minimal end-to-end training example: striped ring attention on a mesh.
 
-Runs anywhere: on a TPU slice this uses every chip (data x ring mesh); on a
-CPU dev box pass --fake-devices 8 to simulate the mesh.  Trains a small
+Uses every device jax reports (data x ring mesh) and says which at start:
+the first line printed names platform, device_kind and device count.  On
+a CPU dev box pass --fake-devices 8 to simulate the mesh.  Trains a
 char-level model on synthetic data and prints loss + throughput.
 
   python examples/train.py --fake-devices 8 --steps 20
+
+The flagship model every on-chip number came from (one TPU v5e chip):
+
+  python examples/train.py --dim 512 --depth 2 --heads 8 --dim-head 64 \
+      --bf16 --use-pallas --remat-policy save_attn --batch 1 --seq-len 262144
 """
 
 from __future__ import annotations
@@ -13,6 +19,7 @@ import argparse
 import contextlib
 import os
 import sys
+import time
 import warnings
 
 try:  # prefer the installed package (pip install -e .)
@@ -23,7 +30,12 @@ except ModuleNotFoundError:  # running from a source checkout, any cwd
     )
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run the trainer; returns what it measured (``chip_smoke.py`` drives
+    this in-process): ``losses`` at every logged step, ``compile_seconds``
+    of the train step, the per-step seconds by ``block_until_ready`` and by
+    value fetch, and the ``.sharding`` of params, optimizer state and
+    batch."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--fake-devices", type=int, default=0,
                     help="simulate N CPU devices (for dev boxes)")
@@ -32,7 +44,17 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=512)
     ap.add_argument("--dim", type=int, default=128)
     ap.add_argument("--depth", type=int, default=2)
+    ap.add_argument("--heads", type=int, default=4)
+    ap.add_argument("--dim-head", type=int, default=None,
+                    help="head width (default: dim // heads)")
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="grouped-query attention: kv heads (default: "
+                         "heads)")
     ap.add_argument("--ring-size", type=int, default=None)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="use only the first N devices jax reports "
+                         "(default: all) — 1 runs the one-chip path on a "
+                         "multi-chip host")
     ap.add_argument("--multihost", action="store_true",
                     help="join a multi-process cluster via "
                          "jax.distributed (coordinator discovered from "
@@ -129,9 +151,6 @@ def main() -> None:
                          "position is padding (docs/packing.md)")
     ap.add_argument("--docs-per-seq", type=int, default=4,
                     help="documents packed into each row with --pack")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent XLA compilation cache directory: "
-                         "repeated runs skip recompiles (utils/benchtime.py)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="checkpoint directory: saves every --ckpt-every "
                          "steps and resumes from the last good checkpoint "
@@ -177,7 +196,7 @@ def main() -> None:
                          "barrier waits, watchdog beats) — merge across "
                          "processes with tools/cluster_timeline.py "
                          "(docs/observability.md §6)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.log_every < 1:
         ap.error("--log-every must be >= 1")
     if args.elastic_ckpt and not args.ckpt_dir:
@@ -213,8 +232,6 @@ def main() -> None:
         CheckpointManager,
         MetricsLogger,
         StepTimer,
-        achieved_mfu,
-        device_peak_tflops,
         enable_compile_cache,
         init_step_stats,
         init_train_metrics,
@@ -222,18 +239,22 @@ def main() -> None:
         ring_comms_accounting,
         transformer_step_flops,
     )
-    from ring_attention_tpu.utils.train import StepStats
+    from ring_attention_tpu.utils.telemetry import PEAK_TFLOPS
 
-    if args.compile_cache_dir:
-        # before any jit: every compile from here on lands in the cache
-        enable_compile_cache(args.compile_cache_dir)
+    # before any jit: every compile from here on lands in the cache
+    # (placed by JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache_tpu)
+    enable_compile_cache()
     # CPU dev boxes can't honor donation; the hint is still correct on TPU
     warnings.filterwarnings(
         "ignore", message="Some donated buffers were not usable"
     )
 
-    n_dev = len(jax.devices())
+    devices = jax.devices()[:args.devices] if args.devices else None
+    n_dev = len(devices or jax.devices())
     n_proc = jax.process_count()
+    dev0 = jax.devices()[0]
+    print(f"platform={dev0.platform} device_kind={dev0.device_kind!r} "
+          f"devices={n_dev} (of {len(jax.devices())})")
 
     # span tracing (docs/observability.md §6): each process appends to
     # its own spans_pNNNNN.jsonl; tools/cluster_timeline.py merges them
@@ -292,22 +313,32 @@ def main() -> None:
     if hybrid:
         ring = args.ring_size or inner_dev // ulysses
         mesh = create_mesh(ring_size=ring, ulysses_size=ulysses,
-                           dcn_data_size=args.dcn_data_size)
+                           dcn_data_size=args.dcn_data_size, devices=devices)
         seq_shards = ulysses * ring
     else:
         ring = args.ring_size or inner_dev
         mesh = create_mesh(
-            ring_size=ring, dcn_data_size=args.dcn_data_size
+            ring_size=ring, dcn_data_size=args.dcn_data_size, devices=devices
         ) if n_dev > 1 else None
         seq_shards = ring
     print(f"devices={n_dev} mesh={dict(mesh.shape) if mesh else None}")
+    if mesh is not None:
+        # ring order beside each device's physical coordinates: a ring
+        # that does not follow the torus still trains, only slower
+        for idx in np.ndindex(mesh.devices.shape):
+            d = mesh.devices[idx]
+            print(f"  mesh{list(idx)} = device {d.id} "
+                  f"coords={getattr(d, 'coords', None)}")
 
+    dim_head = args.dim_head or args.dim // args.heads
+    kv_heads = args.kv_heads or args.heads
     model = RingTransformer(
         num_tokens=256,
         dim=args.dim,
         depth=args.depth,
-        heads=4,
-        dim_head=args.dim // 4,
+        heads=args.heads,
+        dim_head=dim_head,
+        kv_heads=args.kv_heads,
         causal=True,
         striped=True,
         bucket_size=max(args.seq_len // max(seq_shards, 1), 1),
@@ -374,9 +405,19 @@ def main() -> None:
         tokens = jnp.asarray(tokens)
         if segments is not None:
             segments = jnp.asarray(segments)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    # parameter shapes do not depend on the sequence length: init on one
+    # tile per shard, jitted, instead of an eager pass over the full batch
+    init_tokens = jnp.zeros((1, 128 * max(seq_shards, 1)), jnp.int32)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), init_tokens)
     opt = optax.adamw(3e-4)
     opt_state = opt.init(params)
+    if mesh is not None:
+        # replicated over the whole mesh, explicitly: the compiled step is
+        # specialised to its input shardings and hands the same ones back
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        replicated = NamedSharding(mesh, PartitionSpec())
+        params, opt_state = jax.device_put((params, opt_state), replicated)
     if args.shard_opt_state:
         if mesh is None:
             ap.error("--shard-opt-state needs a mesh (more than 1 device)")
@@ -392,7 +433,7 @@ def main() -> None:
         # seed the loop host-side; the step keeps it there (utils/train.py)
         from ring_attention_tpu.utils import compat
 
-        opt_state = compat.host_device_put(opt_state, mesh)
+        opt_state = compat.host_device_put(opt_state)
 
     if args.pack:
         def loss_fn(p, t, s):
@@ -417,7 +458,6 @@ def main() -> None:
         jit_donate=True,
         collect_metrics=collect,
         offload_opt_state=args.offload_opt_state,
-        offload_mesh=mesh,
         shard_opt_state=args.shard_opt_state,
         shard_mesh=mesh,
     )
@@ -472,23 +512,29 @@ def main() -> None:
     logger = None
     mfu_flops = 0.0
     comms = {}
-    peak = device_peak_tflops() * max(n_dev, 1)
+    # utilization needs a listed peak: a device_kind the table does not
+    # hold (the CPU) gets no mfu field rather than one against a guess
+    peak = PEAK_TFLOPS.get(dev0.device_kind)
+    if peak is not None:
+        peak *= n_dev
+    step_args = (params, opt_state)
     if collect:
         # a resumed run continues its counters in the metrics carry
         metrics = init_train_metrics(skipped=int(stats.skipped),
                                      nonfinite=int(nonfinite))
+        step_args += (metrics,)
         logger = MetricsLogger(args.metrics_dir)
         n_params = sum(x.size for x in jax.tree.leaves(params))
         mfu_flops = transformer_step_flops(
-            n_params, tokens.size, depth=args.depth, heads=4,
-            dim_head=args.dim // 4, seq_len=args.seq_len, causal=True,
+            n_params, tokens.size, depth=args.depth, heads=args.heads,
+            dim_head=dim_head, seq_len=args.seq_len, causal=True,
             batch=args.batch,
         )
         if mesh is not None:
             pad_seq = args.seq_len + (-args.seq_len) % seq_shards
             comms = ring_comms_accounting(
                 ring_size=ring, ulysses_size=ulysses, seq_len=pad_seq,
-                heads=4, kv_heads=4, dim_head=args.dim // 4,
+                heads=args.heads, kv_heads=kv_heads, dim_head=dim_head,
                 dtype_bytes=2 if args.bf16 else 4, batch=args.batch,
                 depth=args.depth, counter_rotate=args.counter_rotate,
                 hop_compression=args.hop_compression,
@@ -496,20 +542,33 @@ def main() -> None:
             )
         else:
             comms = {"ring_hops": 0, "ring_hops_per_step": 0, "hop_bytes": 0}
-        # compiled peak-memory accounting of the step that actually runs
-        # (telemetry.compiled_memory): AOT-compile once, log temp/argument
-        # bytes next to the analytic comms numbers, and drive the loop on
-        # the same executable — no second compile
-        try:
-            from ring_attention_tpu.utils.telemetry import compiled_memory
+    elif guarded:
+        step_args += (stats,)
 
-            compiled_exe = train_step.lower(
-                params, opt_state, metrics, *batch
-            ).compile()
-            comms.update(compiled_memory(compiled_exe))
-            train_step = compiled_exe
-        except Exception:  # noqa: BLE001 — diagnostics never fail the run
-            pass
+    # AOT-compile the step that is about to run, once, and drive the loop
+    # on that executable: compile seconds are set-up time reported on
+    # their own, and a step that does not compile stops the run here
+    t0 = time.perf_counter()
+    train_step = train_step.lower(*step_args, *batch).compile()
+    compile_seconds = time.perf_counter() - t0
+    print(f"compile: {compile_seconds:.1f} s (train step, cache "
+          f"{jax.config.jax_compilation_cache_dir})")
+    if collect:
+        # compiled peak-memory accounting of the step that actually runs
+        # (telemetry.compiled_memory): temp/argument bytes next to the
+        # analytic comms numbers
+        from ring_attention_tpu.utils.telemetry import compiled_memory
+
+        comms.update(compiled_memory(train_step))
+
+    def shardings(tree):
+        return sorted({str(x.sharding) for x in jax.tree.leaves(tree)})
+
+    placement = {"params": shardings(params),
+                 "opt_state": shardings(opt_state),
+                 "batch": shardings(batch)}
+    for name, shs in placement.items():
+        print(f"sharding {name}: {'; '.join(shs)}")
 
     # numerics flight recorder (docs/observability.md §Observatory): the
     # last --flight-window metric rows ride in memory; a nonfinite step,
@@ -549,6 +608,9 @@ def main() -> None:
     loop_guard = recorder.guard() if recorder is not None else (
         contextlib.nullcontext()
     )
+    result = {"compile_seconds": compile_seconds, "losses": [],
+              "step_seconds": [], "fetch_seconds": [],
+              "shardings": placement, "mesh": mesh}
     try:
         if guard is not None:
             guard.install()  # compile/init/restore are behind us
@@ -556,7 +618,7 @@ def main() -> None:
             _train_loop(args, recorder, timer, train_step, params,
                         opt_state, metrics, stats, batch, collect, guarded,
                         mgr, logger, start, mfu_flops, comms, peak, guard,
-                        n_proc=n_proc, dog=dog)
+                        n_proc=n_proc, dog=dog, result=result)
     finally:
         if dog is not None:
             dog.stop()
@@ -573,12 +635,13 @@ def main() -> None:
         print(f"metrics: {logger.path} (render with tools/trace_report.py)")
     if recorder is not None and recorder.dumps:
         print("flight dumps: " + ", ".join(recorder.dumps))
+    return result
 
 
 def _train_loop(args, recorder, timer, train_step, params, opt_state,
                 metrics, stats, batch, collect, guarded, mgr, logger,
                 start, mfu_flops, comms, peak, guard=None, n_proc=1,
-                dog=None):
+                dog=None, *, result):
     from ring_attention_tpu.utils import achieved_mfu, tracing
     from ring_attention_tpu.utils.train import StepStats
 
@@ -603,6 +666,7 @@ def _train_loop(args, recorder, timer, train_step, params, opt_state,
         # the step-phase span measures host-side dispatch + the loss
         # sync inside timer.step; the compiled program itself is pinned
         # untraced (tests/test_tracing.py HLO pin)
+        t_dispatch = time.perf_counter()
         with tracer.span("train/step", step=step):
             if collect:
                 params, opt_state, metrics, loss = train_step(
@@ -625,14 +689,23 @@ def _train_loop(args, recorder, timer, train_step, params, opt_state,
                 params, opt_state, loss = train_step(
                     params, opt_state, *batch
                 )
-            timer.step(loss)
+            timer.step(loss)  # jax.block_until_ready(loss)
+        t_ready = time.perf_counter()
+        result["step_seconds"].append(t_ready - t_dispatch)
         if dog is not None:
             dog.beat(step)
         if step % args.log_every == 0 or step == args.steps - 1:
             with tracer.span("train/log", step=step):
+                loss_value = float(loss)
+                # what the value fetch still waited for after
+                # block_until_ready returned (chip_smoke.py's sync check)
+                result["fetch_seconds"].append(
+                    time.perf_counter() - t_ready)
+                result["losses"].append(loss_value)
                 skipped = int(stats.skipped) if (guarded or collect) else 0
                 print(
-                    f"step {step:4d}  loss {float(loss):.4f}  "
+                    f"step {step:4d}  loss {loss_value:.4f}  "
+                    f"{result['step_seconds'][-1]:.3f} s/step  "
                     f"{timer.tokens_per_sec:,.0f} tok/s"
                     + (f"  [skipped {skipped}]" if skipped else "")
                 )
@@ -640,7 +713,7 @@ def _train_loop(args, recorder, timer, train_step, params, opt_state,
                     sps = timer.steps_per_sec
                     logger.log(
                         step,
-                        loss=float(loss),
+                        loss=loss_value,
                         grad_norm=float(metrics.grad_norm),
                         step_ok=bool(metrics.step_ok),
                         skipped=int(metrics.skipped),
@@ -649,9 +722,9 @@ def _train_loop(args, recorder, timer, train_step, params, opt_state,
                         steps_per_sec=round(sps, 4),
                         step_ms_p50=round(timer.step_ms_p50, 2),
                         step_ms_p95=round(timer.step_ms_p95, 2),
-                        mfu=round(
+                        **({"mfu": round(
                             achieved_mfu(mfu_flops, 1.0 / sps, peak), 6
-                        ) if sps > 0 else 0.0,
+                        )} if sps > 0 and peak is not None else {}),
                         **comms,
                     )
         if drain_requested(step):

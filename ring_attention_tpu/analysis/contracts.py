@@ -290,9 +290,24 @@ JAXPR_COLLECTIVE_PRIMS = {
     "psum_scatter",
 }
 
+# One collective instruction line of optimized HLO, keyed on the OPCODE
+# (``%ppermute.3 = f32[2,4]{1,0} collective-permute(%param.1), ...``): XLA
+# names instructions after the jax op that produced them, so the name
+# left of ``=`` says nothing about the kind.  ``-start`` is the async
+# form's issuing half; ``-done`` carries no opcode paren match here.
 _HLO_COLLECTIVE_RE = re.compile(
-    r"%?(" + "|".join(HLO_COLLECTIVE_KINDS) + r")(?:-start)?[.\d]* = "
+    r"^[^=\n]* = [^\n]*?[\s)](" + "|".join(HLO_COLLECTIVE_KINDS)
+    + r")(?:-start)?\([^\n]*",
+    re.MULTILINE,
 )
+
+
+def _hlo_instructions(txt: str, kind: str) -> list[str]:
+    """Full text of every ``kind`` instruction line, in program order."""
+    return [m.group(0) for m in _HLO_COLLECTIVE_RE.finditer(txt)
+            if m.group(1) == kind]
+
+
 _PPERMUTE_PAIRS_RE = re.compile(
     r"collective-permute[^\n]*source_target_pairs=\{([0-9,{} ]*)\}"
 )
@@ -395,8 +410,7 @@ def check_groups_axis(
     """Replica groups of ``kind`` instructions must each span exactly the
     given mesh axis (all other coordinates fixed within a group)."""
     out = []
-    inst_re = re.compile(r"%?" + re.escape(kind) + r"(?:-start)?[.\d]* = [^\n]*")
-    for inst, line in enumerate(inst_re.findall(txt)):
+    for inst, line in enumerate(_hlo_instructions(txt, kind)):
         groups = _parse_replica_groups(line)
         if groups is None:
             continue  # scalar/degenerate form without explicit groups
@@ -451,14 +465,14 @@ class JaxprCollectives:
 
 
 def _sub_jaxprs(value):
-    import jax
+    from jax.extend import core as jex_core
 
     stack = [value]
     while stack:
         v = stack.pop()
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             stack.extend(v)
@@ -1229,10 +1243,7 @@ def hlo_dcn_isolation(
                     f"over DCN [rule: dcn-isolation]"
                 )
     for kind in ("all-to-all", "all-gather", "reduce-scatter"):
-        inst_re = re.compile(
-            r"%?" + re.escape(kind) + r"(?:-start)?[.\d]* = [^\n]*"
-        )
-        for inst, line in enumerate(inst_re.findall(txt)):
+        for inst, line in enumerate(_hlo_instructions(txt, kind)):
             groups = _parse_replica_groups(line)
             if groups is None:
                 continue

@@ -109,23 +109,23 @@ def _inner_aval(aval):
 
 def _sub_closed_jaxprs(value):
     """Yield every (Closed)Jaxpr nested in a params value."""
-    import jax
+    from jax.extend import core as jex_core
 
     stack = [value]
     while stack:
         v = stack.pop()
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             stack.extend(v)
 
 
 def _as_jaxpr(value):
-    import jax
+    from jax.extend import core as jex_core
 
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, jex_core.ClosedJaxpr):
         return value.jaxpr
     return value
 
@@ -166,9 +166,9 @@ class JaxprWalker:
 
     # -- environment helpers ----------------------------------------------
     def _read(self, env, atom):
-        import jax
+        from jax.extend import core as jex_core
 
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jex_core.Literal):
             return self.init_value(getattr(atom, "aval", None))
         if atom in env:
             return env[atom]
@@ -474,12 +474,12 @@ class PrecisionFlow(JaxprWalker):
         # ref mutation: a store joins the stored value's taint into the
         # ref variable so later loads observe it
         if eqn.primitive.name in ("swap", "addupdate") and eqn.invars:
-            import jax
+            from jax.extend import core as jex_core
 
             ref = eqn.invars[0]
             stored = (frozenset().union(*in_vals[1:])
                       if in_vals[1:] else frozenset())
-            if not isinstance(ref, jax.core.Literal):
+            if not isinstance(ref, jex_core.Literal):
                 env[ref] = env.get(ref, frozenset()) | stored
 
     def visit(self, eqn, in_vals, out_vals, site):
@@ -555,7 +555,7 @@ def _producing_arithmetic(jaxpr, outvar, _depth: int = 0):
     arithmetic equation on the producing chain, or None when the value is
     a pure pass-through of the loop inputs (a rotating payload — a
     ``ppermute`` of the carry — is movement, not accumulation)."""
-    import jax
+    from jax.extend import core as jex_core
 
     if _depth > 6:
         return None
@@ -564,7 +564,7 @@ def _producing_arithmetic(jaxpr, outvar, _depth: int = 0):
     stack = [outvar]
     while stack:
         v = stack.pop()
-        if isinstance(v, jax.core.Literal) or id(v) in seen:
+        if isinstance(v, jex_core.Literal) or id(v) in seen:
             continue
         seen.add(id(v))
         e = producers.get(v)
@@ -575,7 +575,7 @@ def _producing_arithmetic(jaxpr, outvar, _depth: int = 0):
             return e
         if name in _TRANSPARENT_PRIMS:
             stack.extend(a for a in e.invars
-                         if not isinstance(a, jax.core.Literal))
+                         if not isinstance(a, jex_core.Literal))
             continue
         # control flow: look through the sub-jaxpr outputs feeding v
         subs = []
@@ -593,7 +593,7 @@ def _producing_arithmetic(jaxpr, outvar, _depth: int = 0):
                     if hit is not None:
                         return hit
             stack.extend(a for a in e.invars
-                         if not isinstance(a, jax.core.Literal))
+                         if not isinstance(a, jex_core.Literal))
             continue
         # unknown leaf primitive (erf, sin, a future custom op): treat as
         # arithmetic — a carry produced by computation the walker cannot

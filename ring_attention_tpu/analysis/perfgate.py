@@ -10,9 +10,8 @@ gates on it, in the IO-accounting spirit of FlashAttention (arXiv
 bytes per hop, compiled peak scratch, tokens/sec) and fail loudly when
 one regresses, instead of trusting the narrative.
 
-Wedge-honest policy: the TPU probe has been wedged in 4 of 5 bench
-rounds (docs/hardware_log.md), so the gate's PRIMARY signals are the
-CPU-computable ones that land even on wedged rounds — the
+Policy: the gate's PRIMARY signals are the CPU-computable ones that
+land on every round — the
 ``collective_fingerprint`` (compiled HLO collective counts per
 strategy), the analytic hop/byte accounting, ``compiled_cost`` FLOPs /
 bytes, ``compiled_memory`` peak temp bytes, and the retrace-sentinel

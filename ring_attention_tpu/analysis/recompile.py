@@ -195,14 +195,14 @@ def audit_remat_residuals(fn, *args, forbidden, label: str | None = None
 
 
 def _sub_jaxprs(value):
-    import jax
+    from jax.extend import core as jex_core
 
     stack = [value]
     while stack:
         v = stack.pop()
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             stack.extend(v)

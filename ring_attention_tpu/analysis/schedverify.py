@@ -172,14 +172,14 @@ class _ScheduleExtractor(JaxprWalker):
         key = (site.path, site.index)
         if key in self.ops:
             return  # fixpoint sweeps revisit loop bodies
-        import jax
+        from jax.extend import core as jex_core
 
         bufs, sems, lits = [], [], []
         for atom, val in zip(eqn.invars, in_vals):
             aval_s = str(getattr(atom, "aval", ""))
-            if "MemRef" in aval_s:
+            if aval_s.startswith("Ref"):  # Ref<any>{...}, Ref<semaphore_mem>{...}
                 (sems if "sem" in aval_s else bufs).append(self._name(val))
-            elif isinstance(atom, jax.core.Literal):
+            elif isinstance(atom, jex_core.Literal):
                 try:
                     lits.append(int(atom.val))
                 except (TypeError, ValueError):

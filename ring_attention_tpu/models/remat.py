@@ -34,9 +34,9 @@ The table (policy -> what the backward recomputes):
 ``save_attn_and_ffn_inputs``  union of the two named policies
 ``offload_attn``           as ``save_attn``, but the saved residuals live
                            in host memory (``pinned_host``) instead of
-                           HBM; degrades to ``save_attn`` on backends
-                           without an addressable host space (jax 0.4.x
-                           CPU — see ``utils/compat.host_memory_kind``)
+                           HBM; ``save_attn`` on a backend without an
+                           addressable host space (see
+                           ``utils/compat.host_memory_kind``)
 =========================  ==============================================
 
 Policies are per-layer selectable on ``RingTransformer`` (a tuple of names
@@ -64,12 +64,9 @@ def _offload_attn():
     from ..utils import compat
 
     kind = compat.host_memory_kind()
-    fn = getattr(
-        jax.checkpoint_policies, "save_and_offload_only_these_names", None
-    )
-    if kind is None or fn is None:
+    if kind is None:
         return _named(*_ATTN_NAMES)
-    return fn(
+    return jax.checkpoint_policies.save_and_offload_only_these_names(
         names_which_can_be_saved=[],
         names_which_can_be_offloaded=list(_ATTN_NAMES),
         offload_src="device",

@@ -60,7 +60,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import EPSILON, MASK_VALUE
 from .pallas_flash import (
     _block_sizes,
+    _int8_dot,
     _interpret_default,
+    _log_launch,
     _online_update,
     _sds,
     _unify_vma,
@@ -166,17 +168,18 @@ def _fused_local_kernel(origins_ref, his_ref, los_ref, works_ref, *refs,
     def _tile():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        nt = (((1,), (1,)), ((), ()))
         if quantized:
             # int8 QK^T: per-row q/k dequant scales ride the matmul's free
             # indices; the softmax scale folds into the same rescale
             # (docs/precision.md — identical to pallas_flash._fwd_tile).
             qs_ref, ks_ref, _ = scale_refs
-            s = s * ((qs_ref[0, 0] * scale)[:, None] * ks_ref[0, 0][None, :])
-        elif scale != 1.0:
-            s = s * scale
+            s = _int8_dot(q, k, nt) * (
+                (qs_ref[0, 0] * scale) * ks_ref[0, 0])
+        else:
+            s = lax.dot_general(q, k, nt, preferred_element_type=jnp.float32)
+            if scale != 1.0:
+                s = s * scale
         if softclamp_value is not None:
             s = jnp.tanh(s / softclamp_value) * softclamp_value
 
@@ -188,21 +191,21 @@ def _fused_local_kernel(origins_ref, his_ref, los_ref, works_ref, *refs,
         diff = cols - rows
         keep = (diff <= hi) & (diff >= lo)
         if masked:
-            keep = keep & kvm_ref[0][None, :]
+            keep = keep & (kvm_ref[0] != 0)
         if segmented:
-            keep = keep & (qseg_ref[0][:, None] == kseg_ref[0][None, :])
+            keep = keep & (qseg_ref[0] == kseg_ref[0])
         s = jnp.where(keep, s, MASK_VALUE)
 
         _online_update(
             s, v_ref[0, 0], acc, m, l,
-            v_scale=scale_refs[2][0, 0, 0] if quantized else None,
+            v_scale=scale_refs[2][0, 0, 0] if quantized else None,  # (1, 1)
         )
 
     @pl.when(s_id == spans - 1)
     def _write():
         l_safe = jnp.maximum(l[:], EPSILON)
         out_ref[0, 0] = (acc[:] / l_safe).astype(out_ref.dtype)
-        lse_ref[0, 0] = (m[:] + jnp.log(l_safe))[:, 0]
+        lse_ref[0, 0] = m[:] + jnp.log(l_safe)
 
 
 def fused_ring_local(
@@ -270,17 +273,23 @@ def fused_ring_local(
 
     segmented = q_segment_ids is not None
     masked = kv_mask is not None
-    if masked:
-        kv_mask = kv_mask.astype(jnp.bool_)
 
     def q_map(bi, hd, qi, s, o, hi, lo, w):
         return (bi, hd, qi, 0)
 
-    def kv_map(bi, hd, qi, s, o, hi, lo, w):
-        return (bi, hd // g, o[s // kpb] * kpb + s % kpb, 0)
+    def kblock(s, o):
+        return o[s // kpb] * kpb + s % kpb
 
-    def kcol_map(bi, hd, qi, s, o, hi, lo, w):
-        return (bi, o[s // kpb] * kpb + s % kpb)
+    def kv_map(bi, hd, qi, s, o, hi, lo, w):
+        return (bi, hd // g, kblock(s, o), 0)
+
+    # Per-token vectors keep a trailing unit axis when the tile wants a
+    # column ((..., n, 1) blocked (..., bq, 1)) and a unit second-to-last
+    # axis when it wants a row ((..., 1, n) blocked (..., 1, bk)): a
+    # (..., 1, block) slice of the plain (..., rows, n) layout is not a
+    # legal TPU block (pallas_flash._token_vectors).
+    def krow_map(bi, hd, qi, s, o, hi, lo, w):
+        return (bi, 0, kblock(s, o))
 
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), q_map),
@@ -290,23 +299,23 @@ def fused_ring_local(
     operands = [q_in, k_in, v_in]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, 1, bq), lambda bi, hd, qi, s, o, hi, lo, w:
-                         (bi, hd, qi)),
-            pl.BlockSpec((1, 1, bk), lambda bi, hd, qi, s, o, hi, lo, w:
-                         (bi, hd // g, o[s // kpb] * kpb + s % kpb)),
-            pl.BlockSpec((1, 1, 1), lambda bi, hd, qi, s, o, hi, lo, w:
-                         (bi, hd // g, o[s // kpb] * kpb + s % kpb)),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
+            pl.BlockSpec((1, 1, 1, bk), lambda bi, hd, qi, s, o, hi, lo, w:
+                         (bi, hd // g, 0, kblock(s, o))),
+            pl.BlockSpec((1, 1, 1, 1, 1), lambda bi, hd, qi, s, o, hi, lo, w:
+                         (bi, hd // g, kblock(s, o), 0, 0)),
         ]
-        operands += [qs, ks, vs]
+        operands += [qs[..., None], ks[:, :, None, :], vs[..., None, None]]
     if masked:
-        in_specs.append(pl.BlockSpec((1, bk), kcol_map))
-        operands.append(kv_mask)
+        in_specs.append(pl.BlockSpec((1, 1, bk), krow_map))
+        operands.append(kv_mask.astype(jnp.int32)[:, None, :])
     if segmented:
         in_specs.append(
-            pl.BlockSpec((1, bq), lambda bi, hd, qi, s, o, hi, lo, w:
-                         (bi, qi)))
-        in_specs.append(pl.BlockSpec((1, bk), kcol_map))
-        operands += [q_segment_ids, kv_segment_ids]
+            pl.BlockSpec((1, bq, 1), lambda bi, hd, qi, s, o, hi, lo, w:
+                         (bi, qi, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, bk), krow_map))
+        operands += [q_segment_ids.astype(jnp.int32)[:, :, None],
+                     kv_segment_ids.astype(jnp.int32)[:, None, :]]
 
     kernel = functools.partial(
         _fused_local_kernel,
@@ -329,8 +338,7 @@ def fused_ring_local(
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), q_map),
-            pl.BlockSpec((1, 1, bq), lambda bi, hd, qi, s, o, hi, lo, w:
-                         (bi, hd, qi)),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -338,12 +346,13 @@ def fused_ring_local(
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
     )
+    _log_launch(name, n_q, n_total, h, hk, d, bq, bk, (b, h, nqb, spans))
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             _sds((b, h, n_q, d), q.dtype, like),
-            _sds((b, h, n_q), jnp.float32, like),
+            _sds((b, h, n_q, 1), jnp.float32, like),
         ],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -352,7 +361,7 @@ def fused_ring_local(
         interpret=interpret,
         name=name if not quantized else name + "_q8",
     )(*tables, *operands)
-    return out, lse
+    return out, lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -673,16 +682,17 @@ def _fused_remote_kernel(his_ref, los_ref, works_ref, nbrs_ref, *refs,
             kblk = kvv[buf, 0]
             vblk = kvv[buf, 1]
             k = kblk[:, :d] if quantized else kblk
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            nt = (((1,), (1,)), ((), ()))
             if quantized:
                 ks = lax.bitcast_convert_type(
                     kblk[:, d:d + _quant.SCALE_BYTES], jnp.float32)
-                s = s * ((qs_ref[0] * scale)[:, None] * ks[None, :])
-            elif scale != 1.0:
-                s = s * scale
+                s = _int8_dot(q, k, nt) * (
+                    (qs_ref[0] * scale) * ks[None, :])
+            else:
+                s = lax.dot_general(q, k, nt,
+                                    preferred_element_type=jnp.float32)
+                if scale != 1.0:
+                    s = s * scale
             if softclamp_value is not None:
                 s = jnp.tanh(s / softclamp_value) * softclamp_value
             rows = lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + row0
@@ -738,7 +748,7 @@ def _fused_remote_kernel(his_ref, los_ref, works_ref, nbrs_ref, *refs,
     def _write():
         l_safe = jnp.maximum(l[:], EPSILON)
         out_ref[0] = (acc[:] / l_safe).astype(out_ref.dtype)
-        lse_ref[0] = (m[:] + jnp.log(l_safe))[:, 0]
+        lse_ref[0] = m[:] + jnp.log(l_safe)
 
 
 def fused_ring_remote(
@@ -787,6 +797,22 @@ def fused_ring_remote(
     hops = int(his.shape[0])
     naxes = int(nbr_coords.shape[-1])
     quantized = payload is not None
+    if jax.default_backend() == "tpu":
+        # Mosaic (TPU v5 lite, libtpu 0.0.34, 2026-09-26) refuses the
+        # kernel's hand-written HBM slices: every `.at[...]` window of an
+        # ANY-space buffer must be aligned to the (8, 128) tiling, and
+        # neither the d=64 rows of the circulated KV buffer ("Slice shape
+        # along dimension 4 must be aligned to tiling (128), but is 64")
+        # nor the (bq, 1) windows of the m/l spill at any head width
+        # ("... dimension 2 ... but is 1") are.  Tracing still works on
+        # other backends (the contract and protocol checks count its
+        # DMA/semaphore primitives from exactly this trace).
+        raise NotImplementedError(
+            "fused_ring_remote: refused by Mosaic on TPU — HBM slices of "
+            "its ring buffer and m/l spill are not aligned to the "
+            "128-lane tiling (ROADMAP S4/D2); the gather-fed "
+            "fused_ring_local and impl=\"pallas\" run"
+        )
 
     bq, bk = _block_sizes(n_local, n_local, block_q, block_k)
     nqb = n_local // bq
@@ -801,14 +827,14 @@ def fused_ring_remote(
     hbm = pl.BlockSpec(memory_space=pltpu.ANY)
     if quantized:
         q8, qs = _quant.quantize_rows(q_f)
-        operands = [q8, qs, fold(payload[0]), fold(payload[1])]
+        operands = [q8, qs[..., None], fold(payload[0]), fold(payload[1])]
         dd = d + _quant.SCALE_BYTES
         kv_dtype = jnp.int8
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda hop, bhi, qi, hi, lo, w, nb:
                          (bhi, qi, 0)),
-            pl.BlockSpec((1, bq), lambda hop, bhi, qi, hi, lo, w, nb:
-                         (bhi, qi)),
+            pl.BlockSpec((1, bq, 1), lambda hop, bhi, qi, hi, lo, w, nb:
+                         (bhi, qi, 0)),
             hbm,
             hbm,
         ]
@@ -842,8 +868,8 @@ def fused_ring_remote(
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda hop, bhi, qi, hi, lo, w, nb:
                          (bhi, qi, 0)),
-            pl.BlockSpec((1, bq), lambda hop, bhi, qi, hi, lo, w, nb:
-                         (bhi, qi)),
+            pl.BlockSpec((1, bq, 1), lambda hop, bhi, qi, hi, lo, w, nb:
+                         (bhi, qi, 0)),
             # HBM working buffers, returned-and-dropped: the circulated
             # double buffer and the cross-hop (acc, m, l) spill.
             hbm,
@@ -863,12 +889,14 @@ def fused_ring_remote(
             pltpu.SemaphoreType.REGULAR,
         ],
     )
+    _log_launch(name, n_local, n_local * hops, h, hk, d, bq, bk,
+                (hops, bh, nqb))
     out_f, lse_f, *_hbm_work = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             _sds((bh, n_local, d), q.dtype, like),
-            _sds((bh, n_local), jnp.float32, like),
+            _sds((bh, n_local, 1), jnp.float32, like),
             _sds((2, 2, bh, n_local, dd), kv_dtype, like),
             _sds((bh, n_local, d), jnp.float32, like),
             _sds((bh, n_local, 1), jnp.float32, like),

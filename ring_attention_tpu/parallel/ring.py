@@ -80,6 +80,8 @@ from ..ops.flash import (
     _ungroup,
 )
 from ..ops.pallas_flash import (
+    DEFAULT_BLOCK_K,
+    DEFAULT_BLOCK_Q,
     FlashPartials,
     _block_sizes,
     finalize_partials,
@@ -231,8 +233,8 @@ def _q8_block(bucket_size, nq, nk):
     """The ``block_k`` a pallas launch over an ``(nq, nk)`` span will fit
     — the granularity the int8 compute path's v scales must be packed at
     for the dequant-free hop feed (one derivation shared by the payload
-    packer and the kernel's own ``_block_sizes`` fitting)."""
-    return _block_sizes(nq, nk, bucket_size, bucket_size)[1]
+    packer and the launch: :func:`_pallas_blocks`)."""
+    return _fit_pallas_blocks(bucket_size, nq, nk)[1]
 
 
 def _stream_state(bidirectional, passes, ring_size, n_local, k, v, kv_mask,
@@ -425,8 +427,24 @@ def _fit_bucket(bucket_size: int | None, nk: int) -> int | None:
     return b
 
 
+def _fit_pallas_blocks(bucket_size, nq, nk):
+    """THE place a ring hop's Pallas tile is decided.
+
+    ``bucket_size`` is the XLA path's scan bucket and callers size it to
+    the shard (``examples/train.py``: ``seq_len // ring``), so it is only
+    an upper bound here: the tile never exceeds the kernels' own default
+    (``DEFAULT_BLOCK_Q/K``, the largest score tile Mosaic accepted on a
+    v5e), then ``_block_sizes`` fits it to the span."""
+    return _block_sizes(
+        nq, nk,
+        min(bucket_size or DEFAULT_BLOCK_Q, DEFAULT_BLOCK_Q),
+        min(bucket_size or DEFAULT_BLOCK_K, DEFAULT_BLOCK_K),
+    )
+
+
 def _pallas_blocks(bucket_size, nq, nk):
-    """Pallas-path analogue of :func:`_fit_bucket`'s visibility guarantee.
+    """:func:`_fit_pallas_blocks` with :func:`_fit_bucket`'s visibility
+    guarantee.
 
     The kernels' ``_block_sizes`` silently halves a block by powers of two
     until it divides the span — correct, but on a bidirectional half-stream
@@ -436,10 +454,11 @@ def _pallas_blocks(bucket_size, nq, nk):
     of what was asked for."""
     if bucket_size is None:
         return None, None
-    bq, bk = _block_sizes(nq, nk, bucket_size, bucket_size)
-    if bq * 2 <= min(bucket_size, nq) or bk * 2 <= min(bucket_size, nk):
+    bq, bk = _fit_pallas_blocks(bucket_size, nq, nk)
+    want = min(bucket_size, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+    if bq * 2 <= min(want, nq) or bk * 2 <= min(want, nk):
         warnings.warn(
-            f"ring pallas blocks demoted from {bucket_size} to "
+            f"ring pallas blocks demoted from {want} to "
             f"(block_q={bq}, block_k={bk}) to divide the ({nq}, {nk}) span; "
             f"tiny blocks underfill the MXU — pick a bucket_size dividing "
             f"the (half-)shard length",

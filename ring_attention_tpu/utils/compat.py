@@ -1,13 +1,13 @@
-"""JAX version compatibility shims (part of the resilience layer).
+"""Thin seams over the JAX APIs this package leans on.
 
-The kernels target current JAX (``jax.typeof`` varying-axes metadata,
-top-level ``jax.shard_map`` with ``check_vma``), but CI and dev boxes can
-run older releases where those APIs don't exist yet — and a framework
-whose import crashes on the CPU-only box that would have caught a bug is
-not resilient.  Each shim degrades to the semantically-equivalent older
-API; where the newer API only adds metadata that old JAX cannot represent
-(vma), the fallback is the identity, which is exactly what old JAX's
-``shard_map`` assumes.
+One spelling per API for the one installation there is (``pyproject.toml``
+pins ``jax>=0.9``; the sandbox and the chip machine both run 0.9.0):
+``jax.typeof``, ``jax.shard_map``, ``lax.pcast``, ``lax.axis_size``,
+``pltpu.CompilerParams``, ``jax.profiler.trace``.  The wrappers remain so
+call sites (and lint RA001) keep one import; inlining them away is ROADMAP
+D5.  The host-memory helpers are the only ones that still probe: whether
+a backend exposes a host memory space is a property of the machine, not
+of the JAX version.
 """
 
 from __future__ import annotations
@@ -15,203 +15,113 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+from jax import lax
 
 
 def typeof(x: Any):
-    """``jax.typeof`` (new) or the abstract value (old) — both expose
-    shape/dtype; only the new one carries ``vma``, and every caller here
-    reads ``vma`` via ``getattr(..., frozenset())``."""
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(x)
-    return jax.core.get_aval(x)
+    """``jax.typeof`` — shape/dtype plus the shard_map varying-axes
+    (``vma``) metadata the Pallas launchers unify across operands."""
+    return jax.typeof(x)
 
 
 def pcast(x: Any, axes, to: str = "varying"):
-    """``lax.pcast`` when it exists; identity otherwise.
-
-    Callers only reach this with non-empty ``axes`` when :func:`typeof`
-    reported varying-axes metadata — which old JAX never does, so the
-    identity fallback is unreachable there by construction (kept total
-    anyway: resilience code must not be the thing that crashes)."""
-    from jax import lax
-
-    fn = getattr(lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, axes, to=to)
+    """``lax.pcast``: mark ``x`` as varying over ``axes``."""
+    return lax.pcast(x, axes, to=to)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` (new, ``check_vma``) or
-    ``jax.experimental.shard_map.shard_map`` (old, ``check_rep``).
-
-    The two kwargs gate the same per-output replication/varying checker
-    across the rename.  On old JAX the checker is force-disabled: its
-    replication-rule table predates primitives this codebase relies on
-    (``checkpoint_name`` residuals raise ``NotImplementedError: No
-    replication rule for name``), and a checker that crashes working
-    programs is strictly worse than no checker — new-JAX CI keeps the
-    real ``check_vma`` coverage.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as old_shard_map
-
-    return old_shard_map(
+    """``jax.shard_map`` (``check_vma`` gates the per-output
+    replication/varying checker)."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=check_vma,
     )
 
 
 def jit(fn, *, donate_argnums=(), **kwargs):
-    """``jax.jit`` with buffer donation, degrading to no donation.
+    """``jax.jit`` with buffer donation.
 
     Donation is an aliasing hint — XLA reuses the donated input buffers
     for outputs instead of double-allocating (the train loop's
     ``(params, opt_state)`` are exactly the buffers whose copies would
-    otherwise double peak optimizer-state memory).  Old/exotic jax builds
-    that reject the kwarg fall back to a plain jit: the program is then
-    merely less memory-efficient, never wrong."""
-    if donate_argnums:
-        try:
-            return jax.jit(fn, donate_argnums=donate_argnums, **kwargs)
-        except TypeError:
-            pass
-    return jax.jit(fn, **kwargs)
+    otherwise double peak optimizer-state memory)."""
+    return jax.jit(fn, donate_argnums=donate_argnums, **kwargs)
 
 
 def profiler_trace(logdir: str):
-    """``jax.profiler.trace(logdir)`` across the 0.4.x → 0.5+ surface.
-
-    The context-manager form exists everywhere this repo runs, but newer
-    releases grew extra keyword defaults (``create_perfetto_link``/
-    ``create_perfetto_trace``) whose *absence* is the portable spelling —
-    and on builds without the context manager at all, the start/stop pair
-    is composed into one here.  Callers go through
-    ``utils/profiling.trace`` (docs/observability.md §Observatory); this
-    shim is the single place a profiler entry-point difference may live.
-    """
-    cm = getattr(jax.profiler, "trace", None)
-    if cm is not None:
-        return cm(logdir)
-
-    import contextlib
-
-    @contextlib.contextmanager
-    def _fallback():
-        jax.profiler.start_trace(logdir)
-        try:
-            yield
-        finally:
-            jax.profiler.stop_trace()
-
-    return _fallback()
+    """``jax.profiler.trace(logdir)`` — callers go through
+    ``utils/profiling.trace`` (docs/observability.md §Observatory)."""
+    return jax.profiler.trace(logdir)
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new name) or ``pltpu.TPUCompilerParams``
-    (old name) — same dataclass across the rename; every field this repo
-    passes (``dimension_semantics``) exists in both."""
+    """``pltpu.CompilerParams`` for a ``pallas_call``."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def host_memory_kind() -> str | None:
-    """The device-addressable host memory space ("pinned_host" on TPU/GPU
-    builds with offload support), or None when the backend exposes none.
+    """The host memory space in-graph placement can target
+    ("pinned_host"), or None when the backend has none.
 
-    CPU backends report only "unpinned_host" — which IS host memory
-    already, so "offloading" there is meaningless and callers correctly
-    degrade to the identity.  Every probe failure (old jax without
-    ``addressable_memories``, exotic backends) reads as "no host space":
-    offload is an optimization and must never be the thing that crashes.
+    jax 0.9.0's CPU backend LISTS ``pinned_host`` among its memory spaces,
+    but XLA:CPU compiles an in-graph host placement to device memory (the
+    compiled output's ``memory_kind`` is ``device``), so a host-seeded
+    state would mismatch the step that consumes it.  Offload on the CPU
+    is therefore the identity, as it always was: this returns None there.
     """
-    try:
-        kinds = {
-            m.kind
-            for d in jax.local_devices()
-            for m in d.addressable_memories()
-        }
-    except Exception:  # noqa: BLE001 — any probe failure means "unsupported"
+    if jax.default_backend() == "cpu":
         return None
+    kinds = {
+        m.kind
+        for d in jax.local_devices()
+        for m in d.addressable_memories()
+    }
     return "pinned_host" if "pinned_host" in kinds else None
 
 
-def host_sharding(sharding):
-    """``sharding`` moved into the host memory space, or None when this
-    backend has no host space / the sharding cannot express memory kinds
-    (old jax).  Callers treat None as "keep the buffer where it is"."""
-    kind = host_memory_kind()
-    if kind is None:
-        return None
-    try:
-        return sharding.with_memory_kind(kind)
-    except (AttributeError, ValueError):
-        return None
-
-
-def host_device_put(tree, mesh=None):
+def host_device_put(tree):
     """Move every array leaf of ``tree`` into host memory, PRESERVING its
     sharding; the identity when the backend has no host memory space.
 
-    This is the jax-0.4.x-safe offload primitive: ``jax.device_put`` onto
-    a memory-kind target is the documented in-graph transfer
-    (``with_sharding_constraint`` did not learn memory kinds until later
-    releases).  Placement keeps each leaf's partitioning — a ZeRO-1
-    sharded optimizer state stays sharded on host, never silently
-    re-replicated N-x:
+    Placement keeps each leaf's partitioning — a ZeRO-1 sharded optimizer
+    state stays sharded on host, never silently re-replicated N-x:
 
     - concrete leaves (seeding the loop outside jit) move via their own
       ``sharding.with_memory_kind``;
-    - traced leaves (inside the step) move via ``TransferToMemoryKind``,
+    - traced leaves (inside the step) move via ``jax.memory.Space.Host``,
       which changes only the memory space and lets the partitioner keep
-      the layout it chose; ``mesh`` is only the replicated fallback for
-      jax builds without it.
+      the layout it chose.
 
     Used by ``make_train_step(offload_opt_state=True)`` for the Adam
     moments — the next HBM cliff after activations (docs/memory.md).
     """
-    from jax.sharding import (
-        NamedSharding,
-        PartitionSpec,
-        SingleDeviceSharding,
-    )
-
     kind = host_memory_kind()
     if kind is None:
         return tree
 
-    def fallback_sharding():
-        if mesh is not None:
-            return NamedSharding(mesh, PartitionSpec(), memory_kind=kind)
-        return SingleDeviceSharding(jax.devices()[0], memory_kind=kind)
-
     def place(x):
+        if not isinstance(x, jax.Array):
+            return x
         if isinstance(x, jax.core.Tracer):
-            try:  # private in 0.4.x (public jax.sharding export came later)
-                from jax._src.sharding_impls import TransferToMemoryKind
-
-                return jax.device_put(x, TransferToMemoryKind(kind))
-            except Exception:  # noqa: BLE001 — degrade, never crash
-                return jax.device_put(x, fallback_sharding())
-        sharding = getattr(x, "sharding", None)
-        if sharding is not None:
-            try:
-                return jax.device_put(x, sharding.with_memory_kind(kind))
-            except (AttributeError, ValueError):
-                pass
-        return jax.device_put(x, fallback_sharding())
+            return jax.device_put(x, jax.memory.Space.Host)
+        return jax.device_put(x, x.sharding.with_memory_kind(kind))
 
     return jax.tree.map(place, tree)
+
+
+def device_memory_put(tree):
+    """Inverse of :func:`host_device_put` inside a traced step: every
+    array leaf back in device memory (the identity for leaves already
+    there, and on a backend without a host memory space)."""
+    if host_memory_kind() is None:
+        return tree
+    return jax.tree.map(
+        lambda x: jax.device_put(x, jax.memory.Space.Device)
+        if isinstance(x, jax.Array) else x,
+        tree,
+    )
 
 
 def bound_axis_names():
@@ -222,30 +132,18 @@ def bound_axis_names():
     per mesh axis, not just the ring axis: a LOGICAL id built from the
     ring coordinate alone addresses the wrong device on any multi-axis
     mesh).  ``get_axis_env().axis_sizes`` is an insertion-ordered dict of
-    bound axes on every jax this repo supports; its private home moved
-    across releases, and a None here just means "no coordinate table",
-    which callers treat as "take the gathered-KV local tier instead" —
-    introspection failure must degrade, never crash."""
-    for mod in ("jax._src.core", "jax.core"):
-        try:
-            import importlib
+    bound axes; it has no public home, and a None here just means "no
+    coordinate table", which callers treat as "take the gathered-KV local
+    tier instead"."""
+    try:
+        from jax._src.core import get_axis_env
 
-            env = importlib.import_module(mod).get_axis_env()
-            return tuple(env.axis_sizes.keys())
-        except Exception:  # noqa: BLE001 — degrade, never crash
-            continue
-    return None
+        return tuple(get_axis_env().axis_sizes.keys())
+    except (ImportError, AttributeError):
+        return None
 
 
 def axis_size(axis_name):
-    """``lax.axis_size`` (new) or the bound axis frame's size (old).
-
-    Both return a static Python int inside ``shard_map``, so callers can
-    keep using it for loop bounds and shape arithmetic."""
-    from jax import lax
-
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
+    """``lax.axis_size``: a static Python int inside ``shard_map``, so
+    callers can keep using it for loop bounds and shape arithmetic."""
+    return lax.axis_size(axis_name)

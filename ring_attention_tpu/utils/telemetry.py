@@ -1,9 +1,8 @@
 """Telemetry spine: in-graph metrics, host-side logging, MFU/hop accounting.
 
-The reference has no timers or profiler hooks at all (SURVEY §5), and this
-repo's own bench history shows the cost: BENCH_r04/r05 report ``value: 0.0``
-with "device probe hung" and no per-phase breakdown to say whether the ring
-hop, the Ulysses all-to-all, or the kernel itself regressed.  FlashAttention
+The reference has no timers or profiler hooks at all (SURVEY §5): a
+regression arrives with no per-phase breakdown to say whether the ring
+hop, the Ulysses all-to-all, or the kernel itself moved.  FlashAttention
 (arXiv 2205.14135) made IO-awareness the design axis; this module is the
 measurement side of that, plus TASP-style (arXiv 2509.26541) topology-aware
 communication accounting, in four pieces:
@@ -69,28 +68,27 @@ def _active_tracer():
 # docs/observability.md for the glossary emitted by examples/train.py).
 SCHEMA_VERSION = 1
 
-# bf16 dense peak TFLOPs per chip by TPU generation — the denominator of
-# every MFU number this framework reports (bench.py mirrors this table; its
-# parent process must stay import-free of the package until the device
-# probe passes).
+# bf16 dense peak TFLOPs per chip, keyed by the EXACT ``device_kind``
+# string jax reports — the denominator of every MFU number this framework
+# reports.  A device that is not listed is an error, never a default: add
+# a row when a new chip is seen, with the string it reports.  Source:
+# Google Cloud "TPU v5e" documentation; the key is what one v5e chip
+# reported on 2026-07-29 (BENCH_r02.json) and again in chip_smoke.py.
 PEAK_TFLOPS = {
-    "v5 lite": 197.0,  # v5e
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v4": 275.0,
-    "v6e": 918.0,
+    "TPU v5 lite": 197.0,
 }
 
-# per-direction ICI link bandwidth (GB/s) by generation — used only for
+# per-direction ICI link bandwidth (GB/s), same keying — used only for
 # the analytic per-hop overlap fraction (a planning number, not a
-# measurement; the measured truth is an XProf capture)
+# measurement; the measured truth is a profiler capture)
 ICI_GBPS = {
-    "v5 lite": 186.0,
-    "v5e": 186.0,
-    "v5p": 306.0,
-    "v4": 268.0,
-    "v6e": 448.0,
+    "TPU v5 lite": 186.0,
 }
+
+# the chip ``ring_comms_accounting``'s overlap MODEL describes when the
+# caller passes no peaks: a fixed reference, not the device the process
+# happens to run on — the model's output never depends on where it runs
+MODEL_DEVICE_KIND = "TPU v5 lite"
 
 # attention matmul counts (shared with bench.py): 2 matmuls forward
 # (q@k^T, p@v); backward recomputes scores and adds 4 grad matmuls
@@ -709,24 +707,31 @@ def read_flight_dump(path: str) -> dict[str, Any]:
 # ----------------------------------------------------------------------
 
 
-def device_peak_tflops(device: Any = None) -> float:
-    """bf16 peak TFLOPs of ``device`` (default: ``jax.devices()[0]``);
-    unknown kinds fall back to the v5e figure — bench.py's convention."""
+def _device_rate(table: dict[str, float], what: str, device: Any) -> float:
     if device is None:
         import jax
 
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", str(device)).lower()
-    return next((v for k, v in PEAK_TFLOPS.items() if k in kind), 197.0)
+    kind = getattr(device, "device_kind", str(device))
+    if kind not in table:
+        raise ValueError(
+            f"no {what} listed for device_kind {kind!r} (listed: "
+            f"{sorted(table)}); add the chip to utils/telemetry.py with "
+            f"its published figure — an assumed peak is not a utilization"
+        )
+    return table[kind]
+
+
+def device_peak_tflops(device: Any = None) -> float:
+    """bf16 peak TFLOPs of ``device`` (default: ``jax.devices()[0]``);
+    raises ``ValueError`` for a ``device_kind`` :data:`PEAK_TFLOPS` does
+    not list."""
+    return _device_rate(PEAK_TFLOPS, "bf16 peak", device)
 
 
 def device_ici_gbps(device: Any = None) -> float:
-    if device is None:
-        import jax
-
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", str(device)).lower()
-    return next((v for k, v in ICI_GBPS.items() if k in kind), 186.0)
+    """Per-direction ICI GB/s of ``device``; raises for an unlisted kind."""
+    return _device_rate(ICI_GBPS, "ICI bandwidth", device)
 
 
 def flash_attention_flops(
@@ -1124,15 +1129,9 @@ def ring_comms_accounting(
     if causal:
         hop_flops *= 0.5  # averaged over hops, half the band is masked
     if peak_tflops is None:
-        try:
-            peak_tflops = device_peak_tflops()
-        except Exception:  # noqa: BLE001 — accounting must not need a device
-            peak_tflops = PEAK_TFLOPS["v5e"]
+        peak_tflops = PEAK_TFLOPS[MODEL_DEVICE_KIND]
     if ici_gbps is None:
-        try:
-            ici_gbps = device_ici_gbps()
-        except Exception:  # noqa: BLE001
-            ici_gbps = ICI_GBPS["v5e"]
+        ici_gbps = ICI_GBPS[MODEL_DEVICE_KIND]
     # int8 matmuls run at ~2x the bf16 MXU rate (v5e/v5p), so a quantized
     # hop finishes its compute in half the time — less of it available to
     # hide the same ICI transfer

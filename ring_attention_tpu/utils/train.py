@@ -59,7 +59,6 @@ def make_train_step(
     jit_donate: bool = False,
     collect_metrics: bool = False,
     offload_opt_state: bool = False,
-    offload_mesh: Mesh | None = None,
     shard_opt_state: bool = False,
     shard_mesh: Mesh | None = None,
     on_step_end: Callable[..., None] | None = None,
@@ -104,14 +103,10 @@ def make_train_step(
       the backend's host memory space (``pinned_host``) inside the step,
       so the Adam moments — 2 model-sized f32 buffers — stop occupying
       HBM between steps.  Seed the loop by placing the initial state
-      there too: ``opt_state = compat.host_device_put(opt.init(params),
-      mesh)``.  Placement preserves each leaf's sharding (a ZeRO-1
-      sharded state stays sharded on host); ``offload_mesh`` only feeds
-      the replicated fallback on jax builds without
-      ``TransferToMemoryKind``.
-      On backends without an addressable host space (jax 0.4.x CPU) the
-      transfer is the identity and the step is unchanged — the
-      graceful-degradation contract every compat shim follows; the
+      there too: ``opt_state = compat.host_device_put(opt.init(params))``.
+      Placement preserves each leaf's sharding (a ZeRO-1 sharded state
+      stays sharded on host).  On a backend without an addressable host
+      space the transfer is the identity and the step is unchanged; the
       placement is auditable via ``analysis.recompile.audit_host_offload``
       and ``tools/check_contracts.py --memory``.
     - ``collect_metrics=True`` — the instrumented step
@@ -175,6 +170,12 @@ def make_train_step(
     grad_fn = jax.value_and_grad(loss_fn)
 
     def compute_update(params, opt_state, *batch):
+        if offload_opt_state:
+            # the state arrives host-resident; arithmetic needs every
+            # operand in one memory space, so fetch it for the update
+            from . import compat
+
+            opt_state = compat.device_memory_put(opt_state)
         if accum_steps == 1:
             loss, grads = grad_fn(params, *batch)
         else:
@@ -247,7 +248,7 @@ def make_train_step(
             return opt_state
         from . import compat
 
-        return compat.host_device_put(opt_state, offload_mesh)
+        return compat.host_device_put(opt_state)
 
     def finish(step):
         if jit_donate:

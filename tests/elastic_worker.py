@@ -61,17 +61,16 @@ def main() -> None:
 
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_default_matmul_precision", "highest")
-    # share the test suite's persistent compile cache: repeat chaos runs
-    # pay XLA compilation once per (device count, shape), not per run
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     ".jax_cache"),
-    )
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.05)
-
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    # share the test suite's persistent compile cache: repeat chaos runs
+    # pay XLA compilation once per (device count, shape), not per run
+    from ring_attention_tpu.utils import enable_compile_cache
+
+    enable_compile_cache(
+        os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                     ".jax_cache")
     )
     import numpy as np
     import optax

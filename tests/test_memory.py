@@ -333,12 +333,15 @@ def tiny_step_case():
 
 
 def test_host_offload_degrades_to_noop_on_cpu(tiny_step_case):
-    """jax 0.4.x CPU exposes no pinned_host space: the compat probe says
-    so, host_device_put is the identity, and the offloaded step is
+    """The CPU backend lists a pinned_host space but compiles in-graph
+    host placement away, so the compat probe reports no host space there:
+    host_device_put is the identity, and the offloaded step is
     bit-identical to the plain one — offload must never change values,
     with or without a host space."""
-    assert compat.host_memory_kind() is None
-    assert compat.host_sharding(None) is None
+    kinds = {m.kind for d in jax.local_devices()
+             for m in d.addressable_memories()}
+    assert "pinned_host" in kinds  # listed by jax 0.9.0 ...
+    assert compat.host_memory_kind() is None  # ... but not a placement target
     tree = {"a": jnp.ones(3)}
     assert compat.host_device_put(tree)["a"] is tree["a"]
 

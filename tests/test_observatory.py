@@ -241,8 +241,6 @@ def test_gate_passes_on_repo_history(devices):
     assert report.checked, "gate checked nothing — vacuous pass"
     assert any(s.startswith("comms.") for s in report.checked)
     assert any(s == "fingerprint.ring.ppermute" for s in report.checked)
-    # wedge-honest: the 4 wedged rounds are RECORDED, not silently passed
-    assert any("wedge record" in n for n in report.notes)
 
 
 def test_committed_baseline_schema():

@@ -47,8 +47,8 @@ def test_fwd_gqa(rng):
 
 
 def test_head_chunked_launch_bit_exact(rng):
-    """head_chunks splits the launch into per-head-group programs (the
-    relay program-size workaround for h=32 @ 262k); heads are independent,
+    """head_chunks splits the launch into per-head-group programs (a
+    program-size escape hatch); heads are independent,
     so outputs AND grads must be bit-identical to the unsplit launch."""
     q, k, v = make_qkv(rng, h=8, hk=4)
 
@@ -600,8 +600,8 @@ def test_decode_kernel_row_padding(rng, dtype, atol):
 
 
 def test_exp2_log2_space_parity(rng, monkeypatch):
-    """RING_ATTN_EXP2=1 (log2-space scoring, docs/hardware_log.md round-5
-    roofline note) is value-identical at the kernel boundary: fwd outputs
+    """RING_ATTN_EXP2=1 (log2-space scoring, ROADMAP S3) is
+    value-identical at the kernel boundary: fwd outputs
     AND grads match the natural-basis oracle, including softclamp + mask
     + GQA, and the emitted lse stays in natural units."""
     monkeypatch.setenv("RING_ATTN_EXP2", "1")

@@ -355,7 +355,14 @@ def test_transformer_step_flops_and_mfu():
     # a step achieving exactly peak is MFU 1.0
     assert achieved_mfu(197e12 * 0.5, 0.5, 197.0) == pytest.approx(1.0)
     assert achieved_mfu(1.0, 0.0, 197.0) == 0.0
-    assert device_peak_tflops() > 0  # CPU falls back to the v5e figure
+    # a device the table does not list is an error, never a default
+    with pytest.raises(ValueError, match="no bf16 peak listed"):
+        device_peak_tflops()  # the CPU test backend
+
+    class _V5e:
+        device_kind = "TPU v5 lite"
+
+    assert device_peak_tflops(_V5e()) == 197.0
 
 
 def test_ring_comms_accounting_hybrid_factoring():
@@ -751,9 +758,11 @@ def test_train_example_writes_schema_valid_metrics(tmp_path):
     assert proc.returncode == 0, proc.stderr[-2000:]
     rows = [r for r in read_metrics(mdir) if "event" not in r]
     assert rows, "no metric rows written"
-    for field in ("loss", "grad_norm", "tokens_per_sec", "mfu",
+    for field in ("loss", "grad_norm", "tokens_per_sec",
                   "ring_hops", "skipped", "nonfinite", "step_ms_p95"):
         assert field in rows[-1], f"missing {field}: {sorted(rows[-1])}"
+    # the CPU has no listed peak, so no utilization is written for it
+    assert "mfu" not in rows[-1]
     assert rows[-1]["schema"] == SCHEMA_VERSION
     assert rows[-1]["ring_hops"] == 3  # 4-device ring: 3 hops
     # and the report tool renders it

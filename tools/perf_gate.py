@@ -70,9 +70,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--skip-compiled", action="store_true",
                     help="skip the reference-step compile (fingerprint + "
                          "arithmetic comms table still collected)")
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent XLA compile cache (reuse the test "
-                         "suite's tests/.jax_cache to make the gate cheap)")
     ap.add_argument("--note", default="",
                     help="free-form note stored in the baseline on "
                          "--update-baseline")
@@ -99,10 +96,11 @@ def main(argv: list[str] | None = None) -> int:
                                    baseline_path=baseline_path)
         return _emit(report, args)
 
-    if args.compile_cache_dir:
-        from ring_attention_tpu.utils import enable_compile_cache
+    # JAX_COMPILATION_CACHE_DIR places the cache (point it at
+    # tests/.jax_cache to reuse the test suite's compiles)
+    from ring_attention_tpu.utils import enable_compile_cache
 
-        enable_compile_cache(args.compile_cache_dir)
+    enable_compile_cache()
 
     strategies = args.strategies
     if strategies is None:

@@ -7,7 +7,8 @@ then parses the xplane protobuf to report where device time goes (the
 MXU/VPU/DMA split that directs the next MFU push).  Traces land in
 ``docs/hwlogs/xprof/``, the summary in ``docs/hwlogs/xprof_summary.txt``.
 
-Run only inside a healthy TPU window (tools/hw_session.sh step `xprof`).
+Run through the chip tool (only the process that holds the chip can
+trace it).
 """
 
 from __future__ import annotations
