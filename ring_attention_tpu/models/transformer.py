@@ -62,12 +62,15 @@ def _position_nll(
     ``(..., vocab)`` f32 array.  THE loss math shared by the dense and
     chunked CE paths — the chunked path's value-identity guarantee
     depends on both calling exactly this."""
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    chosen = jnp.take_along_axis(
-        lf, jnp.where(valid, labels, 0)[..., None], axis=-1
-    )[..., 0]
-    return jnp.where(valid, lse - chosen, 0.0)
+    # not a flax method, so it has no scope of its own: name it for the
+    # profiler's layer table (utils/profiling.py STAGES)
+    with jax.named_scope("loss/nll"):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        chosen = jnp.take_along_axis(
+            lf, jnp.where(valid, labels, 0)[..., None], axis=-1
+        )[..., 0]
+        return jnp.where(valid, lse - chosen, 0.0)
 
 
 class RingTransformer(nn.Module):

@@ -1,33 +1,28 @@
-"""Profiling: trace capture, step throughput, and the measured-overlap
-observatory.
+"""Profiling: trace capture, step throughput, and reading a capture back.
 
-The reference has no timers or profiler hooks at all (SURVEY §5).  On TPU
-the platform profiler (XProf via ``jax.profiler``) is the ground truth for
-MXU utilization and ICI overlap; this module adds the pieces a training
-loop actually calls — a trace context, named annotations, a
-step-throughput meter — plus the **reader** side: a stdlib-only parser
-for the ``.xplane.pb`` captures the profiler writes, a per-hop/per-stage
-timeline reconstruction keyed on the stack's stable ``jax.named_scope``
-names (``ring/hop{i}``, ``ring/rotate{i}``, ``ulysses/a2a_in``, …), and a
+On TPU the platform profiler (XProf via ``jax.profiler``) is the ground
+truth for where device time goes.  This module has what a training loop
+calls (a trace context, named annotations, a step-throughput meter) and
+the **reader** side: every device operation of an ``.xplane.pb`` capture
+joined to its ``jax.named_scope`` / flax module path, hence to a layer of
+the program and a pass of the step (:func:`layer_breakdown`), a
+per-hop/per-stage timeline keyed on the stack's stable scope names
+(``ring/hop{i}``, ``ring/rotate{i}``, ``ulysses/a2a_in``, …), and a
 *measured* compute/transfer overlap fraction to sit next to the analytic
-one from ``telemetry.ring_comms_accounting`` — Ring Attention's whole
-premise ("KV hops hide under blockwise compute") as a number read off the
-hardware timeline, not a model (docs/observability.md §Observatory).
+one from ``telemetry.ring_comms_accounting`` (docs/observability.md §5.1).
 
-Like ``telemetry.py``/``resilience.py``, this module is stdlib-only at
-module level (jax is imported inside functions), so ``tools/
-trace_report.py`` can load it by file path on a box where jax cannot
-import.  The xplane parser is a ~150-line protobuf wire-format reader —
-the TensorFlow proto stubs this image lacks are NOT required: op events
-carry HLO instruction names and a ``program_id``, the ``/host:metadata``
-plane embeds each program's ``HloProto``, and joining the two recovers
-the full ``op_name`` scope path for every event.
+Stdlib-only at module level (jax is imported inside functions), so
+``tools/trace_report.py`` can load it by file path.  Events are read with
+``jax.profiler.ProfileData``; the scope paths, which it does not expose,
+with a ~100-line protobuf wire-format reader.
 """
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import glob
+import gzip
 import os
 import re
 import statistics
@@ -177,14 +172,15 @@ def percentile(values: list[float], q: float) -> float:
 
 
 # ----------------------------------------------------------------------
-# xplane.pb wire-format parser (stdlib-only)
+# Reading a capture: events from ProfileData, scopes from the wire format
 # ----------------------------------------------------------------------
 #
-# Field numbers below are the stable public schema of
-# tensorflow/tsl/profiler/protobuf/xplane.proto and xla/service/hlo.proto
-# (unchanged across every TF/XLA release this stack can meet).  Only the
-# fields the observatory needs are decoded; unknown fields are skipped by
-# wire type, so schema additions cannot break the reader.
+# ``jax.profiler.ProfileData`` reads planes, lines and events, but not the
+# ``/host:metadata`` plane's ``HloProto`` blobs, which alone hold each
+# instruction's ``op_name`` path (``jit(step)/.../ff_layers_0/.../dot``).
+# The wire-format reader below reads just those.  Field numbers are the
+# public schema of tsl/profiler/protobuf/xplane.proto and
+# xla/service/hlo.proto; unknown fields are skipped by wire type.
 
 
 def _varint(buf: bytes, i: int) -> tuple[int, int]:
@@ -227,35 +223,97 @@ def _wire_fields(buf: bytes) -> Iterator[tuple[int, int, Any]]:
         yield fn, wt, v
 
 
+def _sub(buf: bytes, field: int) -> Iterator[Any]:
+    return (v for fn, _, v in _wire_fields(buf) if fn == field)
+
+
 def _hlo_scopes(hlo_proto: bytes) -> dict[str, str]:
     """``{instruction_name: op_name}`` from a serialized ``HloProto``.
 
     HloProto.hlo_module=1 -> HloModuleProto.computations=3 ->
     HloComputationProto.instructions=2 -> HloInstructionProto.name=1 /
     .metadata=7 -> OpMetadata.op_name=2 (the ``jit(f)/…/ring/hop0/…``
-    scope path the named_scope annotations put there).
+    path the named_scope annotations put there), .id=35, .operand_ids=36.
+
+    What the compiler inserts itself (a ``copy-start`` that prefetches a
+    weight, a layout ``copy``) has no path, or only its argument's name
+    (``cache['k'][0]``).  It takes the path of the first instruction that
+    uses it, else of its first operand: a weight's prefetch belongs to
+    the layer that multiplies by it.
     """
-    out: dict[str, str] = {}
-    for fn, _, module in _wire_fields(hlo_proto):
-        if fn != 1:
-            continue
-        for mfn, _, comp in _wire_fields(module):
-            if mfn != 3:
-                continue
-            for cfn, _, instr in _wire_fields(comp):
-                if cfn != 2:
-                    continue
+    scopes: dict[int, str] = {}
+    names: dict[int, str] = {}
+    operands: dict[int, list[int]] = {}
+    for module in _sub(hlo_proto, 1):
+        for comp in _sub(module, 3):
+            for instr in _sub(comp, 2):
                 name = scope = ""
-                for ifn, _, val in _wire_fields(instr):
-                    if ifn == 1:
+                uid = len(names)
+                ops: list[int] = []
+                for fn, wt, val in _wire_fields(instr):
+                    if fn == 1:
                         name = val.decode(errors="replace")
-                    elif ifn == 7:
-                        for ofn, _, oval in _wire_fields(val):
-                            if ofn == 2:
-                                scope = oval.decode(errors="replace")
-                if name and scope:
-                    out[name] = scope
-    return out
+                    elif fn == 7:
+                        scope = next(_sub(val, 2), b"").decode(errors="replace")
+                    elif fn == 35 and wt == 0:
+                        uid = val
+                    elif fn == 36 and wt == 0:
+                        ops.append(val)
+                    elif fn == 36:  # packed
+                        i = 0
+                        while i < len(val):
+                            v, i = _varint(val, i)
+                            ops.append(v)
+                names[uid], scopes[uid], operands[uid] = name, scope, ops
+    users: dict[int, list[int]] = {}
+    for uid, ops in operands.items():
+        for op in ops:
+            users.setdefault(op, []).append(uid)
+    pathless = [u for u, s in scopes.items() if "/" not in s]
+    for _ in range(4):  # copy-start -> copy-done -> fusion is two rounds
+        for uid in pathless:
+            near = users.get(uid, []) + operands[uid]
+            scopes[uid] = next((scopes[n] for n in near
+                                if "/" in scopes.get(n, "")), scopes[uid])
+        pathless = [u for u in pathless if "/" not in scopes[u]]
+    return {names[u]: s for u, s in scopes.items() if names[u] and s}
+
+
+_PROGRAM_ID = re.compile(r"\((\d+)\)$")
+
+
+def _index_metadata_plane(
+    plane: bytes,
+    by_id: dict[int, dict[str, str]],
+    by_module: dict[str, dict[str, str]],
+) -> None:
+    """The ``/host:metadata`` plane: each event-metadata entry (field 4,
+    its XEventMetadata in field 2) is one profiled program whose
+    ``hlo_proto`` stat holds the serialized HloProto.  Indexed by the
+    entry's id (on a TPU the program's fingerprint, which also ends the
+    name of its ``XLA Modules`` events) and by the module's name less that
+    id (a CPU executable loaded from the compile cache runs under another
+    id than the one its HloProto was stored with)."""
+    for entry in _sub(plane, 4):
+        for meta in _sub(entry, 2):
+            meta_id = None
+            module_name = ""
+            blobs: list[bytes] = []
+            for fn, wt, val in _wire_fields(meta):
+                if fn == 1 and wt == 0:
+                    meta_id = val
+                elif fn == 2:
+                    module_name = _PROGRAM_ID.sub(
+                        "", val.decode(errors="replace"))
+                elif fn == 3:  # raw metadata bytes
+                    blobs.append(val)
+                elif fn == 5:  # XStat whose bytes_value carries the proto
+                    blobs.extend(_sub(val, 6))
+            for scopes in filter(None, map(_hlo_scopes, blobs)):
+                if meta_id is not None:
+                    by_id.setdefault(meta_id, {}).update(scopes)
+                if module_name:
+                    by_module.setdefault(module_name, {}).update(scopes)
 
 
 class OpEvent(NamedTuple):
@@ -269,284 +327,275 @@ class OpEvent(NamedTuple):
     kind: str       # "compute" | "transfer" | "other"
     start_ns: int
     dur_ns: int
+    chip: int = 0       # device ordinal
+    self_ns: int = 0    # dur_ns less the ops that ran inside it (a while's body)
+    layer: str = "other"    # the program's layer (STAGES' fourth column)
+    pass_: str = "forward"  # forward | recompute | backward | update
 
     @property
     def end_ns(self) -> int:
         return self.start_ns + self.dur_ns
 
 
-# stage buckets keyed on the stable scope/kernel names threaded through
-# parallel/ and ops/ (docs/observability.md §4): (needle, label, kind),
-# first match wins.  "transfer" = inter-device payload movement the ring
-# schedule wants hidden under "compute".
-STAGES: list[tuple[str, str, str]] = [
-    ("ring/rotate", "ring kv rotation", "transfer"),
-    ("ring/catchup", "ring dkv catch-up", "transfer"),
-    ("ring/bwd", "ring backward", "compute"),
-    ("ring/hop", "ring hop compute", "compute"),
+class HostEvent(NamedTuple):
+    """A span of the host's: a ``TraceAnnotation`` or one of the runtime's."""
+
+    name: str
+    start_ns: int
+    dur_ns: int
+
+
+class Capture(NamedTuple):
+    ops: list[OpEvent]
+    host: list[HostEvent]
+    programs: list[tuple[int, str, int, int]]  # (chip, name, start_ns, dur_ns)
+    note: str = ""  # why nothing could be read; empty on success
+
+
+# Scope/kernel names -> (needle, stage, kind, layer, pass where the name
+# fixes it).  First match wins.  First the stable names threaded through
+# parallel/ and ops/ (docs/observability.md §4; "transfer" = inter-device
+# payload movement the ring schedule wants hidden under "compute"), then
+# the model's own modules, which flax names for free, and utils/train.py's
+# ``train/*`` scopes.
+_C, _T, _K, _L = "compute", "transfer", "flash kernels", "loss and head"
+STAGES: list[tuple[str, str, str, str, str | None]] = [
+    ("ring/rotate", "ring kv rotation", _T, "ring", None),
+    ("ring/catchup", "ring dkv catch-up", _T, "ring", "backward"),
+    ("ring/bwd", "ring backward", _C, "ring", "backward"),
+    ("ring/hop", "ring hop compute", _C, "ring", None),
     # fused ring (ops/pallas_ring.py): the CPU-degradable local tier's
     # KV gather is transfer; the single launch itself is compute — its
     # in-kernel remote DMAs never surface as separate timeline ops, which
     # is exactly the launch-free-hops property (docs/ring_overlap.md)
-    ("ring/fused_gather", "fused ring kv gather", "transfer"),
-    ("ring/fused", "fused ring kernel", "compute"),
-    ("kv_head_reshard", "gqa kv reshard", "transfer"),
-    ("ulysses/a2a", "ulysses all-to-all", "transfer"),
-    ("ulysses/flash", "ulysses local flash", "compute"),
-    ("hybrid/a2a", "hybrid all-to-all", "transfer"),
-    ("hybrid/inner", "hybrid inner ring", "compute"),
-    ("zigzag/gather", "zigzag gather", "transfer"),
-    ("zigzag/", "zigzag", "compute"),
-    ("tree_decode/gather", "tree-decode merge", "transfer"),
-    ("tree_decode/", "tree-decode local", "compute"),
-    ("flash_bwd", "flash backward kernel", "compute"),  # pallas kernel name
-    ("flash/bwd", "flash backward", "compute"),  # XLA-path named_scope
-    ("flash_decode", "flash decode kernel", "compute"),
-    ("flash", "flash forward kernel", "compute"),
+    ("ring/fused_gather", "fused ring kv gather", _T, "ring", None),
+    ("ring/fused", "fused ring kernel", _C, "ring", None),
+    ("fused_ring", "fused ring kernel", _C, _K, None),
+    ("kv_head_reshard", "gqa kv reshard", _T, "ring", None),
+    ("ulysses/a2a", "ulysses all-to-all", _T, "ring", None),
+    ("ulysses/flash", "ulysses local flash", _C, "ring", None),
+    ("hybrid/a2a", "hybrid all-to-all", _T, "ring", None),
+    ("hybrid/inner", "hybrid inner ring", _C, "ring", None),
+    ("zigzag/gather", "zigzag gather", _T, "ring", None),
+    ("zigzag/", "zigzag", _C, "ring", None),
+    ("tree_decode/gather", "tree-decode merge", _T, "ring", None),
+    ("tree_decode/", "tree-decode local", _C, "ring", None),
+    # pallas kernel names, and the XLA blockwise path's named_scopes
+    ("flash_bwd", "flash backward kernel", _C, _K, "backward"),
+    ("flash/bwd", "flash backward", _C, "xla flash", "backward"),
+    ("flash_decode", "flash decode kernel", _C, _K, None),
+    ("flash/fwd", "flash forward", _C, "xla flash", None),
+    ("flash", "flash forward kernel", _C, _K, None),
+    ("train/optimizer", "optimizer update", _C, "optimizer", "update"),
+    ("train/clip", "gradient clip and guard", _C, "optimizer", "update"),
+    ("train/accumulate", "gradient accumulation", _C, "optimizer", "update"),
+    ("_chunked_ce", "loss", _C, _L, None),
+    ("loss/nll", "loss", _C, _L, None),
+    ("_valid_labels", "loss", _C, _L, None),
+    ("to_logits", "logits head", _C, _L, None),
+    ("final_norm", "final norm", _C, _L, None),
+    ("ff_layers_", "feed-forward", _C, "feed-forward", None),
+    ("attn_layers_", "attention projections", _C, "attention projections", None),
+    ("embed", "embedding", _C, "embed", None),
 ]
 
-# instruction-name prefixes that are payload movement even when no scope
-# attributed them (an unattributed collective is itself a finding — RA004
-# lints the source side of this)
+# instruction-name prefixes that are payload movement wherever they sit
+# (an unattributed collective is itself a finding — RA004 lints the source
+# side of this); jax 0.9 names the CPU's after the primitive
 _COLLECTIVE_PREFIXES = (
     "collective-permute", "all-to-all", "all-gather", "all-reduce",
-    "reduce-scatter", "collective-broadcast",
+    "reduce-scatter", "collective-broadcast", "ppermute", "all_to_all",
+    "all_gather", "psum",
 )
+_PERMUTE_PREFIXES = ("collective-permute", "ppermute")
+_CONTAINER_PREFIXES = ("while", "conditional", "call")
 
+_SUFFIX = re.compile(r"(\.(\d+|clone|remat))+$")  # "fusion.12.clone" -> "fusion"
 _HOP_RE = re.compile(r"ring/(?:bwd_)?hop(\d+)")
 _ROTATE_RE = re.compile(r"ring/rotate(\d+)")
 
 
+def _stage_row(hay: str, kernels: bool = True):
+    hay = hay.lower()
+    return next((row for row in STAGES if row[0] in hay
+                 and (kernels or row[3] != _K)), None)
+
+
 def stage_of(name: str, scope: str = "") -> tuple[str, str]:
     """``(label, kind)`` for an op: scope needles first (first match in
-    STAGES wins), then the bare-collective fallback, else ``other``."""
-    hay = (scope or name).lower()
-    for needle, label, kind in STAGES:
-        if needle in hay:
-            return label, kind
-    if name.startswith(_COLLECTIVE_PREFIXES):
+    STAGES wins), then the bare-collective fallback, else ``other``.  A
+    collective is a transfer whatever scope it sits in."""
+    row = _stage_row(scope or name)
+    collective = name.startswith(_COLLECTIVE_PREFIXES)
+    if row and collective and row[2] != "transfer":
+        return f"collective in {row[1]}", "transfer"
+    if row:
+        return row[1], row[2]
+    if collective:
         return "unattributed collective", "transfer"
     return "other", "other"
 
 
-def _xplane_paths(path: str) -> list[str]:
-    if os.path.isdir(path):
-        return sorted(
-            glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True),
-            key=os.path.getmtime,
-        )
-    return [path]
+def layer_of(name: str, scope: str = "") -> tuple[str, str]:
+    """``(layer, pass)`` for an op.  A kernel is named by its own name
+    wherever it is called from (a ``flash_partials_tile`` inside
+    ``ring/hop2`` is a flash kernel) and by nothing else: the glue XLA
+    runs beside it is its scope's.  Everything else goes by its scope.
+    The pass is the row's where the name fixes it, else what JAX wrote
+    into the path: ``rematted_computation`` under ``nn.remat``'s backward,
+    ``transpose(jvp(...))`` for the backward itself."""
+    row = _stage_row(name) or _stage_row(scope, kernels=False)
+    layer = row[3] if row else (
+        "ring" if name.startswith(_COLLECTIVE_PREFIXES) else "other")
+    if row and row[4]:
+        return layer, row[4]
+    if "rematted_computation" in scope:
+        return layer, "recompute"
+    return layer, "backward" if "transpose(" in scope else "forward"
+
+
+def _self_times(spans: list[tuple[int, int]]) -> list[int]:
+    """Self time of each ``(start, end)`` on one chip: the time during
+    which it is the innermost span open.  A ``while`` keeps what its body
+    leaves; the self times sum to the union of the spans exactly."""
+    out = [0] * len(spans)
+    order = sorted(range(len(spans)),
+                   key=lambda i: (spans[i][0], -spans[i][1]))
+    stack, at = [], 0  # indices of the spans that are open, innermost last
+    for i in [*order, None]:  # None: drain what is still open
+        until = spans[i][0] if i is not None else max(
+            (end for _, end in spans), default=0)
+        while stack and at < until:  # the innermost open span has [at, ...)
+            end = spans[stack[-1]][1]
+            if end > at:
+                out[stack[-1]] += min(end, until) - at
+                at = min(end, until)
+            if end <= at:
+                stack.pop()
+        at = max(at, until)
+        if i is not None:
+            stack.append(i)
+    return out
+
+
+_DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:(\d+)$")
+
+
+def read_capture(path: str) -> Capture:
+    """The newest ``*.xplane.pb[.gz]`` under ``path`` (or the file itself)
+    as device ops with their scopes, host events, and which program ran
+    when.  Never raises: the note says why there is nothing."""
+    found = [path] if not os.path.isdir(path) else sorted(
+        (p for ext in ("*.xplane.pb", "*.xplane.pb.gz") for p in glob.glob(
+            os.path.join(path, "**", ext), recursive=True)),
+        key=os.path.getmtime)
+    if not found:
+        return Capture([], [], [], f"no .xplane.pb under {path}")
+    by_id: dict[int, dict[str, str]] = {}
+    by_module: dict[str, dict[str, str]] = {}
+    try:
+        from jax.profiler import ProfileData  # what reads the events
+
+        with open(found[-1], "rb") as f:
+            data = f.read()
+        if found[-1].endswith(".gz"):
+            data = gzip.decompress(data)
+        for plane in _sub(data, 1):
+            if b"metadata" in next(_sub(plane, 2), b""):
+                _index_metadata_plane(plane, by_id, by_module)
+        planes = list(ProfileData.from_serialized_xspace(data).planes)
+        capture = _join(planes, by_id, by_module)
+    except ImportError as e:
+        return Capture([], [], [], f"jax cannot be imported here: {e}")
+    except Exception as e:  # noqa: BLE001 - unreadable, or truncated by a
+        # killed profiler: a note, because the timeline is a diagnostic
+        return Capture([], [], [], f"unreadable or malformed capture "
+                                   f"{found[-1]}: {type(e).__name__}: {e}")
+    if not capture.ops:
+        return capture._replace(note=f"no op events parsed from {found[-1]}")
+    return capture
 
 
 def read_xplane_events(path: str) -> tuple[list[OpEvent], str]:
-    """Parse the newest ``*.xplane.pb`` under ``path`` (or the file
-    itself) into resolved :class:`OpEvent` rows.
-
-    Returns ``(events, note)`` — ``note`` is a human-readable degradation
-    reason when nothing could be parsed (missing capture, no op events),
-    empty on success.  Never raises on malformed input: the timeline is a
-    diagnostic, not a gate.
-    """
-    paths = _xplane_paths(path)
-    if not paths:
-        return [], f"no .xplane.pb under {path}"
-    try:
-        data = open(paths[-1], "rb").read()
-    except OSError as e:
-        return [], f"unreadable capture: {e}"
-    # program_id -> {instruction: scope}; module_name -> same (fallback)
-    scopes_by_id: dict[int, dict[str, str]] = {}
-    scopes_by_module: dict[str, dict[str, str]] = {}
-    op_planes: list[bytes] = []
-    try:
-        for fn, _, plane in _wire_fields(data):
-            if fn != 1:
-                continue
-            pname = ""
-            for pfn, _, pval in _wire_fields(plane):
-                if pfn == 2:
-                    pname = pval.decode(errors="replace")
-                    break
-            if "metadata" in pname:
-                _index_metadata_plane(plane, scopes_by_id, scopes_by_module)
-            else:
-                op_planes.append(plane)
-        events: list[OpEvent] = []
-        for plane in op_planes:
-            events.extend(
-                _plane_events(plane, scopes_by_id, scopes_by_module)
-            )
-    except (IndexError, ValueError, OverflowError) as e:
-        # a capture truncated mid-write (killed profiler — the wedge mode
-        # this repo knows well) degrades to a note, never a traceback
-        return [], (
-            f"malformed capture {paths[-1]}: {type(e).__name__}: {e}"
-        )
-    if not events:
-        return [], f"no op events parsed from {paths[-1]}"
-    return events, ""
+    """``(ops, note)`` of :func:`read_capture`; never raises."""
+    capture = read_capture(path)
+    return capture.ops, capture.note
 
 
-def _index_metadata_plane(
-    plane: bytes,
-    by_id: dict[int, dict[str, str]],
-    by_module: dict[str, dict[str, str]],
-) -> None:
-    """The ``/host:metadata`` plane: each event-metadata entry is one
-    profiled program; its ``hlo_proto`` stat holds the serialized
-    HloProto whose OpMetadata carries the named_scope paths."""
-    for pfn, _, entry in _wire_fields(plane):
-        if pfn != 4:  # event_metadata map entry
-            continue
-        for efn, _, meta in _wire_fields(entry):
-            if efn != 2:  # XEventMetadata
-                continue
-            meta_id = None
-            module_name = ""
-            blobs: list[bytes] = []
-            for mfn, mwt, mval in _wire_fields(meta):
-                if mfn == 1 and mwt == 0:
-                    meta_id = mval
-                elif mfn == 2:
-                    module_name = mval.decode(errors="replace")
-                elif mfn in (3, 5):
-                    # field 3: raw metadata bytes; field 5: XStat whose
-                    # bytes_value (field 6) carries the proto — both
-                    # spellings exist in the wild
-                    if mfn == 3:
-                        blobs.append(mval)
-                    else:
-                        for sfn, _, sval in _wire_fields(mval):
-                            if sfn == 6:
-                                blobs.append(sval)
-            for blob in blobs:
-                scopes = _hlo_scopes(blob)
-                if not scopes:
-                    continue
-                if meta_id is not None:
-                    by_id.setdefault(meta_id, {}).update(scopes)
-                if module_name:
-                    by_module.setdefault(module_name, {}).update(scopes)
+def _join(planes, by_id, by_module) -> Capture:
+    """Ops of every chip joined to their scopes.  On a TPU a chip is a
+    ``/device:TPU:<n>`` plane: its ``XLA Ops`` events carry only times and
+    the instruction's text, and the program is the enclosing event of the
+    ``XLA Modules`` line.  A CPU capture has no device plane: its ops sit
+    among the host threads' events and carry ``hlo_op``, ``program_id``
+    and ``device_ordinal`` stats."""
+    raw: list[tuple] = []  # plane, line, chip, instruction, scopes, start, dur
+    host: list[HostEvent] = []
+    programs: list[tuple[int, str, int, int]] = []
+    on_device = any(_DEVICE_PLANE.match(p.name) for p in planes)
 
+    def scopes_of(program_id, module):
+        return by_id.get(program_id) or by_module.get(module) or (
+            next(iter(by_module.values())) if len(by_module) == 1 else {})
 
-def _plane_events(
-    plane: bytes,
-    by_id: dict[int, dict[str, str]],
-    by_module: dict[str, dict[str, str]],
-) -> list[OpEvent]:
-    pname = ""
-    metas: dict[int, str] = {}
-    stat_names: dict[int, str] = {}
-    lines: list[bytes] = []
-    for pfn, _, pval in _wire_fields(plane):
-        if pfn == 2:
-            pname = pval.decode(errors="replace")
-        elif pfn == 3:
-            lines.append(pval)
-        elif pfn == 4:  # event_metadata map entry -> id, name
-            mid, mname = None, ""
-            for efn, ewt, meta in _wire_fields(pval):
-                if efn == 1 and ewt == 0:  # map key == metadata id
-                    mid = meta
-                elif efn == 2:  # XEventMetadata
-                    for mfn, mwt, mval in _wire_fields(meta):
-                        if mfn == 1 and mwt == 0:
-                            mid = mval
-                        elif mfn == 2:
-                            mname = mval.decode(errors="replace")
-            if mid is not None:
-                metas[mid] = mname
-        elif pfn == 5:  # stat_metadata map entry -> id, name
-            sid, sname = None, ""
-            for efn, _, meta in _wire_fields(pval):
-                if efn == 1:
-                    sid = meta
-                elif efn == 2:
-                    for mfn, mwt, mval in _wire_fields(meta):
-                        if mfn == 1 and mwt == 0:
-                            sid = mval
-                        elif mfn == 2:
-                            sname = mval.decode(errors="replace")
-            if sid is not None:
-                stat_names[sid] = sname
-    out: list[OpEvent] = []
-    parsed_lines: list[tuple[str, int, list[bytes]]] = []
-    for line_buf in lines:
-        lname = ""
-        ts_ns = 0
-        evs: list[bytes] = []
-        for lfn, lwt, lval in _wire_fields(line_buf):
-            if lfn == 2:
-                lname = lval.decode(errors="replace")
-            elif lfn == 3 and lwt == 0:
-                ts_ns = lval
-            elif lfn == 4:
-                evs.append(lval)
-        parsed_lines.append((lname, ts_ns, evs))
-    # device planes (TPU) carry an "XLA Ops" line plus DERIVED lines
-    # (step, framework-name-scope) describing the same wall-clock spans;
-    # counting both would double every op.  When a plane has op lines,
-    # only they enter the timeline; CPU planes (one thunk line per
-    # thread, no derived lines) keep everything.
-    op_lines = [pl for pl in parsed_lines if "XLA Ops" in pl[0]]
-    if op_lines:
-        parsed_lines = op_lines
-    for lname, ts_ns, evs in parsed_lines:
-        for ev in evs:
-            mid = None
-            offset_ps = dur_ps = 0
-            program_id = None
-            module_ref = None
-            for efn, ewt, eval_ in _wire_fields(ev):
-                if efn == 1 and ewt == 0:
-                    mid = eval_
-                elif efn == 2 and ewt == 0:
-                    offset_ps = eval_
-                elif efn == 3 and ewt == 0:
-                    dur_ps = eval_
-                elif efn == 4:  # XStat
-                    smid = None
-                    val = None
-                    for sfn, swt, sval in _wire_fields(eval_):
-                        if sfn == 1 and swt == 0:
-                            smid = sval
-                        elif sfn in (3, 4, 7) and swt == 0:
-                            val = sval
-                    sname = stat_names.get(smid, "")
-                    if sname == "program_id":
-                        program_id = val
-                    elif sname == "hlo_module" and val is not None:
-                        module_ref = stat_names.get(val, "")
-            name = metas.get(mid, "")
-            if not name or not dur_ps:
-                continue
-            if program_id is None and not module_ref:
-                # only HLO-attributed op events enter the timeline: host
-                # python-tracer/TraceMe spans (a dispatch wrapper named
-                # after the jitted fn, a ThreadpoolListener) would
-                # otherwise bucket as compute and corrupt busy time and
-                # the measured overlap (the needle match runs on NAMES
-                # when no scope resolves)
-                continue
-            scope = ""
-            if program_id is not None and program_id in by_id:
-                scope = by_id[program_id].get(name, "")
-            if not scope and module_ref and module_ref in by_module:
-                scope = by_module[module_ref].get(name, "")
-            if not scope and len(by_module) == 1:
-                scope = next(iter(by_module.values())).get(name, "")
-            label, kind = stage_of(name, scope)
-            out.append(OpEvent(
-                plane=pname, line=lname, name=name, scope=scope,
-                stage=label, kind=kind,
-                start_ns=ts_ns * 1000 + offset_ps,  # both in picoseconds
-                dur_ns=dur_ps,
-            ))
-    # start/dur computed in ps above; convert once here so one unit rules
-    return [
-        e._replace(start_ns=e.start_ns // 1000, dur_ns=max(e.dur_ns // 1000, 1))
-        for e in out
-    ]
+    for plane in planes:
+        device = _DEVICE_PLANE.match(plane.name)
+        lines = {line.name: line.events for line in plane.lines}
+        if device:
+            chip = int(device.group(1))
+            ran = sorted((int(e.start_ns), int(e.duration_ns), e.name)
+                         for e in lines.get("XLA Modules", ()))
+            programs += [(chip, _PROGRAM_ID.sub("", n), s, d)
+                         for s, d, n in ran]
+            starts = [s for s, _, _ in ran]
+            tables = {"": scopes_of(None, "")}
+            for _, _, n in ran:
+                found = _PROGRAM_ID.search(n)
+                tables[n] = scopes_of(found and int(found.group(1)),
+                                      _PROGRAM_ID.sub("", n))
+            for e in lines.get("XLA Ops", ()):
+                start = int(e.start_ns)
+                i = bisect.bisect_right(starts, start) - 1
+                inside = i >= 0 and start < ran[i][0] + ran[i][1]
+                # "%fusion.12 = bf16[...] fusion(...)" -> "fusion.12"
+                raw.append((plane.name, "XLA Ops", chip,
+                            e.name.lstrip("%").split(" ", 1)[0],
+                            tables[ran[i][2] if inside else ""],
+                            start, int(e.duration_ns)))
+        elif plane.name.startswith("/host:"):
+            for lname, events in lines.items():
+                for e in events:
+                    stats = {} if on_device else dict(e.stats)
+                    if "hlo_op" in stats and "program_id" in stats:
+                        raw.append((
+                            plane.name, lname,
+                            int(stats.get("device_ordinal", 0)),
+                            str(stats["hlo_op"]),
+                            scopes_of(int(stats["program_id"]),
+                                      str(stats.get("hlo_module", ""))),
+                            int(e.start_ns), int(e.duration_ns)))
+                    elif e.duration_ns > 0:
+                        host.append(HostEvent(
+                            e.name, int(e.start_ns), int(e.duration_ns)))
+    selfs = [0] * len(raw)
+    for chip in {r[2] for r in raw}:
+        members = [i for i, r in enumerate(raw) if r[2] == chip]
+        for i, t in zip(members, _self_times(
+                [(raw[i][5], raw[i][5] + raw[i][6]) for i in members])):
+            selfs[i] = t
+    memo: dict[tuple, tuple] = {}
+    ops = []
+    for (pname, lname, chip, name, table, start, dur), self_ns in zip(
+            raw, selfs):
+        key = (id(table), name)
+        if key not in memo:
+            scope = table.get(name, "")
+            memo[key] = (scope, *stage_of(name, scope),
+                         *layer_of(name, scope))
+        scope, stage, kind, layer, pass_ = memo[key]
+        ops.append(OpEvent(pname, lname, name, scope, stage, kind, start,
+                           max(dur, 1), chip, self_ns, layer, pass_))
+    return Capture(ops, host, programs)
 
 
 # ----------------------------------------------------------------------
@@ -567,24 +616,18 @@ def stage_timeline(
                      "samples"}, ...],                  # hop index order
          "total_busy_ms": float}
 
-    ``p50/p95`` are over stage *instances* — one sample per (line, stage,
-    hop-index) group, i.e. per device-thread occurrence — not per HLO op,
-    so a hop that fragments into 40 fusions still reads as one latency
-    sample.  ``hops`` resolves per-hop indices into the compute-vs-
-    transfer table the overlap story is about: the unrolled Pallas path
-    carries static ``ring/hop{i}`` / ``ring/rotate{i}`` scope indices; the
+    Times are self times.  ``p50/p95`` are over stage *instances*, one
+    sample per (line, stage, hop-index) group, not per HLO op: a hop that
+    fragments into 40 fusions is one latency sample.  The unrolled Pallas
+    path carries static ``ring/hop{i}`` / ``ring/rotate{i}`` indices; the
     XLA scan path re-runs ONE set of instructions per hop, so its indices
-    are reconstructed temporally — on each timeline line, hop ``i`` is
-    whatever runs after the line's ``i``-th completed KV rotation (an
-    approximation when a thread pool interleaves devices on one line, so
-    ``hops`` rows carry their ``samples`` count for sanity).
+    are reconstructed temporally: on each line, hop ``i`` is whatever runs
+    after the line's ``i``-th completed KV rotation (approximate when a
+    thread pool interleaves devices on one line, hence ``samples``).
 
-    A capture should normally cover ONE step (the xprof_capture
-    practice); for a multi-step capture pass ``ring_size`` so hop indices
-    fold modulo the ring and each step contributes its own latency
-    sample (hop-index DECREASES on a line mark the step boundary —
-    without ``ring_size`` the scan path's temporal counter keeps
-    growing and a multi-step capture reads as one long hop sequence).
+    For a multi-step capture pass ``ring_size``: hop indices fold modulo
+    the ring and each step contributes its own latency sample (a hop
+    index that DECREASES on a line marks the step boundary).
     """
     instances: dict[tuple, float] = {}
     stage_events: dict[str, int] = {}
@@ -604,7 +647,7 @@ def stage_timeline(
             hop = int(m.group(1))
         elif ev.stage == "ring kv rotation":
             hop = rotations_seen.get(line_key, 0)
-            if ev.name.startswith("collective-permute"):
+            if ev.name.startswith(_PERMUTE_PREFIXES):
                 # the permute op itself advances the line's hop counter;
                 # its satellite copies/converts stay on the same index
                 rotations_seen[line_key] = hop + 1
@@ -623,13 +666,13 @@ def stage_timeline(
             cycle = cycles.get(line_key, 0)
         key = (ev.plane, ev.line, ev.stage, hop, cycle)
         first = key not in instances
-        instances[key] = instances.get(key, 0.0) + ev.dur_ns / 1e6
+        instances[key] = instances.get(key, 0.0) + ev.self_ns / 1e6
         stage_events[ev.stage] = stage_events.get(ev.stage, 0) + 1
         stage_kind[ev.stage] = ev.kind
         if hop is not None:
             slot = hop_busy.setdefault(hop, {"compute": 0.0, "transfer": 0.0})
             if ev.kind in slot:
-                slot[ev.kind] += ev.dur_ns / 1e6
+                slot[ev.kind] += ev.self_ns / 1e6
             if first:
                 hop_samples[hop] = hop_samples.get(hop, 0) + 1
     per_stage: dict[str, list[float]] = {}
@@ -679,24 +722,23 @@ def _merge_intervals(spans: list[tuple[int, int]]) -> list[tuple[int, int]]:
 def measured_overlap(events: list[OpEvent]) -> dict[str, Any]:
     """Measured compute/transfer overlap over one capture.
 
-    Walks the wall-clock timeline: merges all transfer spans (KV
-    rotations, all-to-alls, catch-up permutes) and all compute spans into
-    interval unions, and reports what fraction of transfer wall time ran
-    concurrently with compute anywhere on the chip —
+    Merges all transfer spans (KV rotations, all-to-alls, catch-up
+    permutes) and all compute spans into interval unions and reports what
+    fraction of transfer wall time ran concurrently with compute —
     ``overlap_fraction = overlapped_ms / transfer_ms`` (0.0 when the
-    capture has no transfer spans; ``transfer_ms`` of 0 means the
-    schedule's communication never reached the timeline, which is its own
-    finding).  This is the empirical counterpart of
-    ``ring_comms_accounting``'s ``hop_overlap_fraction`` (compute time at
-    peak over max(compute, transfer at ICI bandwidth)): the analytic one
-    says whether the shapes *can* hide the hop, this one says whether the
-    schedule *did*.
+    capture has no transfer spans, which is its own finding).  The
+    empirical counterpart of ``ring_comms_accounting``'s
+    ``hop_overlap_fraction``: the analytic one says whether the shapes
+    *can* hide the hop, this one says whether the schedule *did*.
     """
+    # a while spans its whole body, transfers and all: leaves only
+    leaves = [e for e in events
+              if not e.name.startswith(_CONTAINER_PREFIXES)]
     transfer = _merge_intervals(
-        [(e.start_ns, e.end_ns) for e in events if e.kind == "transfer"]
+        [(e.start_ns, e.end_ns) for e in leaves if e.kind == "transfer"]
     )
     compute = _merge_intervals(
-        [(e.start_ns, e.end_ns) for e in events if e.kind == "compute"]
+        [(e.start_ns, e.end_ns) for e in leaves if e.kind == "compute"]
     )
     transfer_ns = sum(hi - lo for lo, hi in transfer)
     compute_ns = sum(hi - lo for lo, hi in compute)
@@ -731,11 +773,10 @@ def overlap_report(
 
     ``source`` is a capture directory/file or pre-parsed events;
     ``analytic`` is ``ring_comms_accounting(...)`` output (its
-    ``hop_overlap_fraction`` is used) or a bare fraction.  When both
-    numbers exist and disagree by more than ``tolerance``, the report
-    carries ``agrees=False`` plus a one-line ``finding`` — a model that
-    no longer describes the hardware is itself a regression
-    (docs/observability.md §Observatory).
+    ``hop_overlap_fraction`` is used) or a bare fraction.  When the two
+    disagree by more than ``tolerance``, the report carries
+    ``agrees=False`` plus a one-line ``finding``: a model that no longer
+    describes the hardware is itself a regression.
     """
     if isinstance(source, str):
         events, note = read_xplane_events(source)
@@ -763,3 +804,140 @@ def overlap_report(
                 f"model no longer describes this capture"
             )
     return report
+
+
+# ----------------------------------------------------------------------
+# Every device millisecond to a layer and a pass
+# ----------------------------------------------------------------------
+
+# What the host was doing while the device idled: the first activity that
+# any event open at that instant matches.  A launch still being enqueued
+# keeps the device waiting whatever else the host does: dispatch is first.
+HOST_ACTIVITIES: list[tuple[str, tuple[str, ...]]] = [
+    ("dispatch", ("PjitFunction", "LoadedExecutable", "ExecuteHelper",
+                  "ExecuteLaunch", "IssueSequencedEvent", "EnqueueProgram",
+                  "EnqueueContinuation", "AllocateAndFillTupleIndexTable")),
+    ("fetch", ("np.asarray", "D2H", "TransferFromDevice", "ToLiteral",
+               "Delinearize")),
+]
+
+
+def _extent(capture: Capture, window) -> tuple[int, int] | None:
+    if isinstance(window, tuple):
+        return window
+    names = {window} if isinstance(window, str) else set(window or ())
+    spans = [(o.start_ns, o.end_ns) for o in capture.ops] if window is None else [
+        (s, s + d) for n, s, d in [*capture.host, *(p[1:] for p in capture.programs)]
+        if n in names]
+    return (min(s for s, _ in spans), max(e for _, e in spans)) if spans else None
+
+
+def _host_split(idle, host, lo, hi) -> dict[tuple[str, str], int]:
+    """Idle nanoseconds by ``(innermost host event, activity)``: one sweep
+    over the idle intervals' and the host events' edges."""
+    edges = []  # (time, opens-after-closes, host index or None for idle)
+    for a, b in idle:
+        edges += [(a, 1, None), (b, 0, None)]
+    for i, h in enumerate(host):
+        if h.start_ns < hi and h.start_ns + h.dur_ns > lo:
+            edges += [(h.start_ns, 1, i), (h.start_ns + h.dur_ns, 0, i)]
+    edges.sort(key=lambda e: e[:2])
+    out: dict[tuple[str, str], int] = {}
+    open_: set[int] = set()
+    idling, at = False, lo
+    for t, opens, i in edges:
+        if idling and t > at:
+            names = [host[j].name for j in sorted(
+                open_, key=lambda j: (host[j].start_ns, -host[j].dur_ns))]
+            activity = next(
+                (a for a, needles in HOST_ACTIVITIES
+                 if any(n in name for name in names for n in needles)),
+                "other")
+            key = (names[-1] if names else "(no host event)", activity)
+            out[key] = out.get(key, 0) + t - at
+        at = max(at, t)
+        if i is None:
+            idling = bool(opens)
+        else:
+            (open_.add if opens else open_.discard)(i)
+    return out
+
+
+def layer_breakdown(capture: str | Capture, window=None, *, per=None,
+                    chip: int | None = None) -> dict[str, Any]:
+    """Every nanosecond of a traced window on one chip, put down to a
+    layer of the program and a pass of the step, or to idle.
+
+    ``window``: ``None`` for the extent of the device's ops; a name (or
+    several) for the extent of the host events or programs so named
+    (``"bench/step"``, ``"jit_decode_fn"``); or ``(lo_ns, hi_ns)``.
+    ``per``: a number of units, or a name whose events inside the window
+    are counted (steps, tokens); times are per unit.  ``chip``: default
+    the one that was busy longest.
+
+    ``rows``: ``{layer, pass, ms, share, ops, transfer_ms}`` by time, then
+    ``other`` rows for ops whose scope matched nothing (counted, never
+    dropped; ``other_ops`` names the largest) and an ``idle`` row; their
+    ``ms`` sum to ``window_ms`` and ``transfer_ms`` is the collectives'
+    part of a row.  ``idle_host`` splits the idle row by the innermost
+    host event open across it, whoever's it is, and ``idle_activity``
+    sums that into dispatch, fetch and other (:data:`HOST_ACTIVITIES`).
+    """
+    if isinstance(capture, str):
+        capture = read_capture(capture)
+    if capture.note:
+        return {"note": capture.note}
+    extent = _extent(capture, window)
+    if extent is None:
+        return {"note": f"nothing in the capture is named {window!r}"}
+    lo, hi = extent
+    clipped: dict[int, list[tuple[OpEvent, int, int]]] = {}
+    for o in capture.ops:
+        a, b = max(o.start_ns, lo), min(o.end_ns, hi)
+        if a < b:
+            clipped.setdefault(o.chip, []).append((o, a, b))
+    if not clipped:
+        return {"note": f"no device op inside the window {window!r}"}
+    times = {c: _self_times([(a, b) for _, a, b in members])
+             for c, members in clipped.items()}
+    if chip is None:
+        chip = max(times, key=lambda c: sum(times[c]))
+    if isinstance(per, str):
+        starts = [h.start_ns for h in capture.host if h.name == per] + [
+            s for c, n, s, _ in capture.programs if n == per and c == chip]
+        per = sum(lo <= s < hi for s in starts)
+    units = per or 1
+    scale = 1e-6 / units
+    cells: dict[tuple[str, str], list[int]] = {}
+    unscoped: dict[str, float] = {}
+    for (o, _, _), t in zip(clipped[chip], times[chip]):
+        cell = cells.setdefault((o.layer, o.pass_), [0, 0, 0])
+        cell[0] += t
+        cell[1] += 1
+        cell[2] += t if o.kind == "transfer" else 0
+        if o.layer == "other":
+            key = _SUFFIX.sub("", o.name)
+            unscoped[key] = unscoped.get(key, 0.0) + t * scale
+    cells["idle", ""] = [(hi - lo) - sum(times[chip]), 0, 0]
+    rows = [{"layer": layer, "pass": pass_, "ms": ns * scale,
+             "share": ns / (hi - lo), "ops": n, "transfer_ms": moved * scale}
+            for (layer, pass_), (ns, n, moved) in cells.items()]
+    rows.sort(key=lambda r: ({"other": 1, "idle": 2}.get(r["layer"], 0),
+                             -r["ms"]))
+    spans = _merge_intervals([(a, b) for _, a, b in clipped[chip]])
+    edges = [lo, *(t for span in spans for t in span), hi]
+    idle = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if a < b]
+    split = _host_split(idle, capture.host, lo, hi)
+    activity: dict[str, float] = {}
+    for (_, act), ns in split.items():
+        activity[act] = activity.get(act, 0.0) + ns * scale
+    return {
+        "window_ms": (hi - lo) * scale, "units": units, "chip": chip,
+        "busy_ms_by_chip": {c: sum(t) * scale for c, t in sorted(times.items())},
+        "rows": rows,
+        "other_ops": sorted(unscoped.items(), key=lambda x: -x[1])[:10],
+        "idle_host": sorted(
+            ({"event": e, "activity": a, "ms": ns * scale}
+             for (e, a), ns in split.items()), key=lambda r: -r["ms"]),
+        "idle_activity": activity,
+    }

@@ -196,40 +196,47 @@ def make_train_step(
             def body(carry, mb):
                 acc, loss_sum = carry
                 loss, grads = grad_fn(params, *mb)
-                acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), acc, grads
-                )
+                with jax.named_scope("train/accumulate"):
+                    acc = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32), acc, grads
+                    )
                 return (acc, loss_sum + loss), None
 
             (gsum, loss_sum), _ = lax.scan(
                 body, (zeros, jnp.float32(0.0)), micro
             )
             inv = 1.0 / accum_steps
-            grads = jax.tree.map(
-                lambda g, p: (g * inv).astype(p.dtype), gsum, params
-            )
+            with jax.named_scope("train/accumulate"):
+                grads = jax.tree.map(
+                    lambda g, p: (g * inv).astype(p.dtype), gsum, params
+                )
             loss = loss_sum * inv
 
         # one global norm serves clipping, the non-finite guard, AND the
         # metrics carry: any NaN/inf in any leaf propagates into it, and
         # clipping by a finite factor keeps non-finite values non-finite,
-        # so checking the pre-clip norm is equivalent to post-clip
-        gnorm = (
-            optax.global_norm(grads)
-            if (clip_grad_norm is not None or skip_nonfinite
-                or collect_metrics)
-            else None
-        )
-        if clip_grad_norm is not None:
-            clip = jnp.minimum(
-                1.0, clip_grad_norm / jnp.maximum(gnorm, 1e-12)
+        # so checking the pre-clip norm is equivalent to post-clip.
+        # (train/* scopes are HLO metadata only: they put the step's own
+        # ops on the profiler's layer table, utils/profiling.py STAGES)
+        with jax.named_scope("train/clip"):
+            gnorm = (
+                optax.global_norm(grads)
+                if (clip_grad_norm is not None or skip_nonfinite
+                    or collect_metrics)
+                else None
             )
-            grads = jax.tree.map(
-                lambda g: (g * clip).astype(g.dtype), grads
-            )
+            if clip_grad_norm is not None:
+                clip = jnp.minimum(
+                    1.0, clip_grad_norm / jnp.maximum(gnorm, 1e-12)
+                )
+                grads = jax.tree.map(
+                    lambda g: (g * clip).astype(g.dtype), grads
+                )
 
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("train/optimizer"):
+            updates, new_opt_state = optimizer.update(
+                grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         return new_params, new_opt_state, loss, gnorm
 
     def place_opt(opt_state):
@@ -296,9 +303,10 @@ def make_train_step(
             return new_params, new_opt_state
 
         def keep_old(new, old):
-            return jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new, old
-            )
+            with jax.named_scope("train/clip"):
+                return jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new, old
+                )
 
         # jnp.where with the old value on the skip branch is bit-identical
         # (no arithmetic touches the kept params) — the property the

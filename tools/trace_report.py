@@ -1,32 +1,23 @@
 #!/usr/bin/env python
-"""Render a telemetry run into per-metric, per-stage, and per-hop tables.
+"""Render a profiler capture and/or a telemetry run into tables.
 
-Input is a metrics directory (or JSONL file) written by
-``examples/train.py --metrics-dir`` / ``MetricsLogger``
-(``docs/observability.md`` is the schema glossary), plus optionally an
-XProf capture directory (``tools/xprof_capture.py`` / ``utils.profiling
-.trace``).  Output:
+``--xprof DIR`` (``benchmarks/run.py --trace 1`` leaves a capture under
+``benchmarks/.trace/<cell>``, ``utils.profiling.trace`` anywhere) prints,
+per program or per ``--window``, every device millisecond by layer and
+pass with ``other`` and ``idle`` rows, the idle row split by what the host
+was doing (``utils.profiling.layer_breakdown``); then the per-stage table,
+the per-hop compute-vs-transfer timeline and the MEASURED overlap, beside
+the analytic ``hop_overlap_fraction`` of the metrics rows when both exist
+(disagreement beyond ``--overlap-tolerance`` is a FINDING line).
 
-- run summary (rows, step span, schema version, degradation events);
-- per-metric table (last / mean / p50 / p95) over the numeric metric
-  columns — loss, grad_norm, tokens_per_sec, step latency, mfu;
-- comms accounting echo (ring hops, bytes per hop, overlap fraction);
-- when ``--xprof DIR`` points at a capture with ``*.xplane.pb`` planes:
-  the per-stage device-time table (busy ms / share / p50 / p95 keyed on
-  the stack's stable trace names), the per-hop compute-vs-transfer
-  timeline, and the MEASURED compute/transfer overlap fraction — printed
-  next to the analytic ``hop_overlap_fraction`` from the metrics rows
-  when both exist; disagreement beyond ``--overlap-tolerance`` is
-  reported as a FINDING line (the comms model no longer describes the
-  capture);
-- ``--diff OLD NEW`` (instead of a single run): side-by-side per-metric
-  table over two runs with delta and percent columns — the manual
-  version of ``tools/perf_gate.py`` for a human bisecting a regression.
+A metrics directory (or JSONL file) written by ``MetricsLogger``
+(``docs/observability.md``) prints the run summary, the per-metric table
+(last / mean / p50 / p95) and the comms accounting; ``--diff OLD NEW``
+compares two runs.  The metrics tables are stdlib-only; a capture's
+events need ``jax.profiler.ProfileData``.  Usage::
 
-Stdlib-only: the xplane parser is ``utils/profiling.py``'s wire-format
-reader (loaded by file path, no jax import), so this tool runs on a box
-where jax cannot.  Usage::
-
+  python tools/trace_report.py --xprof benchmarks/.trace/sc2-3b.train-64k
+  python tools/trace_report.py --xprof DIR --window bench/token,bench/fetch --per bench/token
   python tools/trace_report.py /tmp/m [--xprof /tmp/profile]
   python tools/trace_report.py --diff /tmp/m_before /tmp/m_after
 """
@@ -209,22 +200,57 @@ def diff_report(old_path: str, new_path: str, out: list[str]) -> None:
         )
 
 
+def layer_report(capture, out: list[str], windows: list, per, chip) -> None:
+    """The layer-and-pass table of each window; with none named, of each
+    program that took at least 1% of the device's time, per execution."""
+    if not windows:
+        took: dict[str, int] = defaultdict(int)
+        for _, name, _, dur in capture.programs:
+            took[name] += dur
+        windows = [[n] for n, t in took.items()
+                   if t >= 0.01 * sum(took.values())] or [None]
+    for window in windows:
+        got = _profiling().layer_breakdown(
+            capture, window, chip=chip,
+            per=per or (window[0] if window else None))
+        what = ",".join(window) if window else "all device ops"
+        if "note" in got:
+            out.append(f"[xprof] {what}: {got['note']}")
+            continue
+        out += ["", f"layer and pass: {what} x{got['units']}, "
+                    f"{got['window_ms']:.3f} ms each, chip {got['chip']} "
+                    f"(busy ms by chip: {got['busy_ms_by_chip']})",
+                f"  {'layer':24s} {'pass':10s} {'ms':>11s} {'share':>7s} "
+                f"{'ops':>8s} {'transfer ms':>12s}"]
+        out += [f"  {r['layer']:24s} {r['pass']:10s} {r['ms']:11.4f} "
+                f"{100 * r['share']:6.2f}% {r['ops']:8d} "
+                f"{r['transfer_ms']:12.4f}" for r in got["rows"]]
+        for label, pairs in (("other holds", got["other_ops"]),
+                             ("idle by host activity",
+                              got["idle_activity"].items())):
+            out.append(f"  {label}: " + ", ".join(
+                f"{n} {ms:.4f}" for n, ms in sorted(pairs, key=lambda x: -x[1])))
+        out += [f"    {r['ms']:11.4f}  {r['activity']:9s} {r['event']}"
+                for r in got["idle_host"][:12]]
+
+
 def xprof_report(trace_dir: str, out: list[str], *,
                  analytic: float | None = None,
                  tolerance: float = 0.25,
-                 ring_size: int | None = None) -> None:
-    """Per-stage/per-hop device time + measured overlap from an xplane
-    capture, via the stdlib parser in ``utils/profiling.py``.
-    ``ring_size`` (from the run's accounting rows) folds multi-step
-    captures into per-step hop samples.  Best-effort: an unreadable
-    capture degrades to a note, never an error (the metrics table above
-    is the primary product)."""
+                 ring_size: int | None = None,
+                 windows: list | None = None, per=None, chip=None) -> None:
+    """Layer-and-pass tables, per-stage/per-hop device time and measured
+    overlap from an xplane capture (``utils/profiling.py``).  ``ring_size``
+    folds multi-step captures into per-step hop samples.  Best-effort: an
+    unreadable capture is a note, never an error."""
     prof = _profiling()
-    report = prof.overlap_report(trace_dir, analytic=analytic,
-                                 tolerance=tolerance, ring_size=ring_size)
-    if "note" in report:
-        out.append(f"[xprof] {report['note']}")
+    capture = prof.read_capture(trace_dir)
+    if capture.note:
+        out.append(f"[xprof] {capture.note}")
         return
+    layer_report(capture, out, windows or [], per, chip)
+    report = prof.overlap_report(capture.ops, analytic=analytic,
+                                 tolerance=tolerance, ring_size=ring_size)
     timeline = report["timeline"]
     total = timeline["total_busy_ms"] or 1.0
     out.append("")
@@ -272,10 +298,20 @@ def main(argv: list[str] | None = None) -> int:
                     help="metrics directory (holding metrics.jsonl) or a "
                          "JSONL file written by MetricsLogger")
     ap.add_argument("--xprof", default=None,
-                    help="xprof capture dir (tools/xprof_capture.py / "
-                         "utils.profiling.trace): adds per-stage and "
-                         "per-hop device-time tables plus the measured "
+                    help="profiler capture dir or .xplane.pb[.gz] "
+                         "(benchmarks/run.py --trace 1, utils.profiling."
+                         "trace): layer-and-pass, per-stage and per-hop "
+                         "device-time tables plus the measured "
                          "compute/transfer overlap fraction")
+    ap.add_argument("--window", action="append", default=[],
+                    help="host event or program name(s), comma-separated, "
+                         "whose extent is one layer table (repeatable); "
+                         "default: one table per program")
+    ap.add_argument("--per", default=None,
+                    help="name whose events in the window are the units "
+                         "(steps, tokens); default: the window's first name")
+    ap.add_argument("--chip", type=int, default=None,
+                    help="chip of the layer table (default: the busiest)")
     ap.add_argument("--last", type=int, default=None,
                     help="summarize only the last N metric rows")
     ap.add_argument("--diff", nargs=2, metavar=("OLD", "NEW"), default=None,
@@ -291,16 +327,17 @@ def main(argv: list[str] | None = None) -> int:
         diff_report(args.diff[0], args.diff[1], out)
         print("\n".join(out))
         return 0
-    if args.metrics is None:
-        ap.error("metrics path required (or use --diff OLD NEW)")
+    if args.metrics is None and args.xprof is None:
+        ap.error("metrics path or --xprof DIR required (or --diff OLD NEW)")
 
-    rows = _read_rows(args.metrics)
+    rows = _read_rows(args.metrics) if args.metrics else []
     if args.last is not None:
         events = [r for r in rows if "event" in r]
         metric = [r for r in rows if "event" not in r][-args.last:]
         rows = events + metric
-    out.append(f"trace report: {args.metrics}")
-    metrics_report(rows, out)
+    out.append(f"trace report: {args.metrics or args.xprof}")
+    if args.metrics:
+        metrics_report(rows, out)
     if args.xprof:
         # analytic overlap + ring size from the run's own accounting
         # rows, when present
@@ -315,7 +352,8 @@ def main(argv: list[str] | None = None) -> int:
         )
         xprof_report(args.xprof, out, analytic=analytic,
                      tolerance=args.overlap_tolerance,
-                     ring_size=ring_size)
+                     ring_size=ring_size, per=args.per, chip=args.chip,
+                     windows=[w.split(",") for w in args.window])
     print("\n".join(out))
     return 0
 
