@@ -98,8 +98,9 @@ def main(argv: list[str] | None = None) -> dict:
                          "docs/memory.md)")
     ap.add_argument("--loss-chunk-size", type=int, default=None,
                     help="chunked cross-entropy: at most (batch, chunk, "
-                         "vocab) logits materialize — required at real LM "
-                         "vocabularies with long sequences")
+                         "vocab) logits materialize per device (on a mesh "
+                         "the chunk is rows per sequence shard) — required "
+                         "at real LM vocabularies with long sequences")
     ap.add_argument("--offload-opt-state", action="store_true",
                     help="host offload of the optimizer state (Adam "
                          "moments leave HBM between steps); a no-op on "

@@ -399,17 +399,20 @@ class RingTransformer(nn.Module):
         x = self.final_norm(x)
 
         if return_loss and self.loss_chunk_size:
-            # the (b, n, vocab) logits never materialize: un-permute the
-            # (b, n, dim) features instead (CE is position-local, so the
-            # layout permutation only has to line features up with labels)
-            # and scan the projection+CE over sequence chunks
+            # the (b, n, vocab) logits never materialize, and under a
+            # sequence-parallel mesh the (b, n, dim) features never leave
+            # their shards: CE is position-local, so the labels (small,
+            # replicated) take the tokens' padding and layout permutation
+            # and the projection+CE scans chunks within each shard
+            valid = self._valid_labels(labels, example_mask, segment_same)
+            shards = 1
             if ring > 1 and self.auto_shard:
-                x = layout_unpermute(x, scheme, factor)
-                x = x[:, :n_orig]
-            return self._chunked_ce(
-                x, labels,
-                self._valid_labels(labels, example_mask, segment_same),
-            )
+                shards = ring
+                labels, _ = pad_to_multiple(labels, pad_mult)
+                valid, _ = pad_to_multiple(valid, pad_mult, value=False)
+                labels = layout_permute(labels, scheme, factor)
+                valid = layout_permute(valid, scheme, factor)
+            return self._chunked_ce(x, labels, valid, shards)
 
         logits = self.to_logits(x)
 
@@ -445,28 +448,58 @@ class RingTransformer(nn.Module):
     def _chunked_ce(
         self,
         x: jax.Array,  # (b, n, dim) final-norm features
-        labels: jax.Array,  # (b, n)
-        valid: jax.Array,  # (b, n) bool, from _valid_labels
+        labels: jax.Array,  # (b, n), in the features' layout
+        valid: jax.Array,  # (b, n) bool, from _valid_labels, same layout
+        shards: int = 1,  # sequence shards the features arrive in
     ) -> jax.Array:
         """Cross-entropy as a rematted scan over sequence chunks.
 
-        Peak memory is one chunk's logits ``(b, chunk, vocab)`` — forward
-        AND backward (the remat recomputes each chunk's projection in the
-        grad pass; dW accumulates across scan steps).  Value-identical to
-        the dense path (same f32 lse-minus-chosen per position)."""
+        Peak memory is one chunk's logits ``(b, chunk, vocab)`` per device
+        — forward AND backward (the remat recomputes each chunk's
+        projection in the grad pass; dW accumulates across scan steps).
+        Value-identical to the dense path (same f32 lse-minus-chosen per
+        position).
+
+        Chunks are taken WITHIN each sequence shard, as
+        ``FeedForward._chunked`` takes them: ``(b, n, d) -> (nc, b,
+        shards, c, d)`` with the shard axis held to the sequence mesh
+        axes, so ``loss_chunk_size`` is rows per device per scan step and
+        every device scores only its own ``n / shards`` rows.  The scalar
+        carry and the head's weight gradient are partial sums the
+        partitioner reduces across the mesh."""
         b, n, _ = x.shape
+        n_local = n // shards
         # clamp: padding a short sequence UP to the chunk size would make
         # peak memory/compute strictly worse than the dense path
-        c = min(self.loss_chunk_size, n)
-        x, _ = pad_to_multiple(x, c)
-        labels, _ = pad_to_multiple(labels, c)
-        valid, _ = pad_to_multiple(valid, c, value=False)
-        nc = x.shape[1] // c
-        xs = (
-            x.reshape(b, nc, c, x.shape[-1]).transpose(1, 0, 2, 3),
-            labels.reshape(b, nc, c).transpose(1, 0, 2),
-            valid.reshape(b, nc, c).transpose(1, 0, 2),
-        )
+        c = min(self.loss_chunk_size, n_local)
+
+        # off a mesh there is no shard axis: the program is the plain
+        # (nc, b, c, ...) chunk scan
+        lead = (b, shards) if shards > 1 else (b,)
+        k = len(lead)
+
+        def chunks(a, value=0):
+            # (b, n, ...) -> (nc, b, shards, c, ...): the shard axis is
+            # split out first, so the reshape/transpose stay local to each
+            # device; a shard length c does not divide is padded up with
+            # valid=False rows
+            a = a.reshape(*lead, n_local, *a.shape[2:])
+            a, _ = pad_to_multiple(a, c, axis=k, value=value)
+            a = a.reshape(*lead, -1, c, *a.shape[k + 1:])
+            a = jnp.moveaxis(a, k, 0)
+            if shards > 1:
+                # without the constraint the partitioner is free to gather
+                # the whole sequence onto every device and replicate the scan
+                a = lax.with_sharding_constraint(
+                    a, NamedSharding(
+                        self.mesh,
+                        P(None, data_partition(self.mesh),
+                          seq_partition(self.mesh), *(None,) * (a.ndim - 3)),
+                    )
+                )
+            return a
+
+        xs = (chunks(x), chunks(labels), chunks(valid, value=False))
 
         def body(mdl, carry, inp):
             x_c, lab_c, val_c = inp

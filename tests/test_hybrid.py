@@ -281,8 +281,10 @@ def test_hybrid_transformer_loss(rng, meshes):
 
 @pytest.mark.slow
 def test_hybrid_transformer_chunked_ce(rng, meshes):
-    """The chunked-CE path un-permutes the factored striped layout before
-    scanning: loss must match the dense CE bit-for-bit in f32 math."""
+    """The chunked-CE path scans within the shards of the factored striped
+    layout (labels permuted by the outer ring degree, shard axis over
+    both mesh axes): loss must match the dense CE bit-for-bit in f32
+    math."""
     mesh = meshes[(1, 2, 4)]
     common = dict(num_tokens=64, dim=32, depth=1, heads=8, dim_head=4,
                   causal=True, striped=True, bucket_size=4)

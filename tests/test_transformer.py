@@ -8,6 +8,9 @@ layout, odd sequence lengths (padding), GQA, and a 2x4 mesh
 (``num_sharded_batches`` analogue).
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -325,39 +328,78 @@ def test_ring_dkv_dtype_through_model(rng, mesh):
         np.testing.assert_allclose(a, b, atol=3e-2, rtol=3e-2)
 
 
-@pytest.mark.parametrize("chunk,layout", [
-    (8, "local"), (5, "local"), (64, "local"),  # 64 > n: clamp path
-    (8, "striped"), (8, "zigzag"),
+def _ce_mesh(layout):
+    """Mesh and model options of one ``test_chunked_ce_matches_dense``
+    layout (built per case: a parametrize argument may not touch jax)."""
+    if layout == "local":
+        return dict(use_ring=False)
+    if layout == "data2-seq4":
+        return dict(mesh=create_mesh(ring_size=4, data_size=2), striped=True)
+    kw = dict(mesh=create_mesh(ring_size=8))
+    if layout == "striped":
+        kw["striped"] = True
+    elif layout in ("zigzag", "ulysses"):
+        kw.update(sequence_parallel=layout, heads=8, dim_head=4)
+    else:
+        assert layout == "contiguous", layout
+    return kw
+
+
+# (chunk, layout, seq_len, extra): the ring cases run 8 shards (4 on the
+# data2-seq4 mesh), so after the label shift a shard holds (seq_len - 1) / 8
+# rows and the chunk is rows per shard per scan step
+@pytest.mark.parametrize("chunk,layout,seq_len,extra", [
+    pytest.param(8, "local", 33, None, id="8-local"),
+    pytest.param(5, "local", 33, None, id="5-local"),
+    pytest.param(64, "local", 33, None, id="64-local"),  # 64 > n: clamp path
+    pytest.param(8, "striped", 33, None, id="8-striped"),
+    pytest.param(8, "zigzag", 33, None, id="8-zigzag"),
+    pytest.param(4, "contiguous", 65, None, id="contiguous-ring"),
+    pytest.param(3, "striped", 65, None, id="pad-within-shard"),  # 8 -> 9
+    pytest.param(64, "striped", 65, None, id="chunk-over-shard"),  # clamp to 8
+    pytest.param(4, "striped", 60, None, id="model-top-pad"),  # 59 -> 64
+    pytest.param(4, "zigzag", 60, None, id="model-top-pad-zigzag"),
+    pytest.param(4, "ulysses", 65, None, id="ulysses"),
+    # documents end at 13 and 37: inside the contiguous shards 8..15, 32..39
+    pytest.param(4, "contiguous", 65, "segments", id="segments-in-shard"),
+    pytest.param(4, "striped", 65, "example_mask", id="example-mask"),
+    pytest.param(4, "data2-seq4", 65, None, id="data2-seq4"),
 ])
-def test_chunked_ce_matches_dense(rng, chunk, layout):
+def test_chunked_ce_matches_dense(rng, chunk, layout, seq_len, extra):
     """loss_chunk_size: the rematted chunk-scan loss (and its gradients)
     equals the dense logits+CE path — including a chunk size that doesn't
     divide the sequence, one larger than the sequence (clamped), an
-    ignore_index tail, and the striped/zig-zag paths where the features
-    (not the logits) get un-permuted."""
+    ignore_index tail, and every sequence-parallel layout, where the
+    features stay in their shards, the labels are permuted to them and the
+    chunks are taken within each shard (padded, clamped, under model-top
+    padding, packed documents, a ragged batch and a data axis)."""
     kw = dict(
         num_tokens=VOCAB, dim=32, depth=2, heads=4, dim_head=8,
         causal=True, bucket_size=8,
-        **({"local": dict(use_ring=False),
-            "striped": dict(mesh=create_mesh(ring_size=8), striped=True),
-            "zigzag": dict(mesh=create_mesh(ring_size=8),
-                           sequence_parallel="zigzag")}[layout]),
     )
+    kw.update(_ce_mesh(layout))
     dense = RingTransformer(**kw)
     chunked = RingTransformer(loss_chunk_size=chunk, **kw)
-    tokens = jnp.asarray(rng.integers(0, VOCAB, (2, 33)), jnp.int32)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (2, seq_len)), jnp.int32)
     tokens = tokens.at[0, 20:].set(-1)  # ignore_index tail in row 0
     params = dense.init(jax.random.PRNGKey(0), jnp.abs(tokens))
+    call = {}
+    if extra == "segments":
+        seg = np.zeros((2, seq_len), np.int32)
+        seg[:, 13:] = 1
+        seg[:, 37:] = 2
+        call["segment_ids"] = jnp.asarray(seg)
+        tokens = jnp.abs(tokens)  # labels on both sides of each boundary
+    elif extra == "example_mask":
+        call["example_mask"] = jnp.asarray([False, True])
 
     def loss_fn(model):
-        return lambda p: model.apply(p, tokens, return_loss=True)
+        return lambda p: model.apply(p, tokens, return_loss=True, **call)
 
-    ld = loss_fn(dense)(params)
-    lc = loss_fn(chunked)(params)
+    ld, gd = jax.jit(jax.value_and_grad(loss_fn(dense)))(params)
+    lc, gc = jax.jit(jax.value_and_grad(loss_fn(chunked)))(params)
     np.testing.assert_allclose(lc, ld, rtol=2e-6)
 
-    gd = jax.grad(loss_fn(dense))(params)
-    gc = jax.grad(loss_fn(chunked))(params)
     flat_d = jax.tree_util.tree_leaves_with_path(gd)
     flat_c = {jax.tree_util.keystr(p): l
               for p, l in jax.tree_util.tree_leaves_with_path(gc)}
@@ -383,3 +425,73 @@ def test_chunked_ce_program_does_not_materialize_logits(rng):
     )(params)
     full = f"1,{n},{VOCAB}"
     assert full not in str(jaxpr), f"found full-logits shape ({full})"
+
+
+def _hlo_shapes(lines):
+    """Every array shape (a tuple of ints) written in these HLO lines."""
+    return {
+        tuple(int(d) for d in dims.split(",") if d)
+        for line in lines
+        for dims in re.findall(r"\b(?:pred|[a-z]+[0-9]+)\[([0-9,]*)\]", line)
+    }
+
+
+def test_chunked_ce_ring_program_scores_own_rows_only(rng):
+    """On a ring the compiled chunked loss is sharded over the sequence:
+    every device scans its own n / ring rows, chunks taken within the
+    shard.  Read from the partitioned HLO of ``value_and_grad``:
+
+    (a) nothing in the loss holds the features of more than one shard, let
+        alone (b, n)-by-vocab logits;
+    (b) the per-device logits are (b, c, vocab), the scan has
+        n_local / c steps;
+    (c) the loss gathers nothing, and beside the scalar carry its one
+        all-reduce is the head's weight gradient (a partial sum a device).
+
+    Before the scan kept its shard axis the partitioner replicated it:
+    an all-gather of the (b, n, dim) features and n / c steps on every
+    device."""
+    ring, n, dim, vocab, chunk = 4, 64, 24, 136, 8
+    n_local = n // ring
+    mesh = create_mesh(ring_size=ring, devices=jax.devices()[:ring])
+    model = RingTransformer(
+        num_tokens=vocab, dim=dim, depth=1, heads=2, dim_head=12,
+        causal=True, bucket_size=8, striped=True, mesh=mesh,
+        loss_chunk_size=chunk,
+    )
+    tokens = jnp.asarray(rng.integers(0, vocab, (1, n + 1)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    hlo = jax.jit(jax.value_and_grad(
+        lambda p: model.apply(p, tokens, return_loss=True)
+    )).lower(params).compile().as_text()
+    loss = [line for line in hlo.splitlines() if "_chunked_ce" in line]
+    assert loss, "no instruction carries the _chunked_ce scope"
+    shapes = _hlo_shapes(loss)
+    head = {(dim, vocab), (vocab, dim)}  # the kernel and its gradient
+
+    # (a) and (b)
+    for shape in shapes - head:
+        if shape[-1:] == (dim,):
+            assert math.prod(shape[:-1]) <= n_local, (
+                f"features of more than one shard in the loss: {shape}")
+        if vocab in shape:
+            assert math.prod(shape) // vocab <= chunk, (
+                f"logits of more than one chunk in the loss: {shape}")
+    assert (1, 1, chunk, vocab) in shapes  # (b, this shard, c, vocab)
+    assert (n_local // chunk, 1, 1, chunk, dim) in shapes  # the scanned xs
+
+    # (c)
+    def collectives(lines, kind):
+        return [line for line in lines
+                if re.search(rf"\b{kind}(-start)?\(", line)]
+
+    for kind in ("all-gather", "all-to-all", "collective-permute"):
+        assert not collectives(loss, kind), f"{kind} in the loss"
+    assert not collectives(hlo.splitlines(), "all-gather")
+    reduced = [
+        shape
+        for line in collectives(loss, "all-reduce")
+        for shape in _hlo_shapes([line.split(" all-reduce")[0]])
+        if shape  # () is the scalar carry: sum of nll, count
+    ]
+    assert len(reduced) == 1 and reduced[0] in head, reduced
