@@ -39,6 +39,12 @@ def main(argv: list[str] | None = None) -> dict:
                     help="use only the first N devices jax reports "
                          "(default: all) — 1 runs the one-chip path on a "
                          "multi-chip host")
+    ap.add_argument("--config", default=None,
+                    help="build the model from a configuration file "
+                         "(benchmarks/configs/*.json: a published "
+                         "config.json's keys) instead of --dim/--depth/"
+                         "--heads/--dim-head/--kv-heads and 256 tokens; "
+                         "with --bf16 the weights are bfloat16 too")
     ap.add_argument("--dim", type=int, default=128)
     ap.add_argument("--depth", type=int, default=2)
     ap.add_argument("--heads", type=int, default=4)
@@ -95,6 +101,7 @@ def main(argv: list[str] | None = None) -> dict:
     import numpy as np
 
     from ring_attention_tpu import RingTransformer, create_mesh
+    from ring_attention_tpu.models import ModelConfig
     from ring_attention_tpu.utils import compat, enable_compile_cache
 
     # before any jit: every compile from here on lands in the cache
@@ -118,16 +125,32 @@ def main(argv: list[str] | None = None) -> dict:
           f"devices={n_dev} (of {len(jax.devices())})")
     mesh = (create_mesh(ring_size=n_dev, devices=devices)
             if n_dev > 1 else None)
-    model = RingTransformer(
-        num_tokens=256, dim=args.dim, depth=args.depth, heads=args.heads,
-        dim_head=args.dim_head, kv_heads=args.kv_heads,
-        causal=True, bucket_size=64, mesh=mesh, use_ring=mesh is not None,
+    run = dict(
+        bucket_size=64, mesh=mesh, use_ring=mesh is not None,
         use_pallas=args.use_pallas, quantize_cache=args.q8_cache,
         dtype=jnp.bfloat16 if args.bf16 else None,
     )
+    if args.config:
+        model = RingTransformer.from_config(
+            ModelConfig.from_file(args.config), **run)
+    else:
+        model = RingTransformer(
+            num_tokens=256, dim=args.dim, depth=args.depth, heads=args.heads,
+            dim_head=args.dim_head, kv_heads=args.kv_heads, causal=True,
+            **run)
     rng = np.random.default_rng(0)
-    prompt = jnp.asarray(rng.integers(0, 256, (1, args.prompt_len)), jnp.int32)
-    params = jax.jit(model.init)(jax.random.PRNGKey(0), prompt)
+    prompt = jnp.asarray(
+        rng.integers(0, model.num_tokens, (1, args.prompt_len)), jnp.int32)
+    init = model.init
+    if args.config and args.bf16:
+        # a configuration is served from bfloat16 weights (its file's
+        # `assumed.dtype`).  flax draws float32; the cast rides in the same
+        # jit, leaf by leaf, so the float32 draws never stand side by side
+        # (trinity-large-preview: 17 GB of them, on a 16 GB chip)
+        def init(key, tokens):
+            return jax.tree.map(lambda w: w.astype(jnp.bfloat16),
+                                model.init(key, tokens))
+    params = jax.jit(init)(jax.random.PRNGKey(0), prompt)
 
     def log_decode(**fields):
         if args.metrics_dir is None:

@@ -1,12 +1,18 @@
 from .attention import RingAttention
-from .layers import FeedForward, RMSNorm
+from .config import LayerConfig, ModelConfig
+from .layers import FeedForward, GatedFeedForward, RMSNorm
+from .moe import RoutedFeedForward
 from .remat import REMAT_POLICIES, resolve_remat_policy
 from .transformer import RingTransformer
 
 __all__ = [
     "RingAttention",
     "FeedForward",
+    "GatedFeedForward",
+    "RoutedFeedForward",
     "RMSNorm",
+    "LayerConfig",
+    "ModelConfig",
     "RingTransformer",
     "REMAT_POLICIES",
     "resolve_remat_policy",

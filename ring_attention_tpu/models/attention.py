@@ -165,14 +165,26 @@ class RingAttention(nn.Module):
     # ring_hop_compression="int8" into the dequant-free ring
     # (docs/precision.md)
     compute_dtype: str | None = None
+    # RMSNorm over dim_head on q and on k (one weight vector each), before
+    # the rotation
+    qk_norm: bool = False
+    # multiply sigmoid(prenorm(x) W_g), heads * dim_head wide, into the
+    # attention output before to_out
+    out_gate: bool = False
+    norm_eps: float = 1e-12
     dtype: jnp.dtype | None = None
 
     def setup(self):
         h, kvh, dh = self.heads, self._kv_heads(), self.dim_head
-        self.prenorm = RMSNorm(self.dim)
+        self.prenorm = RMSNorm(self.dim, self.norm_eps)
         self.to_qkv = nn.Dense(
             (h + 2 * kvh) * dh, use_bias=False, dtype=self.dtype
         )
+        if self.qk_norm:
+            self.q_norm = RMSNorm(dh, self.norm_eps)
+            self.k_norm = RMSNorm(dh, self.norm_eps)
+        if self.out_gate:
+            self.to_gate = nn.Dense(h * dh, use_bias=False, dtype=self.dtype)
         self.to_out = nn.Dense(self.dim, use_bias=False, dtype=self.dtype)
 
     def _kv_heads(self) -> int:
@@ -331,15 +343,35 @@ class RingAttention(nn.Module):
         return self.ring_bidirectional
 
     def _project_qkv(self, x: jax.Array):
-        """prenorm + fused qkv -> heads-major (b, h|hk, n, dh)."""
+        """prenorm + fused qkv -> heads-major (b, h|hk, n, dh), and the
+        output gate's logits ``(b, n, h * dh)`` (None without one)."""
         h, kvh, dh = self.heads, self._kv_heads(), self.dim_head
-        qkv = self.to_qkv(self.prenorm(x))
+        normed = self.prenorm(x)
+        qkv = self.to_qkv(normed)
         q, k, v = jnp.split(qkv, [h * dh, (h + kvh) * dh], axis=-1)
         b, n, _ = x.shape
         q = q.reshape(b, n, h, dh).transpose(0, 2, 1, 3)
         k = k.reshape(b, n, kvh, dh).transpose(0, 2, 1, 3)
         v = v.reshape(b, n, kvh, dh).transpose(0, 2, 1, 3)
-        return q, k, v
+        if self.qk_norm:
+            with jax.named_scope("attn/qk_norm"):
+                q, k = self.q_norm(q), self.k_norm(k)
+        gate = None
+        if self.out_gate:
+            with jax.named_scope("attn/gate"):
+                gate = self.to_gate(normed)
+        return q, k, v, gate
+
+    def _project_out(self, out: jax.Array, gate: jax.Array | None):
+        """heads-major attention output ``(b, h, n, dh)`` -> ``(b, n, dim)``,
+        through the output gate where the layer has one."""
+        b, _, n, _ = out.shape
+        out = out.transpose(0, 2, 1, 3).reshape(b, n, -1)
+        if gate is not None:
+            with jax.named_scope("attn/gate"):
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(out.dtype)
+        return self.to_out(out)
 
     def __call__(
         self,
@@ -416,7 +448,7 @@ class RingAttention(nn.Module):
                 )
             )
 
-        q, k, v = self._project_qkv(x)
+        q, k, v, gate = self._project_qkv(x)
         b, n, _ = x.shape
         self._certify_mask(n)
 
@@ -428,8 +460,7 @@ class RingAttention(nn.Module):
         else:
             out = self._local_attend(q, k, v, mask, segment_ids)
 
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, self.heads * self.dim_head)
-        out = self.to_out(out)
+        out = self._project_out(out, gate)
 
         if ring and self.auto_shard:
             out = layout_unpermute(out, scheme, factor)
@@ -704,7 +735,7 @@ class RingAttention(nn.Module):
         always contiguous regardless of how training was striped, since
         positions are explicit.  Returns ``(out (b,1,dim), cache_k, cache_v)``.
         """
-        q, k, v = self._project_qkv(x)
+        q, k, v, gate = self._project_qkv(x)
         if self.rotary:
             freqs = rotary_freqs(
                 jnp.reshape(pos, (1,)), self.dim_head, self.rotary_theta
@@ -718,7 +749,7 @@ class RingAttention(nn.Module):
         # (size > every pos) reduces exactly to the plain layout, and a
         # window-sized cache (size >= max_lookback_seq_len) stores only the
         # window — O(W) decode memory/bandwidth instead of O(max_len) for
-        # lookback layers (see RingTransformer.windowed_cache)
+        # lookback layers (RingTransformer.init_cache sizes them so)
         if not ring and self.quantize_cache:
             size = cache_k[0].shape[2]
             cache_k, cache_v = self._quantized_write(
@@ -757,8 +788,7 @@ class RingAttention(nn.Module):
         else:
             out, cache_k, cache_v = self._ring_decode(q, k, v, cache_k, cache_v, pos)
 
-        out = out.transpose(0, 2, 1, 3).reshape(x.shape[0], 1, -1)
-        return self.to_out(out), cache_k, cache_v
+        return self._project_out(out, gate), cache_k, cache_v
 
     @staticmethod
     def _quantized_write(cache_k, cache_v, k, v, pos):
@@ -834,7 +864,7 @@ class RingAttention(nn.Module):
                     f"is only valid for a window-sized cache covering "
                     f"max_lookback_seq_len ({self._eff_lookback()})"
                 )
-        q, k, v = self._project_qkv(x)
+        q, k, v, gate = self._project_qkv(x)
         if self.rotary:
             freqs = rotary_freqs(jnp.arange(n), self.dim_head, self.rotary_theta)
             q = apply_rotary(q, freqs)
@@ -867,8 +897,7 @@ class RingAttention(nn.Module):
             cache_k = lax.dynamic_update_slice(cache_k, k_rows.astype(cache_k.dtype), zeros)
             cache_v = lax.dynamic_update_slice(cache_v, v_rows.astype(cache_v.dtype), zeros)
 
-        out = out.transpose(0, 2, 1, 3).reshape(x.shape[0], n, -1)
-        return self.to_out(out), cache_k, cache_v
+        return self._project_out(out, gate), cache_k, cache_v
 
     def _ring_prefill_attend(self, q, k, v):
         """Ring attention over the prompt in contiguous (cache) layout.

@@ -1,0 +1,171 @@
+"""A frozen description of a model: what ``RingTransformer`` builds its
+stack from.
+
+One ``ModelConfig`` says everything about the block that the arithmetic
+depends on: the widths, one ``LayerConfig`` per layer (its attention
+window, whether it rotates, which feed-forward it has), the norms and the
+routed experts.  How the stack is *run* (mesh, kernels, remat, chunking)
+stays with ``RingTransformer``'s own options and is not here.
+
+``RingTransformer.from_config(config, **options)`` is the constructor.
+The older keyword constructor (``num_tokens, dim, depth, heads, ...``)
+builds the uniform block of ``ModelConfig.uniform`` and walks the same
+stack, so a StarCoder2-like model is this with other values.
+"""
+
+from __future__ import annotations
+
+import json
+from dataclasses import dataclass
+
+FFN_KINDS = ("gelu", "gated", "routed")
+
+
+@dataclass(frozen=True)
+class LayerConfig:
+    # causal lookback: query i reads keys j with i - j < window; None reads
+    # every earlier key (and sizes the layer's decode cache accordingly)
+    window: int | None = None
+    rotary: bool = True  # False: no positional encoding in this layer
+    ffn: str = "gelu"  # one of FFN_KINDS
+
+
+@dataclass(frozen=True)
+class ModelConfig:
+    num_tokens: int  # rows of the embedding and the head held here
+    dim: int
+    heads: int
+    dim_head: int
+    kv_heads: int
+    layers: tuple[LayerConfig, ...]
+    ffn_dim: int  # hidden width of the "gelu" and "gated" feed-forwards
+    rotary_theta: float = 10000.0
+    norm_eps: float = 1e-12
+    qk_norm: bool = False  # RMSNorm over dim_head on q and k, before rotary
+    attn_gate: bool = False  # sigmoid(x W_g) on the attention output
+    # a second RMSNorm on each sub-block's output, before the residual add
+    sandwich_norm: bool = False
+    embed_scale: float = 1.0
+    # "routed" layers: the router scores all ``num_experts`` and this
+    # holder computes ``experts_held`` consecutive ones from ``first_expert``
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    shared_expert_dim: int = 0  # 0: no shared expert
+    route_scale: float = 1.0
+    first_expert: int = 0
+    experts_held: int = 0
+
+    def __post_init__(self):
+        for i, layer in enumerate(self.layers):
+            if layer.ffn not in FFN_KINDS:
+                raise ValueError(
+                    f"ModelConfig: layer {i} has ffn {layer.ffn!r}; the "
+                    f"kinds are {', '.join(FFN_KINDS)}")
+        if self.heads % self.kv_heads:
+            raise ValueError(
+                f"ModelConfig: {self.heads} heads do not group over "
+                f"{self.kv_heads} kv heads")
+        if any(layer.ffn == "routed" for layer in self.layers) and not (
+                0 < self.experts_per_token <= self.num_experts
+                and 0 < self.experts_held
+                and self.first_expert + self.experts_held <= self.num_experts):
+            raise ValueError(
+                f"ModelConfig: routed layers need 0 < experts_per_token <= "
+                f"num_experts and the held experts [{self.first_expert}, "
+                f"{self.first_expert + self.experts_held}) inside "
+                f"[0, {self.num_experts})")
+
+    @property
+    def depth(self) -> int:
+        return len(self.layers)
+
+    @classmethod
+    def uniform(cls, *, num_tokens, dim, depth, heads, dim_head, kv_heads,
+                ffn_dim, windows=None, rotary=True):
+        """The block the keyword constructor has always built: pre-norm,
+        rotary, a non-gated GELU feed-forward, ``windows`` per layer."""
+        windows = windows or (None,) * depth
+        return cls(
+            num_tokens=num_tokens, dim=dim, heads=heads, dim_head=dim_head,
+            kv_heads=kv_heads or heads, ffn_dim=ffn_dim,
+            layers=tuple(LayerConfig(window=w, rotary=rotary) for w in windows))
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "ModelConfig":
+        """From a published ``config.json``'s keys, as the files under
+        ``benchmarks/configs/`` hold them.  What a family's block is (its
+        norms, gates, feed-forward) is not in those keys but in the
+        family's published code, so the translation is one function per
+        family in ``_FAMILIES`` below (named by ``model_type``, else by
+        ``architectures``: "Starcoder2ForCausalLM" is ``starcoder2``), and
+        a family that is not there is an error, not some other family's
+        block."""
+        name = d.get("model_type") or next(
+            (a.removesuffix("ForCausalLM").lower()
+             for a in d.get("architectures", ())), None)
+        family = _FAMILIES.get(name)
+        if family is None:
+            raise ValueError(
+                f"ModelConfig: no translation for the family {name!r} "
+                f"(model_type, else architectures); there are "
+                f"{', '.join(sorted(_FAMILIES))} (models/config.py)")
+        heads = d["num_attention_heads"]
+        return cls(
+            num_tokens=d["vocab_size"], dim=d["hidden_size"], heads=heads,
+            dim_head=d.get("head_dim") or d["hidden_size"] // heads,
+            kv_heads=d.get("num_key_value_heads") or heads,
+            ffn_dim=d["intermediate_size"],
+            rotary_theta=float(d.get("rope_theta", 10000.0)), **family(d))
+
+    @classmethod
+    def from_file(cls, path: str) -> "ModelConfig":
+        with open(path) as f:
+            return cls.from_dict(json.load(f))
+
+
+def _starcoder2(d: dict) -> dict:
+    """The repo's first block, as the StarCoder2 files under
+    ``benchmarks/configs/`` run it (their ``changed``): pre-norm RMSNorm at
+    the default eps, rotary GQA, a non-gated erf-GELU feed-forward, no
+    biases, ``sliding_window`` in every layer or none."""
+    layer = LayerConfig(window=d.get("sliding_window"))
+    return dict(layers=(layer,) * d["num_hidden_layers"])
+
+
+def _afmoe(d: dict) -> dict:
+    """Gated attention with q/k norms, four norms a layer, rotary on the
+    sliding layers only, leading dense layers and then sigmoid-routed
+    experts with a shared one (after transformers' ``modeling_afmoe.py``).
+    Where the file holds a chip's share of a deployment, ``num_experts``
+    counts the experts held here, ``published.num_experts`` the router's
+    width and ``first_expert`` the first one held."""
+    if not d.get("route_norm", True) or d.get("score_func") != "sigmoid":
+        raise ValueError(
+            "ModelConfig: the routed layer is written for sigmoid scores "
+            "normalised over the chosen experts (route_norm)")
+    kinds = d["layer_types"]
+    if len(kinds) != d["num_hidden_layers"]:
+        raise ValueError(
+            f"ModelConfig: {len(kinds)} layer_types for "
+            f"{d['num_hidden_layers']} num_hidden_layers")
+    layers = tuple(
+        LayerConfig(
+            window=d["sliding_window"] if sliding else None,
+            rotary=sliding,  # full layers carry no positional encoding
+            ffn="gated" if i < d["num_dense_layers"] else "routed")
+        for i, sliding in enumerate(k == "sliding_attention" for k in kinds))
+    return dict(
+        layers=layers, norm_eps=d["rms_norm_eps"],
+        qk_norm=True, attn_gate=True, sandwich_norm=True,
+        embed_scale=d["hidden_size"] ** 0.5 if d.get("mup_enabled") else 1.0,
+        num_experts=d.get("published", d)["num_experts"],
+        experts_per_token=d["num_experts_per_tok"],
+        expert_dim=d["moe_intermediate_size"],
+        shared_expert_dim=d["moe_intermediate_size"] * d["num_shared_experts"],
+        route_scale=d["route_scale"],
+        first_expert=d.get("first_expert", 0),
+        experts_held=d["num_experts"])
+
+
+_FAMILIES = {"starcoder2": _starcoder2, "afmoe": _afmoe}

@@ -33,13 +33,37 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 class RMSNorm(nn.Module):
     dim: int
+    eps: float = 1e-12
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         gamma = self.param("gamma", nn.initializers.ones, (self.dim,))
         xf = x.astype(jnp.float32)
-        rms = jnp.sqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-12)
+        rms = jnp.sqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
         return ((xf / rms) * gamma).astype(x.dtype)
+
+
+class GatedFeedForward(nn.Module):
+    """``down(silu(gate(h)) * up(h))`` with ``h = RMSNorm(x)``, no biases:
+    the dense feed-forward of the gated-SiLU families and, with
+    ``prenorm=False`` (the caller has normalised), a shared expert."""
+
+    dim: int
+    hidden: int
+    dtype: jnp.dtype | None = None
+    norm_eps: float = 1e-12
+    prenorm: bool = True
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        if self.prenorm:
+            x = RMSNorm(self.dim, self.norm_eps, name="norm")(x)
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype, name=name)
+
+        h = nn.silu(dense(self.hidden, "gate")(x)) * dense(self.hidden, "up")(x)
+        return dense(self.dim, "down")(h)
 
 
 class FeedForward(nn.Module):

@@ -1,0 +1,142 @@
+"""A routed-expert feed-forward that is told which experts it holds.
+
+``RoutedFeedForward`` is one holder's part of an expert-parallel layer:
+the router scores all ``num_experts`` published experts with a sigmoid,
+picks ``experts_per_token`` of them (a per-expert bias moves the choice
+and never the weights), normalises the chosen scores over all of the
+chosen, and this holder computes the ``experts_held`` consecutive experts
+from ``first_expert`` for the (token, expert) pairs that fell on them,
+added to the shared expert's output.  What the absent experts would have
+added is left out: on one chip the layer runs without its exchange, and
+nothing stands in for the other holders.
+
+No pair is dropped and there is no capacity factor.  The pairs are sorted
+by expert and go through ``lax.ragged_dot`` (on the TPU XLA lowers it to
+a grouped matrix product that visits only the groups that have rows, so
+an expert no token chose reads no weights; measured against the other
+forms in CHANGES.md, PR 28) in passes of at most ``PASS_ROWS`` rows: a
+prefill whose pairs fall an eighth on this holder takes one pass, and the
+worst case, every pair here, takes more passes and not more memory.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .layers import GatedFeedForward, RMSNorm
+
+# rows of sorted (token, expert) pairs one pass of the expert products
+# takes: the workspace is PASS_ROWS x (dim + 3 x expert_dim) values
+PASS_ROWS = 32768
+
+COUNTERS = "counters"  # the flax collection the layer sows its counts into
+
+
+class RoutedFeedForward(nn.Module):
+    dim: int
+    expert_dim: int
+    num_experts: int  # the router's width: every published expert
+    experts_per_token: int
+    experts_held: int
+    first_expert: int = 0
+    shared_dim: int = 0  # 0: no shared expert
+    route_scale: float = 1.0
+    norm_eps: float = 1e-12
+    dtype: jnp.dtype | None = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, n, d = x.shape
+        held, f = self.experts_held, self.expert_dim
+        m = RMSNorm(d, self.norm_eps, name="norm")(x).reshape(b * n, d)
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (d, self.num_experts))
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (self.num_experts,))
+        gate_up = self.param("experts_gate_up", init, (held, d, 2 * f))
+        down = self.param("experts_down", init, (held, f, d))
+        dtype = self.dtype or m.dtype
+
+        with jax.named_scope("moe/router"):
+            # float32 at full precision: it is 256 columns wide, and a
+            # rounded score turns a choice that is near a tie
+            scores = jax.nn.sigmoid(jnp.dot(
+                m.astype(jnp.float32), router.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST))
+            _, chosen = lax.top_k(scores + bias, self.experts_per_token)
+            top = jnp.take_along_axis(scores, chosen, axis=-1)
+            weights = self.route_scale * top / (
+                top.sum(-1, keepdims=True) + 1e-20)
+        out = self._routed(m.astype(dtype), chosen, weights,
+                           gate_up.astype(dtype), down.astype(dtype))
+        if self.shared_dim:
+            with jax.named_scope("moe/shared"):
+                out = out + GatedFeedForward(
+                    d, self.shared_dim, dtype=self.dtype, prenorm=False,
+                    name="shared")(m)
+        return out.astype(x.dtype).reshape(b, n, d)
+
+    def _routed(self, m, chosen, weights, gate_up, down):
+        """Sum over the held experts a token chose of ``weight x expert(m)``,
+        float32 ``(tokens, dim)``."""
+        tokens, d = m.shape
+        k, held, f = self.experts_per_token, self.experts_held, self.expert_dim
+        pairs = tokens * k
+        rows = min(pairs, PASS_ROWS)
+        passes = -(-pairs // rows)
+
+        with jax.named_scope("moe/dispatch"):
+            local = chosen - self.first_expert
+            here = (local >= 0) & (local < held)
+            # pairs sorted by held expert, the absent experts' pairs last
+            key = jnp.where(here, local, held).reshape(pairs)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            place = jnp.argsort(order).astype(jnp.int32).reshape(tokens, k)
+            counts = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
+                             dtype=jnp.int32)
+            ends = jnp.cumsum(counts)
+            starts, n_here = ends - counts, ends[-1]
+            order = jnp.pad(order, (0, passes * rows - pairs))
+        # free unless the caller asks: apply(..., mutable=["counters"])
+        for name, value in (("tokens_per_expert", counts),
+                            ("held_share", n_here / pairs),
+                            ("experts_touched", jnp.sum(counts > 0))):
+            if not self.is_initializing():
+                self.sow(COUNTERS, name, value, init_fn=lambda: None,
+                         reduce_fn=lambda _, new: new)
+
+        def one_pass(lo):
+            with jax.named_scope("moe/dispatch"):
+                source = lax.dynamic_slice_in_dim(order, lo, rows) // k
+                xs = jnp.take(m, source, axis=0)
+                sizes = (jnp.clip(ends, lo, lo + rows)
+                         - jnp.clip(starts, lo, lo + rows))
+            with jax.named_scope("moe/experts"):
+                h = lax.ragged_dot(xs, gate_up, sizes)
+                y = lax.ragged_dot(nn.silu(h[:, :f]) * h[:, f:], down, sizes)
+            with jax.named_scope("moe/combine"):
+                # rows past the last pair are not the product's to define
+                y = jnp.where((jnp.arange(rows) < n_here - lo)[:, None], y, 0)
+                at = place - lo
+                mine = here & (at >= 0) & (at < rows)
+                w = jnp.where(mine, weights, 0.0)
+                at = jnp.clip(at, 0, rows - 1)
+                return sum(w[:, j, None] * jnp.take(y, at[:, j], axis=0)
+                           for j in range(k))
+
+        if passes == 1:
+            return one_pass(0)
+
+        def body(carry):
+            lo, acc = carry
+            return lo + rows, acc + one_pass(lo)
+
+        _, out = lax.while_loop(
+            lambda carry: carry[0] < n_here, body,
+            (jnp.int32(0), jnp.zeros((tokens, d), jnp.float32)))
+        return out

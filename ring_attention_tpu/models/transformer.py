@@ -46,8 +46,12 @@ from ..parallel.sharding import (
 from ..utils.validate import check_tokens_input
 from .attention import RingAttention
 from .. import masks as mask_algebra
-from .layers import FeedForward, RMSNorm
+from .config import ModelConfig
+from .layers import FeedForward, GatedFeedForward, RMSNorm
+from .moe import RoutedFeedForward
 from .remat import REMAT_POLICIES, resolve_remat_policy
+
+PROBES = "probes"  # the flax collection the stack walker sows into
 
 
 def _position_nll(
@@ -109,13 +113,6 @@ class RingTransformer(nn.Module):
     pallas_head_chunks: int | None = None
     # see RingAttention.quantize_cache (int8 decode KV cache)
     quantize_cache: bool = False
-    # size each layer's decode cache to its lookback window instead of
-    # max_len (local decode only): a layer with max_lookback_seq_len=W
-    # stores and reads O(W) cache rows per step regardless of context
-    # length — the decode-side payoff of the local->global layer ladder.
-    # The cache is a ring buffer (writes at pos % size); exactness is
-    # untouched because those layers never attend past their window
-    windowed_cache: bool = False
     # "ring" | "zigzag" | "ulysses" | "hybrid" (Ulysses x Ring factored
     # mesh, create_mesh(ulysses_size=U) — see docs/hybrid_parallelism.md)
     sequence_parallel: str = "ring"
@@ -158,6 +155,28 @@ class RingTransformer(nn.Module):
     # docs/memory.md.
     loss_chunk_size: int | None = None
     dtype: jnp.dtype | None = None
+    # the frozen description the stack is built from (models/config.py):
+    # set by ``from_config``; None builds the uniform block of the keyword
+    # arguments above (``_config``)
+    config: ModelConfig | None = None
+
+    @classmethod
+    def from_config(cls, config: ModelConfig, **options) -> "RingTransformer":
+        """The model ``config`` describes, causal; ``options`` are how it is
+        run (mesh, kernels, remat, chunking, dtype), not what it computes."""
+        return cls(
+            num_tokens=config.num_tokens, dim=config.dim, depth=config.depth,
+            heads=config.heads, dim_head=config.dim_head,
+            kv_heads=config.kv_heads, causal=True, config=config, **options)
+
+    def _config(self) -> ModelConfig:
+        if self.config is not None:
+            return self.config
+        return ModelConfig.uniform(
+            num_tokens=self.num_tokens, dim=self.dim, depth=self.depth,
+            heads=self.heads, dim_head=self.dim_head, kv_heads=self.kv_heads,
+            ffn_dim=self.dim * self.ff_mult, windows=self._lookbacks(),
+            rotary=self.rotary)
 
     def setup(self):
         # a negative chunk size used to surface as an obscure shape error
@@ -178,23 +197,23 @@ class RingTransformer(nn.Module):
                 f"lengths that don't divide are padded)"
             )
         policies = self._remat_policies()
+        cfg = self._config()
         self.embed = nn.Embed(self.num_tokens, self.dim, dtype=self.dtype)
         # flax-lifted remat (NOT raw jax.checkpoint: param creation during
         # init is a side effect that would leak tracers out of the
         # checkpointed trace); one lifted class per layer so the policy is
         # per-layer selectable
+        ffn = {"gelu": FeedForward, "gated": GatedFeedForward,
+               "routed": RoutedFeedForward}
+        attn_classes = [RingAttention] * self.depth
+        ff_classes = [ffn[layer.ffn] for layer in cfg.layers]
         if self.remat:
-            attn_classes = [
-                nn.remat(RingAttention, policy=resolve_remat_policy(p))
-                for p in policies
-            ]
-            ff_classes = [
-                nn.remat(FeedForward, policy=resolve_remat_policy(p))
-                for p in policies
-            ]
-        else:
-            attn_classes = [RingAttention] * self.depth
-            ff_classes = [FeedForward] * self.depth
+            def rematted(classes):
+                return [nn.remat(c, policy=resolve_remat_policy(p))
+                        for c, p in zip(classes, policies)]
+
+            attn_classes = rematted(attn_classes)
+            ff_classes = rematted(ff_classes)
         self.attn_layers = [
             attn_cls(
                 dim=self.dim,
@@ -206,9 +225,10 @@ class RingTransformer(nn.Module):
                 bucket_size=self.bucket_size,
                 use_ring=self.use_ring,
                 force_regular_attn=self.force_regular_attn,
-                rotary=self.rotary,
+                rotary=layer.rotary,
+                rotary_theta=cfg.rotary_theta,
                 softclamp_value=self.softclamp_value,
-                max_lookback_seq_len=lookback,
+                max_lookback_seq_len=layer.window,
                 mask=layer_mask,
                 auto_shard=False,  # sharded once at model top
                 mesh=self.mesh,
@@ -222,29 +242,89 @@ class RingTransformer(nn.Module):
                 ring_counter_rotate=self.ring_counter_rotate,
                 ring_hop_compression=self.ring_hop_compression,
                 compute_dtype=self.compute_dtype,
+                qk_norm=cfg.qk_norm,
+                out_gate=cfg.attn_gate,
+                norm_eps=cfg.norm_eps,
                 dtype=self.dtype,
             )
-            for attn_cls, lookback, layer_mask in zip(
-                attn_classes, self._lookbacks(), self._masks()
+            for attn_cls, layer, layer_mask in zip(
+                attn_classes, cfg.layers, self._masks()
             )
         ]
         self.ff_layers = [
-            ff_cls(
-                self.dim, self.ff_mult, dtype=self.dtype,
+            self._feed_forward(ff_cls, layer.ffn, cfg)
+            for ff_cls, layer in zip(ff_classes, cfg.layers)
+        ]
+        # a second norm on each sub-block's output (none: plain pre-norm)
+        post = self.depth if cfg.sandwich_norm else 0
+        self.post_attn_norms = [
+            RMSNorm(self.dim, cfg.norm_eps) for _ in range(post)]
+        self.post_ff_norms = [
+            RMSNorm(self.dim, cfg.norm_eps) for _ in range(post)]
+        self.final_norm = RMSNorm(self.dim, cfg.norm_eps)
+        self.to_logits = nn.Dense(self.num_tokens, use_bias=False, dtype=self.dtype)
+
+    def _feed_forward(self, ff_cls, kind: str, cfg: ModelConfig):
+        if kind == "gelu":
+            return ff_cls(
+                self.dim, cfg.ffn_dim // self.dim, dtype=self.dtype,
                 chunk_size=self.ff_chunk_size,
                 seq_shards=self._ring_size(),
                 mesh=self.mesh if self.auto_shard else None,
             )
-            for ff_cls in ff_classes
-        ]
-        self.final_norm = RMSNorm(self.dim)
-        self.to_logits = nn.Dense(self.num_tokens, use_bias=False, dtype=self.dtype)
+        if kind == "gated":
+            return ff_cls(self.dim, cfg.ffn_dim, dtype=self.dtype,
+                          norm_eps=cfg.norm_eps)
+        return ff_cls(
+            self.dim, cfg.expert_dim, num_experts=cfg.num_experts,
+            experts_per_token=cfg.experts_per_token,
+            experts_held=cfg.experts_held, first_expert=cfg.first_expert,
+            shared_dim=cfg.shared_expert_dim, route_scale=cfg.route_scale,
+            norm_eps=cfg.norm_eps, dtype=self.dtype)
 
     def _ring_size(self) -> int:
         """Total sequence-parallel world (both axes of a factored mesh)."""
         if self.mesh is None or not self.use_ring or self.force_regular_attn:
             return 1
         return seq_world(self.mesh)
+
+    def _embed(self, tokens: jax.Array) -> jax.Array:
+        x = self.embed(tokens)
+        scale = self._config().embed_scale
+        return x if scale == 1.0 else x * jnp.asarray(scale, x.dtype)
+
+    def _blocks(self, x: jax.Array, attend) -> jax.Array:
+        """The one walk over the stack, for the forward, the prefill and
+        the decode step alike: ``attend(i, attn, x)`` is layer ``i``'s
+        attention output, however that call reaches it.
+
+        Each layer's attention output (what the kernels and the cache
+        made, before any norm) is sown as ``probes/attn_out_<i>``: free
+        unless the caller asks, ``apply(..., mutable=["probes"])``."""
+        sandwich = self._config().sandwich_norm
+        for i, (attn, ff) in enumerate(zip(self.attn_layers, self.ff_layers)):
+            a = attend(i, attn, x)
+            if not self.is_initializing():
+                self.sow(PROBES, f"attn_out_{i}", a, init_fn=lambda: None,
+                         reduce_fn=lambda _, new: new)
+            x = (self.post_attn_norms[i](a) if sandwich else a) + x
+            f = ff(x)
+            x = (self.post_ff_norms[i](f) if sandwich else f) + x
+        return self.final_norm(x)
+
+    def _cached_blocks(self, x, cache, attend):
+        """``_blocks`` for the two calls that carry a cache: ``attend(attn,
+        x, k, v)`` returns the output and the layer's updated k and v."""
+        new_k, new_v = [], []
+
+        def through_cache(i, attn, x):
+            a, ck, cv = attend(attn, x, cache["k"][i], cache["v"][i])
+            new_k.append(ck)
+            new_v.append(cv)
+            return a
+
+        x = self._blocks(x, through_cache)
+        return x, {"k": new_k, "v": new_v}
 
     def _ulysses_size(self) -> int:
         if self.mesh is None or not is_factored(self.mesh):
@@ -384,7 +464,7 @@ class RingTransformer(nn.Module):
                                                  value=PAD_SEGMENT_ID)
                 segment_ids = layout_permute(segment_ids, scheme, factor)
 
-        x = self.embed(tokens)
+        x = self._embed(tokens)
         if ring > 1 and self.auto_shard:
             x = lax.with_sharding_constraint(
                 x, NamedSharding(
@@ -392,11 +472,7 @@ class RingTransformer(nn.Module):
                 )
             )
 
-        for attn, ff in zip(self.attn_layers, self.ff_layers):
-            x = attn(x, mask, segment_ids) + x
-            x = ff(x) + x
-
-        x = self.final_norm(x)
+        x = self._blocks(x, lambda i, attn, x: attn(x, mask, segment_ids))
 
         if return_loss and self.loss_chunk_size:
             # the (b, n, vocab) logits never materialize, and under a
@@ -538,11 +614,6 @@ class RingTransformer(nn.Module):
                 "factored hybrid mesh is a training/forward layout — decode "
                 "with create_mesh(ring_size=...)"
             )
-        if self.windowed_cache:
-            assert ring <= 1, (
-                "windowed_cache is a local-decode optimization; the "
-                "ring-sharded cache uses absolute positions"
-            )
         kvh = self.kv_heads or self.heads
         dtype = self.dtype or jnp.float32
 
@@ -567,10 +638,14 @@ class RingTransformer(nn.Module):
                     self.mesh, P(data_partition(self.mesh), None, SEQ_AXIS, None)))
             return entry
 
+        # a windowed layer never reads past its window, so off the ring
+        # its cache is a ring buffer of that many slots (writes land at
+        # pos % size, RingAttention._buffer_mask tells which slots count);
+        # the ring-sharded cache keeps absolute positions
         sizes = [
-            min(max_len, lb) if self.windowed_cache and lb is not None
-            else max_len
-            for lb in self._lookbacks()
+            max_len if layer.window is None or ring > 1
+            else min(max_len, layer.window)
+            for layer in self._config().layers
         ]
         return {
             "k": [make_entry(s) for s in sizes],
@@ -585,17 +660,10 @@ class RingTransformer(nn.Module):
     ) -> tuple[jax.Array, dict[str, Any]]:
         """Next-token logits given the token at ``pos`` and the cache of
         positions ``[0, pos)``.  Returns ``(logits (b, vocab), new_cache)``."""
-        x = self.embed(token[:, None])
-        new_k, new_v = [], []
-        for i, (attn, ff) in enumerate(zip(self.attn_layers, self.ff_layers)):
-            a, ck, cv = attn.decode_step(x, cache["k"][i], cache["v"][i], pos)
-            new_k.append(ck)
-            new_v.append(cv)
-            x = a + x
-            x = ff(x) + x
-        x = self.final_norm(x)
-        logits = self.to_logits(x)[:, 0]
-        return logits, {"k": new_k, "v": new_v}
+        x, cache = self._cached_blocks(
+            self._embed(token[:, None]), cache,
+            lambda attn, x, k, v: attn.decode_step(x, k, v, pos))
+        return self.to_logits(x)[:, 0], cache
 
     def prefill(
         self,
@@ -606,17 +674,10 @@ class RingTransformer(nn.Module):
 
         Returns ``(last_logits (b, vocab), cache)`` — n flash-prefilled
         positions instead of n sequential decode steps."""
-        x = self.embed(tokens)
-        new_k, new_v = [], []
-        for i, (attn, ff) in enumerate(zip(self.attn_layers, self.ff_layers)):
-            a, ck, cv = attn.prefill(x, cache["k"][i], cache["v"][i])
-            new_k.append(ck)
-            new_v.append(cv)
-            x = a + x
-            x = ff(x) + x
-        x = self.final_norm(x)
-        logits = self.to_logits(x)[:, -1]
-        return logits, {"k": new_k, "v": new_v}
+        x, cache = self._cached_blocks(
+            self._embed(tokens), cache,
+            lambda attn, x, k, v: attn.prefill(x, k, v))
+        return self.to_logits(x)[:, -1], cache
 
     def generate(
         self,

@@ -394,6 +394,17 @@ STAGES: list[tuple[str, str, str, str, str | None]] = [
     ("_valid_labels", "loss", _C, _L, None),
     ("to_logits", "logits head", _C, _L, None),
     ("final_norm", "final norm", _C, _L, None),
+    # the routed feed-forward (models/moe.py) and the gated attention's
+    # own parts, ahead of the module rows they sit inside
+    ("moe/router", "expert router", _C, "router", None),
+    ("moe/dispatch", "expert dispatch", _C, "experts", None),
+    ("moe/experts", "expert products", _C, "experts", None),
+    ("moe/shared", "shared expert", _C, "experts", None),
+    ("moe/combine", "expert combine", _C, "experts", None),
+    ("attn/qk_norm", "q/k norm", _C, "attention projections", None),
+    ("attn/gate", "attention output gate", _C, "attention projections", None),
+    ("post_attn_norms_", "post-attention norm", _C, "attention projections", None),
+    ("post_ff_norms_", "post-feed-forward norm", _C, "feed-forward", None),
     ("ff_layers_", "feed-forward", _C, "feed-forward", None),
     ("attn_layers_", "attention projections", _C, "attention projections", None),
     ("embed", "embedding", _C, "embed", None),

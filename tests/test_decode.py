@@ -239,16 +239,15 @@ def test_generate_256_on_ring(rng):
 
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_windowed_cache_decode(rng, use_pallas):
-    """windowed_cache: a lookback layer's ring-buffer cache (W slots
-    instead of max_len) decodes identically to the full-length cache —
-    per-layer sizes, mixed windowed/global depth."""
+    """A lookback layer's cache is a ring buffer (W slots instead of
+    max_len) and decodes identically to the full forward — per-layer
+    sizes, mixed windowed/global depth."""
     kw = dict(
         num_tokens=VOCAB, dim=32, depth=2, heads=4, dim_head=8,
         causal=True, bucket_size=8, use_ring=False,
         max_lookback_seq_len=(4, None), use_pallas=use_pallas,
     )
-    model = RingTransformer(windowed_cache=True, **kw)
-    ref_model = RingTransformer(**kw)
+    model = ref_model = RingTransformer(**kw)
     tokens = jnp.asarray(rng.integers(0, VOCAB, (2, 12)), jnp.int32)
     params = ref_model.init(jax.random.PRNGKey(0), tokens)
     full = ref_model.apply(params, tokens)
@@ -269,8 +268,7 @@ def test_windowed_cache_prefill_long_prompt(rng):
         causal=True, bucket_size=8, use_ring=False,
         max_lookback_seq_len=4,
     )
-    model = RingTransformer(windowed_cache=True, **kw)
-    ref_model = RingTransformer(**kw)
+    model = ref_model = RingTransformer(**kw)
     tokens = jnp.asarray(rng.integers(0, VOCAB, (2, 12)), jnp.int32)
     params = ref_model.init(jax.random.PRNGKey(0), tokens)
     full = ref_model.apply(params, tokens)
@@ -291,10 +289,14 @@ def test_windowed_cache_prefill_long_prompt(rng):
     # reduction-order tolerance (the ring buffer rotates slot order, so
     # the softmax sums reassociate at ulp level) — catches mis-rolled
     # rows/scales, not just shape bugs
-    qwin = RingTransformer(windowed_cache=True, quantize_cache=True, **kw)
-    qfull = RingTransformer(quantize_cache=True, **kw)
+    # (a full-length cache for the same layers: the sizes a model without
+    # the lookback gives; the lookback model decodes through either)
+    qwin = qfull = RingTransformer(quantize_cache=True, **kw)
     cw = qwin.apply(params, 2, 16, method=RingTransformer.init_cache)
-    cf = qfull.apply(params, 2, 16, method=RingTransformer.init_cache)
+    cf = RingTransformer(
+        quantize_cache=True, **{**kw, "max_lookback_seq_len": None}
+    ).apply(params, 2, 16, method=RingTransformer.init_cache)
+    assert cw["k"][0][0].shape[2] == 4 and cf["k"][0][0].shape[2] == 16
     lw, cw = qwin.apply(params, tokens[:, :10], cw,
                         method=RingTransformer.prefill)
     lf, cf = qfull.apply(params, tokens[:, :10], cf,
@@ -308,9 +310,7 @@ def test_windowed_cache_prefill_long_prompt(rng):
         np.testing.assert_allclose(lw, lf, atol=1e-4)
 
     # over-long prompt on an unwindowed cache must hard-error, not truncate
-    bad = RingTransformer(
-        **{**kw, "max_lookback_seq_len": None}, windowed_cache=True
-    )
+    bad = RingTransformer(**{**kw, "max_lookback_seq_len": None})
     c = bad.apply(params, 2, 8, method=RingTransformer.init_cache)
     with pytest.raises(ValueError, match="window-sized"):
         bad.apply(params, tokens, c, method=RingTransformer.prefill)

@@ -192,7 +192,8 @@ def test_stages_table_names_every_layer_once_per_needle():
     assert len(needles) == len(set(needles))
     assert {row[3] for row in STAGES} == {
         "ring", "flash kernels", "xla flash", "optimizer", "loss and head",
-        "feed-forward", "attention projections", "embed"}
+        "feed-forward", "attention projections", "embed", "experts",
+        "router"}
     assert {row[4] for row in STAGES} <= {None, "backward", "update"}
     # a kernel is a kernel wherever it is called from; the rest by scope
     assert layer_of("flash_partials_tile.3",
@@ -201,6 +202,12 @@ def test_stages_table_names_every_layer_once_per_needle():
     assert layer_of("fusion.7", "jit(step)/attn_layers_0/ring/hop2/mul")[
         0] == "ring"
     assert layer_of("fusion.9", "") == ("other", "forward")
+    # the routed layer's parts go by their own scopes, not the module's
+    path = "jit(f)/RingTransformer.prefill/ff_layers_3/moe/{}/x"
+    assert layer_of("ragged-dot-none.2", path.format("experts"))[0] == "experts"
+    assert layer_of("fusion.4", path.format("router"))[0] == "router"
+    assert layer_of("fusion.5", "jit(f)/attn_layers_1.prefill/attn/gate/mul")[
+        0] == "attention projections"
 
 
 def test_trace_report_prints_the_layer_table_without_a_metrics_dir():
