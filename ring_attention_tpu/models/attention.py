@@ -473,15 +473,27 @@ class RingAttention(nn.Module):
             freqs = rotary_freqs(jnp.arange(n), self.dim_head, self.rotary_theta)
             q = apply_rotary(q, freqs)
             k = apply_rotary(k, freqs)
-        window = self._eff_lookback()
-        causal = self._eff_causal()
         # a mask-declared packing: doc_starts feed the Pallas compact
         # grid directly; the XLA/oracle paths realize them as runtime ids
         form = self._mask_form()
         doc_starts = (form.doc_starts
                       if form is not None and segment_ids is None else None)
+        return self._attend(
+            q, k, v, mask, causal=self._eff_causal(),
+            segment_ids=segment_ids, doc_starts=doc_starts,
+        )
+
+    def _attend(self, q, k, v, mask=None, *, causal, segment_ids=None,
+                doc_starts=None):
+        """Attention over one device's whole span, on the path the model
+        declares: the dense oracle (``force_regular_attn``), the Pallas
+        kernel (``_use_pallas()``) or the XLA blockwise scan.  The ONE
+        choice for the forward pass and ``prefill`` off the ring, so the
+        two cannot drift; rotary is the caller's (``prefill`` keeps the
+        rotated ``k`` for the cache)."""
+        window = self._eff_lookback()
         doc_ids = (None if doc_starts is None
-                   else _doc_runtime_ids(doc_starts, n, q.shape[0]))
+                   else _doc_runtime_ids(doc_starts, q.shape[2], q.shape[0]))
         if self.force_regular_attn and window is None:
             return default_attention(
                 q, k, v, mask, causal=causal,
@@ -843,7 +855,12 @@ class RingAttention(nn.Module):
 
         One O(n^2)-FLOPs flash pass instead of n decode steps; the written
         K/V are rotary-applied exactly as ``decode_step`` writes them, so
-        decoding can continue from position ``n``.  With a mesh, the prompt
+        decoding can continue from position ``n``.  Off the ring the pass
+        takes the forward's own dispatch (``_attend``): the Pallas flash
+        kernel over the causal half (a windowed layer: its band) when the
+        model declares it (``use_pallas`` / ``impl``: bf16 operands, f32
+        accumulators), else the XLA blockwise scan (f32 products over the
+        whole square, masked).  With a mesh, the prompt
         is padded onto the ring and attention runs sequence-parallel
         (contiguous layout, like the decode cache) — per-device memory
         scales as n/ring, same as the training forward.  Returns
@@ -874,11 +891,7 @@ class RingAttention(nn.Module):
         if ring:
             out = self._ring_prefill_attend(q, k, v)
         else:
-            out = flash_attention(
-                q, k, v, causal=True, bucket_size=self.bucket_size,
-                window=self._eff_lookback(),
-                softclamp_value=self.softclamp_value,
-            )
+            out = self._attend(q, k, v, causal=True)
         if n > size:
             # keep the last `size` rows, rolled into ring-buffer slot
             # order: cache[s] = row at position p ≡ s (mod size)
@@ -897,6 +910,15 @@ class RingAttention(nn.Module):
             cache_k = lax.dynamic_update_slice(cache_k, k_rows.astype(cache_k.dtype), zeros)
             cache_v = lax.dynamic_update_slice(cache_v, v_rows.astype(cache_v.dtype), zeros)
 
+        if not ring and self._use_pallas():
+            # tie the cache write to its layer: free of it, XLA schedules
+            # every layer's write at the program's end and carries all
+            # their K/V rows that far, which around the kernel's fixed
+            # layouts grows the heap (4.07 for 3.74 GiB of temporaries at
+            # 4 x 8,192 tokens x 5 layers: PERF.md section 6, PR 29).
+            # Values are untouched
+            out, cache_k, cache_v = lax.optimization_barrier(
+                (out, cache_k, cache_v))
         return self._project_out(out, gate), cache_k, cache_v
 
     def _ring_prefill_attend(self, q, k, v):

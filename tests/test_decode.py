@@ -432,6 +432,77 @@ def test_prefill_then_decode(rng):
         np.testing.assert_allclose(logits, full[:, i], atol=ATOL)
 
 
+def _prefill_pair(window, group):
+    """The same model twice: XLA blockwise prefill, Pallas kernel prefill
+    (interpret mode here).  ``window`` is per layer; kv_heads = 1 or 2."""
+    kv_heads = 2 if group == 3 else 1
+    kw = dict(
+        num_tokens=VOCAB, dim=32, depth=2, heads=kv_heads * group,
+        dim_head=8, kv_heads=kv_heads, causal=True, bucket_size=4,
+        use_ring=False, max_lookback_seq_len=window,
+    )
+    return (RingTransformer(use_pallas=False, **kw),
+            RingTransformer(use_pallas=True, **kw))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("group", [3, 9])
+@pytest.mark.parametrize("window", [None, (4, None)],
+                         ids=["full", "window4"])
+def test_prefill_pallas_matches_xla(rng, window, group, batch):
+    """``prefill`` takes the kernel path the model declares (the forward's
+    own dispatch): logits, every row left in every layer's cache and four
+    decoded tokens agree with the XLA blockwise prefill — full causal, and
+    a windowed layer whose ring-buffer cache (4 slots) is shorter than the
+    prompt (10), beside a full one."""
+    xla, pallas = _prefill_pair(window, group)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (batch, 14)), jnp.int32)
+    params = xla.init(jax.random.PRNGKey(0), tokens)
+    full = xla.apply(params, tokens)
+
+    results = []
+    for model in (xla, pallas):
+        cache = model.apply(params, batch, 16,
+                            method=RingTransformer.init_cache)
+        if window is not None:
+            assert cache["k"][0].shape[2] == 4  # shorter than the prompt
+        prefill, step = _jit_decode_fns(model)
+        logits, cache = prefill(params, tokens[:, :10], cache)
+        prefilled = jax.tree.leaves(cache)
+        got = [logits]
+        for i in range(10, 14):
+            logits, cache = step(params, tokens[:, i], cache, jnp.int32(i))
+            got.append(logits)
+        results.append((jnp.stack(got, 1), prefilled, jax.tree.leaves(cache)))
+    (l_xla, pre_xla, end_xla), (l_pal, pre_pal, end_pal) = results
+    np.testing.assert_allclose(l_xla, full[:, 9:], atol=ATOL)
+    np.testing.assert_allclose(l_pal, l_xla, atol=ATOL)
+    for a, b in zip(pre_pal + end_pal, pre_xla + end_xla):
+        np.testing.assert_allclose(a, b, atol=ATOL)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_prefill_lowers_to_declared_kernel(rng, use_pallas):
+    """The dispatch is static, so the lowered prefill shows it: a
+    ``pallas_call`` and no ``flash/fwd`` scope (the XLA blockwise scan's)
+    with ``use_pallas=True``, the reverse without."""
+    model = _prefill_pair((4, None), 3)[use_pallas]
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (1, 10)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    cache = model.apply(params, 1, 16, method=RingTransformer.init_cache)
+
+    def prefill(p, t, c):
+        return model.apply(p, t, c, method=RingTransformer.prefill)
+
+    jaxpr = str(jax.make_jaxpr(prefill)(params, tokens, cache))
+    text = jax.jit(prefill).lower(params, tokens, cache).as_text(
+        debug_info=True)
+    assert ("pallas_call" in jaxpr) == use_pallas
+    assert ("flash/fwd" in text) == (not use_pallas)
+    # only the kernel path pins each layer's cache write to its layer
+    assert ("optimization_barrier" in jaxpr) == use_pallas
+
+
 def test_generate_edge_asserts(rng):
     model = RingTransformer(
         num_tokens=VOCAB, dim=16, depth=1, heads=2, dim_head=8,
