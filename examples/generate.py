@@ -7,7 +7,7 @@ pass --fake-devices 8 for a simulated CPU mesh:
 
   python examples/generate.py --fake-devices 8 --steps 16
 
-The 2^20-token decode shape of docs/hwlogs/results.jsonl (one v5e chip):
+A 2^20-token decode on one v5e chip (measured cells: PERF.md, benchmarks/):
 
   python examples/generate.py --dim 512 --depth 2 --heads 8 --kv-heads 2 \
       --dim-head 64 --bf16 --use-pallas --max-len 1048576 --prompt-len 4096

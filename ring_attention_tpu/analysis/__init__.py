@@ -19,10 +19,6 @@ The passes, all CPU-runnable in tier-1 (see docs/static_analysis.md):
     prover: the compact skip grids held to a global-position oracle for
     soundness (no live tile skipped), tightness (no dead tile visited),
     and schedule completeness, per strategy x layout x masking row;
-  - :mod:`~ring_attention_tpu.analysis.perfgate` — the perf-observatory
-    regression gate: BENCH_r*.json / hwlog history ingest + CPU-signal
-    checks against ``docs/perf_baseline.json`` (wedge-honest: rounds
-    whose TPU probe never ran are recorded, never silently passed);
   - :mod:`~ring_attention_tpu.analysis.schedverify` — the DMA/semaphore
     protocol verifier for the fused-ring kernel: jaxpr extraction of
     every DMA/semaphore site cross-checked against the declared
@@ -32,10 +28,9 @@ The passes, all CPU-runnable in tier-1 (see docs/static_analysis.md):
     deadlock freedom under arbitrary compute skew.
 
 CLI: ``tools/check_contracts.py`` (contract suite; ``--coverage`` /
-``--dataflow`` for the prover and jaxpr audits), ``tools/perf_gate.py``
-(the regression gate), and ``python -m ring_attention_tpu.analysis``
-(lint + dtype audit + precision flow + divergence + coverage +
-compile-free gate self-run).
+``--dataflow`` for the prover and jaxpr audits) and
+``python -m ring_attention_tpu.analysis`` (lint + dtype audit + precision
+flow + divergence + coverage + protocol + elastic self-run).
 On a host without jax, run the lint as a plain script —
 ``python ring_attention_tpu/analysis/lint.py`` — which skips this
 package ``__init__`` chain entirely.
@@ -51,16 +46,6 @@ from .dataflow import (
     run_precision_suite,
 )
 from .lint import Violation, lint_file, lint_package, lint_source
-from .perfgate import (
-    GATE_SCHEMA_VERSION,
-    GateFinding,
-    GateReport,
-    History,
-    collect_current,
-    load_history,
-    run_gate,
-    write_baseline,
-)
 from .recompile import (
     CompileCounter,
     RetraceError,
@@ -73,23 +58,15 @@ from .recompile import (
 
 __all__ = [
     "CompileCounter",
-    "GATE_SCHEMA_VERSION",
-    "GateFinding",
-    "GateReport",
-    "History",
     "JaxprWalker",
     "PrecisionFlow",
     "RetraceError",
     "Violation",
     "audit_precision_flow",
     "check_spmd_divergence",
-    "collect_current",
     "collective_signature",
-    "load_history",
     "run_divergence_suite",
-    "run_gate",
     "run_precision_suite",
-    "write_baseline",
     "assert_compiles_once",
     "audit_accumulator_dtypes",
     "audit_donation",

@@ -8,14 +8,9 @@ checker over every strategy when multiple simulated devices are
 available), the tile-coverage prover (unless ``--no-coverage``), the
 fused-ring DMA/semaphore protocol verifier (unless ``--no-schedverify``:
 the rings-2..8 model check always, plus the jaxpr extraction
-cross-check when virtual devices are available), the
-elastic checkpoint contracts (unless ``--no-elastic``), and
-the perf-observatory gate (unless ``--no-gate``): benchmark-history
-trend checks plus the arithmetic comms-reference table and the coverage
-fingerprint against ``docs/perf_baseline.json``.  The default gate pass
-compiles nothing; ``--gate-full`` adds the collective fingerprint and
-the reference-step compiled cost/memory signals (what
-``tools/perf_gate.py --check`` runs).  Exit status 0 = clean.
+cross-check when virtual devices are available), and the
+elastic checkpoint contracts (unless ``--no-elastic``).  Exit status
+0 = clean.
 
 The ``-m`` form imports the package ``__init__`` chain (which needs
 jax); on a host without jax, run the lint as a plain script instead:
@@ -30,8 +25,8 @@ from __future__ import annotations
 import argparse
 import os
 
+from . import recompile
 from .lint import lint_package
-from . import perfgate, recompile
 
 
 def _request_virtual_devices(n: int = 8) -> None:
@@ -61,7 +56,8 @@ def main(argv: list[str] | None = None) -> int:
         prog="python -m ring_attention_tpu.analysis",
         description="lint the package tree + audit kernel accumulator "
                     "dtypes + precision-flow/divergence dataflow passes + "
-                    "tile-coverage prover + perf-observatory gate",
+                    "tile-coverage prover + fused-ring protocol verifier "
+                    "+ elastic checkpoint contracts",
     )
     parser.add_argument("--no-audit", action="store_true",
                         help="skip the (jax-importing) f32 accumulator audit")
@@ -77,13 +73,6 @@ def main(argv: list[str] | None = None) -> int:
                         help="skip the elastic checkpoint contracts "
                              "(manifest round-trip, resharded==direct "
                              "load, corrupt-shard fallback, debris sweep)")
-    parser.add_argument("--no-gate", action="store_true",
-                        help="skip the perf gate (history + comms baseline)")
-    parser.add_argument("--gate-full", action="store_true",
-                        help="gate on the full CPU signal set (fingerprint "
-                             "+ reference-step compile) — pays compiles; "
-                             "the default gates only the compile-free "
-                             "signals")
     args = parser.parse_args(argv)
 
     notes: list[str] = []
@@ -140,14 +129,6 @@ def main(argv: list[str] | None = None) -> int:
                 "with < 4 devices (tools/check_contracts.py --elastic "
                 "runs them with virtual devices)"
             )
-    if not args.no_gate:
-        if args.gate_full:
-            current = perfgate.collect_current()
-        else:
-            current = perfgate.collect_current(strategies=None,
-                                               compiled=False)
-        report = perfgate.run_gate(current)
-        failures.extend(str(f) for f in report.findings)
     for line in failures:
         print(line)
     for line in notes:

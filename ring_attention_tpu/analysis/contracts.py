@@ -349,14 +349,43 @@ def _parse_replica_groups(line: str) -> list[list[int]] | None | str:
 # ---------------------------------------------------------------------------
 
 
+def _arrays_moved(m: re.Match) -> int:
+    """Arrays one collective instruction (a :data:`_HLO_COLLECTIVE_RE`
+    match) moves: the top-level operands in the parentheses after its
+    opcode.  XLA's combiners fold neighbouring all-reduces (and the like)
+    into ONE tuple-shaped instruction — ``(f32[..], f32[..])
+    all-reduce(%a, %b)`` — and which neighbours it folds moves with the
+    XLA version; the program asked for one collective per array, so that
+    is what the contracts count.  An ``all-to-all`` is the exception: its
+    tuple form is ONE array handed over as a piece per peer."""
+    if m.group(1) == "all-to-all":
+        return 1
+    depth, n = 0, 1
+    for ch in m.group(0)[m.end(1) - m.start(0):]:
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                break
+        elif ch == "," and depth == 1:
+            n += 1
+    return n
+
+
 def hlo_collective_sequence(txt: str) -> list[str]:
-    """Collective kinds in program order — the telemetry pin's signature:
-    an instrumented program must issue the same sequence as its base."""
-    return [m.group(1) for m in _HLO_COLLECTIVE_RE.finditer(txt)]
+    """Collective kinds in program order, one entry per array moved (see
+    :func:`_arrays_moved`) — the telemetry pin's signature: an
+    instrumented program must issue the same sequence as its base."""
+    return [
+        m.group(1)
+        for m in _HLO_COLLECTIVE_RE.finditer(txt)
+        for _ in range(_arrays_moved(m))
+    ]
 
 
 def hlo_collective_counts(txt: str) -> dict[str, int]:
-    """Collective instruction counts per kind in optimized HLO text."""
+    """Collectives per kind in optimized HLO text, one per array moved."""
     return dict(Counter(hlo_collective_sequence(txt)))
 
 
@@ -492,6 +521,10 @@ def jaxpr_collectives(closed_jaxpr) -> JaxprCollectives:
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
             if name in JAXPR_COLLECTIVE_PRIMS:
+                # under check_vma jax binds ``psum_invariant`` /
+                # ``all_gather_invariant`` for a plain ``lax.psum`` /
+                # ``lax.all_gather``: the same collective by another name
+                name = name.removesuffix("_invariant")
                 res.counts[name] += mult
                 if in_cond:
                     res.in_cond.append(name)
@@ -766,6 +799,24 @@ def _direction_fn(fn, direction: str):
     raise ValueError(f"unknown direction {direction!r}")
 
 
+def _compiled_hlo(fn, args) -> str:
+    """Optimized-HLO text of ``fn(*args)``, compiled with XLA's
+    common-subexpression pass off.  Every collective of one program now
+    carries the same ``channel_id``, so that pass folds a rotation the
+    backward asks for again into the forward's identical one (the ring's
+    kv counter-rotation: ``ring`` permutes left of ``2 * ring - 1``) — a
+    saving one XLA version makes, not a change in what the program asks
+    for, which is what the contracts hold.  Dead-code elimination and
+    every other pass still run, so the formulas stay the ones of a
+    compiled program (``ring - 1`` after XLA drops the unused final
+    rotate)."""
+    from ..utils import compat
+
+    return compat.jit(fn).lower(*args).compile(
+        compiler_options={"xla_disable_hlo_passes": "cse"}
+    ).as_text()
+
+
 def verify_hlo(strategy: str, direction: str, txt: str,
                dims: dict[str, int], mesh_shape: tuple[int, ...],
                axis_names: list[str]) -> list[str]:
@@ -867,8 +918,6 @@ def check_strategy(strategy: str, mesh=None, *, directions=None,
     """
     import jax
 
-    from ..utils import compat
-
     contract = CONTRACTS[strategy]
     if mesh is None:
         mesh = default_mesh(strategy)
@@ -885,7 +934,7 @@ def check_strategy(strategy: str, mesh=None, *, directions=None,
             strategy=strategy, direction=direction, impl=contract["impl"],
             mesh_shape=mesh_shape, dims=dims,
         )
-        txt = compat.jit(dfn).lower(*args).compile().as_text()
+        txt = _compiled_hlo(dfn, args)
         report.counts = hlo_collective_counts(txt)
         report.expected = expected_counts(strategy, direction, dims)
         report.violations.extend(verify_hlo(
@@ -1120,7 +1169,6 @@ def check_hybrid_hop_reduction(world: int | None = None, ulysses: int = 2,
     import jax
 
     from ..parallel.mesh import create_mesh
-    from ..utils import compat
 
     if world is None:
         world = len(jax.devices())
@@ -1130,10 +1178,10 @@ def check_hybrid_hop_reduction(world: int | None = None, ulysses: int = 2,
     hfn, hargs, hdims = build_entry("hybrid", hmesh, **shape_kw)
     rfn, rargs, rdims = build_entry("ring", rmesh, **shape_kw)
     hops_h = hlo_collective_counts(
-        compat.jit(hfn).lower(*hargs).compile().as_text()
+        _compiled_hlo(hfn, hargs)
     ).get("collective-permute", 0)
     hops_r = hlo_collective_counts(
-        compat.jit(rfn).lower(*rargs).compile().as_text()
+        _compiled_hlo(rfn, rargs)
     ).get("collective-permute", 0)
 
     report = ContractReport(
@@ -1170,17 +1218,12 @@ def check_counter_collective_budget(**shape_kw) -> ContractReport:
     (fwd alone pays one extra for the out/lse catch-up, ``ring`` vs
     ``ring - 1``; the backward's resident-KV schedule repays it with
     ``ring`` vs ``2 * ring - 1``)."""
-    import jax
-
-    from ..utils import compat
-
     mesh = default_mesh("ring")
     ring = _mesh_dims(mesh)["ring"]
 
     def permutes(strategy, direction):
         fn, args, _ = build_entry(strategy, mesh, **shape_kw)
-        dfn = _direction_fn(fn, direction)
-        txt = compat.jit(dfn).lower(*args).compile().as_text()
+        txt = _compiled_hlo(_direction_fn(fn, direction), args)
         return hlo_collective_counts(txt).get("collective-permute", 0)
 
     base_fwd = permutes("ring", "fwd")
@@ -1300,7 +1343,6 @@ def check_dcn_isolation(
     import jax
 
     from ..parallel.mesh import DCN_DATA_AXIS, create_mesh
-    from ..utils import compat
 
     n = len(jax.devices())
     if n % dcn or n // dcn < 2:
@@ -1331,7 +1373,7 @@ def check_dcn_isolation(
                 impl=CONTRACTS[strategy]["impl"], mesh_shape=mesh_shape,
                 dims=dims,
             )
-            txt = compat.jit(dfn).lower(*args).compile().as_text()
+            txt = _compiled_hlo(dfn, args)
             report.counts = hlo_collective_counts(txt)
             report.expected = expected_counts(strategy, direction, dims)
             # the ordinary contract (exact counts, axis discipline, no
@@ -1361,12 +1403,10 @@ def check_dcn_isolation(
 
 
 def dcn_collective_fingerprint(*, dcn: int = 2, ulysses: int = 2) -> dict:
-    """The multihost-dryrun comms signature for the bench JSON (phase
-    0e): per-row forward collective counts over the hierarchical
-    ``(dcn_data, ...)`` mesh, plus the machine-checked verdict that no
-    sequence-parallel collective crossed the dcn axis.  CPU-runnable —
-    it lands even on wedged-TPU rounds, and ``analysis/perfgate.py``
-    gates it exactly like the flat-mesh fingerprint."""
+    """The multihost-dryrun comms signature: per-row forward collective
+    counts over the hierarchical ``(dcn_data, ...)`` mesh, plus the
+    machine-checked verdict that no sequence-parallel collective crossed
+    the dcn axis.  CPU-runnable; ``tests/test_analysis.py`` pins it."""
     out: dict[str, Any] = {}
     ok = True
     for report in check_dcn_isolation(
@@ -1391,8 +1431,7 @@ def dims_str(dims: dict[str, int]) -> str:
 def run_contract_suite(strategies=None, *, scan: bool = True,
                        **shape_kw) -> list[ContractReport]:
     """Every strategy's contract on its canonical CPU mesh, plus the
-    hybrid-vs-ring hop-reduction relation.  The CLI and the bench
-    fingerprint both run exactly this."""
+    hybrid-vs-ring hop-reduction relation.  The CLI runs exactly this."""
     if strategies is None or strategies == "all":
         strategies = list(CONTRACTS)
     reports: list[ContractReport] = []
@@ -1418,13 +1457,11 @@ def collective_fingerprint(
     strategies=("ring", "ulysses", "hybrid", "counter", "ring_compressed",
                 "counter_q8", "blockwise_ffn"),
 ) -> dict:
-    """Compact comms signature for the bench JSON: per-strategy forward
-    collective counts from compiled HLO, so a perf trajectory catches a
-    hop-count or accidental-gather regression even when tokens/sec moves
-    for other reasons.  The counter-rotation and int8-compressed ring
-    variants ride along so a comms regression in either shows up on a
-    wedged-TPU round too (the CPU fingerprint is the primary signal,
-    ROADMAP item 5)."""
+    """Compact comms signature (``__graft_entry__.dryrun_multichip``
+    prints it): per-strategy forward collective counts from compiled HLO,
+    so a hop-count or accidental-gather regression shows in the dry run's
+    last line.  The counter-rotation and int8-compressed ring variants
+    ride along."""
     out: dict[str, Any] = {}
     ok = True
     for strategy in strategies:

@@ -36,10 +36,9 @@ and holds the system under test to it at three levels:
   dropped between hops, nothing double-counted into the softmax).
 
 All pure numpy + trace-time helpers — CPU, no devices, no compiles.
-CLI: ``tools/check_contracts.py --coverage``; the per-row tile counts
-ride bench phase 0 as ``coverage_fingerprint`` and gate in
-``analysis/perfgate.py`` (a mask change that visits dead tiles fails
-like a contract violation).
+CLI: ``tools/check_contracts.py --coverage``; ``coverage_fingerprint``
+gives the per-row tile counts (a mask change that visits dead tiles fails
+``tests/test_coverage.py`` like a contract violation).
 """
 
 from __future__ import annotations
@@ -999,10 +998,9 @@ def run_coverage_suite() -> list[CoverageReport]:
 
 
 def coverage_fingerprint() -> dict:
-    """Exact per-row tile accounting for bench phase 0 and the perf
-    gate: a future mask/hint change that grows (dead tiles visited) or
-    shrinks (live tiles at risk) any row's table fails the gate next to
-    the PR-5 collective fingerprint."""
+    """Exact per-row tile accounting: a future mask/hint change that
+    grows (dead tiles visited) or shrinks (live tiles at risk) any row's
+    table moves it, and the row's own proof fails."""
     fp: dict = {}
     ok = True
     for report in run_coverage_suite():

@@ -575,7 +575,7 @@ def lint_file(path: str | Path, root: str | Path | None = None) -> list[Violatio
 
 def lint_package(root: str | Path | None = None) -> list[Violation]:
     """Lint every module under ``ring_attention_tpu/`` (the library scope:
-    tools/, examples/, bench.py and tests/ are host-side and exempt)."""
+    tools/, examples/, benchmarks/ and tests/ are host-side and exempt)."""
     if root is None:
         root = Path(__file__).resolve().parents[2]
     root = Path(root)

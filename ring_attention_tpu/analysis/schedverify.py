@@ -728,7 +728,7 @@ def run_schedverify_suite(*, feeds=(False, True)) -> list[tuple[str, list]]:
 
 
 def protocol_fingerprint() -> dict:
-    """The exact-gated perfgate family: derived primitive counts, table
+    """The protocol's signature: derived primitive counts, table
     size, per-ring model event counts, total violations (0 on a healthy
     tree), and per-feed extracted-op counts.  Deterministic — any edit
     to the kernel's hop schedule or the PROTOCOL table moves it."""

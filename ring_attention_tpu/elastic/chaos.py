@@ -20,9 +20,8 @@ devices (``tests/test_elastic.py``):
   need no RPC into the victim.
 - :func:`delay_tap` / :func:`hang` — injected delay: a ``pure_callback``
   sleep gate spliced into a jitted step simulates a hung collective /
-  wedged device (the 5/5-round BENCH wedge) from inside the compiled
-  program, so ``with_retries`` timeout ladders and the bench probe's
-  hard deadline are exercisable without hardware.
+  wedged device from inside the compiled program, so ``with_retries``
+  timeout ladders are exercisable without hardware.
 - :func:`corrupt_file` — truncation/garbage corruption of a shard file,
   for the corrupted-checkpoint fallback matrix.
 

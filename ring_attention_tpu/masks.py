@@ -46,8 +46,8 @@ the kernels support today.
 Elementwise certificates are enumerated up to ``CERT_ELEMENTWISE_MAX``
 total positions per side; larger calls are proven on the leading
 ``CERT_ELEMENTWISE_MAX`` positions plus the closed-form-vs-enumeration
-tile accounting at the full shape (the CPU-countable half that
-``bench.py``'s ``window262k`` phase reports at 262144).  Pure numpy at
+tile accounting at the full shape (CPU-countable even at 262144,
+``tests/test_masks.py``'s ``window_262k`` test).  Pure numpy at
 module level; jax/kernel imports stay inside functions.
 
 See ``docs/masks.md`` for the lowering table per strategy and the
@@ -1183,7 +1183,7 @@ def certify(mask: Mask, spec: GridSpec, *, use_cache: bool = True,
         tiles_k += report.tiles_kmajor
     if pspec is not spec:
         # full-shape tile accounting: closed form vs enumeration on the
-        # real grid (CPU-countable even at 262k — bench window262k)
+        # real grid (CPU-countable even at 262k)
         try:
             full_low = lower(mask, spec)
             for hop in full_low.hops:

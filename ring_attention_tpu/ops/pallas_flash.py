@@ -161,7 +161,7 @@ def _exp2_default() -> bool:
     / ``pallas_flash_fused`` / ``pallas_flash_backward``), which both
     bypasses the env var and keys the jit cache correctly; the env var
     remains the right knob for per-process A/B (``env RING_ATTN_EXP2=1
-    python bench.py ...``).  The
+    python benchmarks/run.py ...``).  The
     attention custom_vjp resolves the flag ONCE per call in
     ``pallas_flash_attention``, so its forward and backward can never
     disagree on the basis.

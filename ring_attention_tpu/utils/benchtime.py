@@ -11,13 +11,9 @@ fetch on the chip it runs on; its output records what that chip does.
 from __future__ import annotations
 
 import os
-import time
 from typing import Callable, Sequence
 
-try:
-    from .tracing import perf_counter as _perf_counter
-except ImportError:  # standalone file-path load (bench parent)
-    _perf_counter = time.perf_counter
+from .tracing import perf_counter as _perf_counter
 
 __all__ = ["enable_compile_cache", "fetch_rtt", "timed_chained"]
 

@@ -11,10 +11,9 @@ per-hop/per-stage timeline keyed on the stack's stable scope names
 *measured* compute/transfer overlap fraction to sit next to the analytic
 one from ``telemetry.ring_comms_accounting`` (docs/observability.md §5.1).
 
-Stdlib-only at module level (jax is imported inside functions), so
-``tools/trace_report.py`` can load it by file path.  Events are read with
-``jax.profiler.ProfileData``; the scope paths, which it does not expose,
-with a ~100-line protobuf wire-format reader.
+Stdlib-only at module level (jax is imported inside functions).  Events
+are read with ``jax.profiler.ProfileData``; the scope paths, which it
+does not expose, with a ~100-line protobuf wire-format reader.
 """
 
 from __future__ import annotations
@@ -26,15 +25,11 @@ import gzip
 import os
 import re
 import statistics
-import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Any, Iterator, NamedTuple
 
-try:
-    from .tracing import perf_counter as _perf_counter
-except ImportError:  # standalone file-path load (tools/trace_report.py)
-    _perf_counter = time.perf_counter
+from .tracing import perf_counter as _perf_counter
 
 
 @contextlib.contextmanager
@@ -47,15 +42,9 @@ def trace(logdir: str):
     >>> with trace("/tmp/profile"):
     ...     step(...)  # traced region
     """
-    try:
-        from . import compat
+    from . import compat
 
-        cm = compat.profiler_trace(logdir)
-    except ImportError:  # standalone file-path load (tools/)
-        import jax
-
-        cm = jax.profiler.trace(logdir)
-    with cm:
+    with compat.profiler_trace(logdir):
         yield
 
 

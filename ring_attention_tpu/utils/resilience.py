@@ -35,16 +35,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator, Sequence
 
-try:
-    from . import tracing as _tracing
-except ImportError:  # standalone file-path load (tools, bench parent)
-    _tracing = None
-
-
-def _tracer():
-    """The active span tracer, or None on a standalone file-path load
-    where the relative import (and hence span emission) is unavailable."""
-    return _tracing.get_tracer() if _tracing is not None else None
+from . import tracing
 
 
 # ----------------------------------------------------------------------
@@ -375,7 +366,7 @@ class DirectoryLock:
         0 = one nonblocking attempt).  Returns True when held; raises
         :class:`LockTimeout` when the budget runs out.  NOT re-entrant:
         a thread that already holds the lock must not re-acquire it."""
-        tracer = _tracer()
+        tracer = tracing.get_tracer()
         if tracer is None or not tracer.enabled:
             return self._acquire(timeout)
         # the lock-wait span IS the straggler signal: a process stuck
@@ -484,8 +475,7 @@ class DegradationRecord:
         """Register ``callback(component, reason)`` to run on every
         recorded degradation (idempotent per callback).  This is how the
         telemetry layer turns silent ``impl="auto"`` fallbacks into metric
-        rows without this module importing it (this file must stay
-        stdlib-only and loadable standalone — see bench.py)."""
+        rows without this module importing it."""
         with self._lock:
             if callback not in self._listeners:
                 self._listeners.append(callback)

@@ -31,8 +31,7 @@ communication accounting, in four pieces:
   run it on a probe batch every N steps, never inside the hot step.
 
 Like ``resilience.py``, this module is stdlib-only at module level (jax is
-imported inside functions), so ``bench.py``'s parent process can load it by
-file path before the subprocess-isolated device probe.
+imported inside functions).
 """
 
 from __future__ import annotations
@@ -46,21 +45,8 @@ import threading
 import time
 from typing import Any, Iterator, NamedTuple
 
-try:
-    from .tracing import monotonic_wall as _monotonic_wall
-except ImportError:  # standalone file-path load (tools, bench parent)
-    def _monotonic_wall() -> tuple[float, float]:
-        return time.monotonic(), time.time()  # ra: allow(RA014 the standalone-load fallback IS the seam's mirror)
-
-
-def _active_tracer():
-    """The process-global span tracer, or None on a standalone file-path
-    load (tools) where the relative import is unavailable."""
-    try:
-        from . import tracing
-    except ImportError:
-        return None
-    return tracing.get_tracer()
+from . import resilience, tracing
+from .tracing import monotonic_wall as _monotonic_wall
 
 # JSONL row schema version.  Bump when a field is renamed or its meaning
 # changes; adding fields is backward compatible and needs no bump.
@@ -73,7 +59,7 @@ SCHEMA_VERSION = 1
 # reports.  A device that is not listed is an error, never a default: add
 # a row when a new chip is seen, with the string it reports.  Source:
 # Google Cloud "TPU v5e" documentation; the key is what one v5e chip
-# reported on 2026-07-29 (BENCH_r02.json) and again in chip_smoke.py.
+# reported on 2026-07-29 and again in chip_smoke.py.
 PEAK_TFLOPS = {
     "TPU v5 lite": 197.0,
 }
@@ -90,7 +76,7 @@ ICI_GBPS = {
 # happens to run on — the model's output never depends on where it runs
 MODEL_DEVICE_KIND = "TPU v5 lite"
 
-# attention matmul counts (shared with bench.py): 2 matmuls forward
+# attention matmul counts: 2 matmuls forward
 # (q@k^T, p@v); backward recomputes scores and adds 4 grad matmuls
 # (dv, dp, dq, dk) => fwd+bwd is 7
 FWD_MATMULS = 2
@@ -254,37 +240,12 @@ telemetry = Telemetry()
 def _on_degradation(component: str, reason: str) -> None:
     """Listener wired onto ``resilience.degradation``: every kernel
     fallback lands as a telemetry event, so a run that silently lost its
-    fast kernels shows up in the metrics stream and bench JSON — not just
-    as a one-shot warning scrolled out of the log."""
+    fast kernels shows up in the metrics stream — not just as a one-shot
+    warning scrolled out of the log."""
     telemetry.event("degraded", component=component, reason=reason)
 
 
-def _wire_degradation() -> None:
-    try:
-        from . import resilience
-    except ImportError:  # standalone file-path load (bench.py parent)
-        return
-    resilience.degradation.add_listener(_on_degradation)
-
-
-_wire_degradation()
-
-
-def degradation_fields() -> dict[str, Any]:
-    """Summary fields for result JSON (bench workers): ``{}`` when nothing
-    degraded, else ``degraded=1`` plus the components and last reason."""
-    try:
-        from . import resilience
-    except ImportError:
-        return {}
-    events = resilience.degradation.events()
-    if not events:
-        return {}
-    return {
-        "degraded": 1,
-        "degraded_components": sorted({e.component for e in events}),
-        "degraded_reason": events[-1].reason,
-    }
+resilience.degradation.add_listener(_on_degradation)
 
 
 # ----------------------------------------------------------------------
@@ -579,7 +540,7 @@ class FlightRecorder:
         dump) or this trigger kind already hit ``max_dumps_per_trigger``
         (``suppressed`` counts what was withheld)."""
         mono, wall = _monotonic_wall()
-        tracer = _active_tracer()
+        tracer = tracing.get_tracer()
         spans = tracer.last_spans(self.span_window) if tracer else []
         with self._lock:
             count = self._per_trigger.get(trigger, 0)
@@ -658,10 +619,6 @@ class FlightRecorder:
         process-global — call :meth:`uninstall` when the recorder's run
         ends before the process does (tests, config sweeps), or dead
         recorders keep dumping into stale directories forever."""
-        try:
-            from . import resilience
-        except ImportError:  # standalone file-path load
-            return self
         resilience.degradation.add_listener(self._on_degraded)
         resilience.add_failure_listener(self._on_retry_exhausted)
         return self
@@ -669,10 +626,6 @@ class FlightRecorder:
     def uninstall(self) -> "FlightRecorder":
         """Detach the :meth:`install` listeners (no-op if never
         installed)."""
-        try:
-            from . import resilience
-        except ImportError:
-            return self
         resilience.degradation.remove_listener(self._on_degraded)
         resilience.remove_failure_listener(self._on_retry_exhausted)
         return self
@@ -749,7 +702,7 @@ def flash_attention_flops(
     Two matmuls forward (``q@k^T`` and ``p@v``, each
     ``2 * seq_q * seq_k * dim_head`` MACs-as-FLOPs per head); backward
     recomputes scores and adds the 4 gradient matmuls (dv, dp, dq, dk) —
-    7 matmuls total, bench.py's ``FWDBWD_MATMULS``.  ``causal`` halves the
+    7 matmuls total, ``FWDBWD_MATMULS``.  ``causal`` halves the
     work (only the lower triangle is computed).  Softmax/normalization
     vector work is excluded by convention — MFU counts MXU work.
     """
@@ -819,8 +772,8 @@ def compiled_memory(compiled: Any) -> dict[str, int]:
     shrink), as ``{"temp_bytes", "argument_bytes", "output_bytes",
     "alias_bytes"(+host_* when a host memory space is in play)}``.  Empty
     when the backend offers no analysis — never raises.  Works on the CPU
-    backend too, which is what lets bench.py's ``train1m`` phase prove the
-    chunked-FFN memory claim on a wedged-TPU round (docs/memory.md)."""
+    backend too, which is what lets the tests prove the chunked-FFN memory
+    claim as a relation between two compiled programs (docs/memory.md)."""
     try:
         ma = compiled.memory_analysis()
         if ma is None:
@@ -870,10 +823,9 @@ def train_memory_estimate(
     """Analytic per-chip peak-HBM model of one rematted train step.
 
     The measured truth is ``compiled_memory()`` of the actual executable;
-    this formula exists so bench.py can print an estimate for shapes it
-    did not compile (the 1M-token target on a wedged-TPU round) and so a
-    config can be sanity-checked against a chip's HBM before burning a
-    hardware window.  Terms (per chip, sequence split ``seq_shards``-ways):
+    this formula exists so a config can be sanity-checked against a
+    chip's HBM before spending chip time on it.  Terms (per chip,
+    sequence split ``seq_shards``-ways):
 
     - params: weights (model dtype) + Adam moments (2x f32) + f32 grads,
       moments dropped from HBM when ``offload_opt_state``, divided

@@ -35,8 +35,8 @@ seconds before the exit-114*.  This module adds the missing layer:
   1 µs lower edge, x sqrt(2) per bucket) so per-token decode latencies
   recorded on different processes **merge associatively** by elementwise
   add; percentiles are deterministic integers (a bucket upper edge in
-  ns), which is what lets ``analysis/perfgate.py`` pin them as an exact
-  gate family.
+  ns), which is what lets ``tests/test_tracing.py`` pin the geometry and
+  a fixed sample's read-off as exact values.
 
 Stdlib-only at module level (like telemetry/resilience): tools load this
 file standalone by path, and nothing here may import jax.

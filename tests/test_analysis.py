@@ -102,10 +102,14 @@ def test_counter_contract_catches_missing_direction(devices):
     assert any("both-directions" in v for v in violations), violations
 
 
-@pytest.mark.parametrize("strategy", ["striped", "ulysses_gqa", "tree_decode"])
+@pytest.mark.parametrize(
+    "strategy", ["striped", "ulysses_gqa", "tree_decode", "counter_q8"]
+)
 def test_contract_fwd_only(devices, strategy):
     """Single-direction strategies (striped shares the ring's backward
-    formula — its forward already pins the permutation-vs-count claim)."""
+    formula — its forward already pins the permutation-vs-count claim;
+    counter_q8 shares counter_compressed's schedule and has no scan
+    table)."""
     _assert_ok(contracts.check_strategy(strategy, directions=("fwd",)))
 
 
@@ -748,8 +752,8 @@ def test_accumulator_dtype_audit_clean():
 
 
 def test_collective_fingerprint_shape(devices):
-    """The bench-JSON fingerprint: per-strategy fwd collective counts,
-    cheap enough to ride along every bench round.  Since PR 18 the ring
+    """The fingerprint ``__graft_entry__.dryrun_multichip`` prints:
+    per-strategy fwd collective counts.  Since PR 18 the ring
     row brings the fused-ring rows with it: the in-kernel remote-DMA /
     semaphore counts from the lowered module, with ``ppermute: 0`` — the
     launch-free-hops pin — for plain and int8-fed variants."""
@@ -814,11 +818,10 @@ def test_dcn_isolation_negative_toy(devices):
 
 
 def test_dcn_collective_fingerprint_deterministic(devices):
-    """The bench phase-0e payload: per-row fwd collective counts over
-    the hierarchical mesh + the machine-checked verdict, deterministic
-    across calls (it rides the exact perf-gate family)."""
+    """Per-row fwd collective counts over the hierarchical mesh + the
+    machine-checked verdict, exact and deterministic across calls."""
     fp = contracts.dcn_collective_fingerprint()
     assert fp["dcn_ok"] is True
     assert fp["ring_dcn"] == {"ppermute": 3}
-    assert "hybrid_dcn" in fp
+    assert fp["hybrid_dcn"] == {"all_to_all": 4, "ppermute": 1}
     assert contracts.dcn_collective_fingerprint() == fp

@@ -155,7 +155,7 @@ def test_band_plan_compact_flag_tracks_smem_cap():
 
 
 # ----------------------------------------------------------------------
-# Fingerprint + gate wiring
+# Fingerprint
 # ----------------------------------------------------------------------
 
 
@@ -174,51 +174,6 @@ def test_coverage_fingerprint_deterministic_and_ok():
         | {c.name for c in coverage.MASK_CASES}
         | {c.name for c in coverage.FUSED_CASES}
     )
-
-
-def test_gate_catches_coverage_regression(tmp_path):
-    """A tile-count change (a future mask change visiting dead tiles)
-    fails the perf gate exactly like a collective-contract violation —
-    and the committed baseline carries the coverage family so the gate
-    actually compares it."""
-    import json
-
-    from ring_attention_tpu.analysis import perfgate
-
-    baseline_path = tmp_path / "perf_baseline.json"
-    current = {
-        "gate_schema": perfgate.GATE_SCHEMA_VERSION,
-        "jax": "0",
-        "coverage": coverage.coverage_fingerprint(),
-    }
-    perfgate.write_baseline(current, str(baseline_path))
-    report = perfgate.check_baseline(
-        current, json.loads(baseline_path.read_text())
-    )
-    assert report.ok and any(
-        s.startswith("coverage.") for s in report.checked
-    )
-    drifted = json.loads(json.dumps(current))
-    drifted["coverage"]["single/causal"]["tiles"] += 3
-    report = perfgate.check_baseline(
-        drifted, json.loads(baseline_path.read_text())
-    )
-    assert not report.ok
-    [finding] = report.findings
-    assert finding.series == "coverage.single/causal.tiles"
-    assert "\n" not in str(finding)
-
-
-def test_committed_baseline_has_coverage_family():
-    """docs/perf_baseline.json carries the coverage rows and the current
-    build matches them exactly (the compile-free gate subset)."""
-    import json
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    baseline = json.load(open(os.path.join(root, "docs",
-                                           "perf_baseline.json")))
-    assert baseline["signals"]["coverage"] == coverage.coverage_fingerprint()
 
 
 # ----------------------------------------------------------------------

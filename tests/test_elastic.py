@@ -13,7 +13,7 @@ real deaths (``tests/elastic_worker.py`` + ``elastic/chaos.py``).
 Plus the in-process halves: PreemptionGuard drain under a real SIGTERM
 and under the fault injector, async-save double buffering and error
 propagation, the ``on_step_end`` hook HLO pin, the wedge-simulation
-delay tap, and the hardened bench probe's kill path.
+delay tap under a ``with_retries`` deadline.
 """
 
 import json
@@ -556,7 +556,7 @@ def test_on_step_end_adds_zero_collectives(rng, devices):
 
 
 # ----------------------------------------------------------------------
-# Wedge simulation: injected delay + the hardened bench probe
+# Wedge simulation: injected delay
 # ----------------------------------------------------------------------
 
 

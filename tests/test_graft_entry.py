@@ -28,95 +28,3 @@ def test_dryrun_multichip_8():
     import __graft_entry__ as g
 
     g.dryrun_multichip(8)  # raises on any failure
-
-
-@pytest.fixture(scope="module")
-def bench_records():
-    """All three bench worker modes measured in ONE subprocess (a fresh
-    jax import per mode would triple the fixed cost on this 1-CPU image)."""
-    import json
-    import subprocess
-
-    bench_path = os.path.join(REPO_ROOT, "bench.py")
-    lines = [
-        "import json, sys, traceback",
-        "import jax; jax.config.update('jax_platforms', 'cpu')",
-    ]
-    # per-mode try/except so one mode's crash still reports the others
-    for mode, impl in (
-        ("fwd", "xla"), ("fwdbwd", "xla"), ("train", "xla"),
-        ("decode", "pallas"), ("hybrid", "pallas"),
-    ):
-        argv = ["bench.py", "--worker", impl, "1024", mode]
-        lines += [
-            "try:",
-            f"    sys.argv = {argv!r}",
-            f"    exec(open({bench_path!r}).read())",
-            "except Exception:",
-            f"    print(json.dumps({{'mode_error': {mode!r},"
-            " 'tb': traceback.format_exc()[-400:]}))",
-        ]
-    env = dict(
-        os.environ,
-        JAX_COMPILATION_CACHE_DIR=os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), ".jax_cache"
-        ),
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", "\n".join(lines)], capture_output=True,
-        text=True, timeout=1200, env=env,
-    )
-    assert proc.returncode == 0, proc.stderr[-500:]
-    recs = [
-        json.loads(ln) for ln in proc.stdout.strip().splitlines()
-        if ln.startswith("{")
-    ]
-    assert len(recs) == 5, proc.stdout[-500:]
-    return dict(zip(("fwd", "fwdbwd", "train", "decode", "hybrid"), recs))
-
-
-@pytest.mark.slow
-def test_bench_worker_contract(bench_records):
-    """bench.py --worker prints one parseable JSON measurement line, with
-    compile time recorded separately from step time."""
-    rec = bench_records["fwd"]
-    assert {"value", "vs_baseline", "seq_len", "impl", "compile_s"} <= set(rec)
-
-
-@pytest.mark.slow
-def test_bench_worker_fwdbwd(bench_records):
-    """Backward-included attention timing (the other half of the
-    north-star: BASELINE.md wants fwd AND training-relevant numbers)."""
-    rec = bench_records["fwdbwd"]
-    assert rec["value"] > 0 and rec["ms_per_step"] > 0
-
-
-@pytest.mark.slow
-def test_bench_worker_decode(bench_records):
-    """Million-token-decode mode (here at 1024): ms/token + effective
-    KV-read bandwidth via the decode kernel (interpret mode on CPU)."""
-    rec = bench_records["decode"]
-    assert rec["decode_ms_per_token"] > 0 and rec["decode_kv_gbps"] > 0
-    assert rec["decode_impl"] == "pallas"
-
-
-@pytest.mark.slow
-def test_bench_worker_hybrid(bench_records):
-    """Hybrid Ulysses x Ring hop-sequence mode: the hybrid262k entry's
-    worker must report the shortened hop chain next to tokens/sec."""
-    rec = bench_records["hybrid"]
-    assert rec["impl"] == "pallas-hybrid"
-    assert rec["ulysses"] == 2 and rec["ring"] == 2
-    assert rec["hops"] == 1 and rec["pure_ring_hops"] == 3
-    assert rec["tokens_per_sec"] > 0
-
-
-@pytest.mark.slow
-def test_bench_worker_train(bench_records):
-    """Train-step (fwd+bwd+adam) tokens/sec measurement."""
-    rec = bench_records["train"]
-    assert rec["tokens_per_sec"] > 0
-    assert rec["train_seq_len"] == 1024
-    import math
-
-    assert math.isfinite(rec["train_loss"])

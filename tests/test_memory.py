@@ -370,8 +370,7 @@ def test_donation_audit_on_chunked_step(tiny_step_case):
 def test_chunked_step_temp_bytes_below_dense(tiny_step_case):
     """The compiler's own accounting proves the memory claim: the chunked
     (FFN + CE) train program's peak scratch bytes sit strictly below the
-    dense program's at equal shape — the relation bench.py's train1m
-    phase reports at proof scale."""
+    dense program's at equal shape."""
     loss_fn, opt, params, opt_state, tokens = tiny_step_case
     dense_model = RingTransformer(
         num_tokens=VOCAB, dim=16, depth=1, heads=2, dim_head=8,
@@ -393,7 +392,7 @@ def test_chunked_step_temp_bytes_below_dense(tiny_step_case):
 def test_train_memory_estimate_tracks_knobs():
     """The analytic peak-HBM model: chunking shrinks the transient term,
     save_attn grows the saved term, offload drops the optimizer term —
-    and the 1M-token bench config fits a 16 GB chip."""
+    and a 1M-token config at dim 512 fits a 16 GB chip."""
     kw = dict(seq_len=1 << 20, dim=512, depth=2, heads=8, vocab=256,
               n_params=28_000_000, dtype_bytes=2)
     chunked = train_memory_estimate(
@@ -414,7 +413,7 @@ def test_train_memory_estimate_tracks_knobs():
 
 
 # ----------------------------------------------------------------------
-# Slow tier: CLI + bench worker + the full sweeps
+# Slow tier: CLI + the full sweeps
 # ----------------------------------------------------------------------
 
 
@@ -428,26 +427,6 @@ def test_check_contracts_memory_cli():
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "memory checks hold" in proc.stdout
     assert "FAIL" not in proc.stdout
-
-
-@pytest.mark.slow
-def test_bench_train1m_mem_worker():
-    """The bench train1m memory phase at a CI-sized proof shape: chunked
-    temp bytes strictly below dense, plus the analytic 1M estimate."""
-    import json
-
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--worker", "cpu", "0", "train1m_mem",
-         json.dumps({"proof_seq": 1024, "ff_chunk": 128,
-                     "loss_chunk": 128})],
-        capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-500:]
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert payload["chunked_below_dense"] is True, payload
-    assert payload["temp_bytes_chunked"] < payload["temp_bytes_dense"]
-    assert payload["peak_hbm_estimate_gb"] < payload[
-        "peak_hbm_dense_estimate_gb"]
 
 
 @pytest.mark.slow

@@ -1,6 +1,5 @@
-"""Perf observatory: measured-overlap profiler, benchmark-history
-regression gate, and numerics flight recorder (ISSUE 8 /
-docs/observability.md §Observatory).
+"""Perf observatory: measured-overlap profiler and numerics flight
+recorder (ISSUE 8 / docs/observability.md §Observatory).
 
 The contracts under test:
 
@@ -9,10 +8,6 @@ The contracts under test:
   the measured compute/transfer overlap fraction sits within tolerance
   of ``ring_comms_accounting``'s analytic one — and a disagreement is a
   reportable finding, not a silent number;
-- the perf gate passes on the repo's actual BENCH history + committed
-  baseline, and each injected regression (fingerprint drift, inflated
-  temp bytes, dropped hop, hardware slowdown) fails with a ONE-LINE
-  diagnostic naming the regressed series;
 - a NaN injected at step k dumps a flight recording carrying the
   preceding metric rows and the triggering event.
 """
@@ -26,7 +21,6 @@ import jax.numpy as jnp
 import optax
 import pytest
 
-from ring_attention_tpu.analysis import perfgate
 from ring_attention_tpu.utils import (
     FlightRecorder,
     init_train_metrics,
@@ -224,218 +218,6 @@ def test_trace_report_renders_capture(ring_capture, tmp_path):
     )
     assert proc.returncode == 0, proc.stderr
     assert "FINDING" in proc.stdout
-
-
-# ----------------------------------------------------------------------
-# Perf gate: the real history passes; injected regressions fail one-line
-# ----------------------------------------------------------------------
-
-
-def test_gate_passes_on_repo_history(devices):
-    """The acceptance run: current build vs the committed baseline +
-    BENCH_r*.json history, on CPU.  Cheap subset (ring fingerprint +
-    arithmetic comms table); the full set is tools/perf_gate.py."""
-    current = perfgate.collect_current(strategies=("ring",), compiled=False)
-    report = perfgate.run_gate(current, root=REPO)
-    assert report.ok, "\n".join(str(f) for f in report.findings)
-    assert report.checked, "gate checked nothing — vacuous pass"
-    assert any(s.startswith("comms.") for s in report.checked)
-    assert any(s == "fingerprint.ring.ppermute" for s in report.checked)
-
-
-def test_committed_baseline_schema():
-    """The baseline file the gate reads is committed and version-matched
-    — deleting it cannot green a regression (run_gate would only note its
-    absence; THIS pin is what fails)."""
-    path = os.path.join(REPO, "docs", "perf_baseline.json")
-    assert os.path.exists(path), "docs/perf_baseline.json missing"
-    with open(path) as f:
-        baseline = json.load(f)
-    assert baseline["gate_schema"] == perfgate.GATE_SCHEMA_VERSION
-    assert "comms" in baseline["signals"]
-    assert "fingerprint" in baseline["signals"]
-    assert "compiled" in baseline["signals"]
-
-
-def _baseline(**signals):
-    return {"gate_schema": perfgate.GATE_SCHEMA_VERSION,
-            "jax": jax.__version__, "signals": signals}
-
-
-def test_gate_toy_fingerprint_drift():
-    """An extra (or missing) collective in a strategy's compiled HLO
-    fails with one line naming the series."""
-    base = _baseline(fingerprint={"ring": {"ppermute": 7}})
-    current = {"jax": jax.__version__,
-               "fingerprint": {"ring": {"ppermute": 8}}}
-    report = perfgate.check_baseline(current, base)
-    assert len(report.findings) == 1
-    line = str(report.findings[0])
-    assert "fingerprint.ring.ppermute" in line
-    assert "7" in line and "8" in line
-    assert "\n" not in line
-
-
-def test_gate_toy_inflated_temp_bytes():
-    """Compiled peak-scratch growth beyond tolerance (the memory-axis
-    regression PR 7's knobs exist to prevent) fails one-line."""
-    base = _baseline(compiled={"temp_bytes": 50_000})
-    current = {"jax": jax.__version__,
-               "compiled": {"temp_bytes": 100_000}}
-    report = perfgate.check_baseline(current, base)
-    assert len(report.findings) == 1
-    line = str(report.findings[0])
-    assert "compiled.temp_bytes" in line and "tolerance" in line
-    assert "\n" not in line
-    # within tolerance: clean
-    ok = perfgate.check_baseline(
-        {"jax": jax.__version__, "compiled": {"temp_bytes": 52_000}}, base
-    )
-    assert ok.ok
-
-
-def test_gate_toy_dropped_hop():
-    """A hop vanishing from the analytic reference table (an attention
-    pass silently skipped — wrong results that bench FASTER) fails
-    one-line; exact families tolerate nothing in either direction."""
-    base = _baseline(comms={"ring8_262k": {"ring_hops": 7,
-                                           "hop_bytes": 67108864}})
-    current = {"jax": jax.__version__,
-               "comms": {"ring8_262k": {"ring_hops": 6,
-                                        "hop_bytes": 67108864}}}
-    report = perfgate.check_baseline(current, base)
-    assert len(report.findings) == 1
-    line = str(report.findings[0])
-    assert "comms.ring8_262k.ring_hops" in line
-    assert "7" in line and "6" in line
-    assert "\n" not in line
-
-
-def test_gate_toy_compiler_version_scoping():
-    """Compiled signals recorded under another jax version are noted and
-    skipped — a compiler upgrade is not a regression."""
-    base = {"gate_schema": perfgate.GATE_SCHEMA_VERSION, "jax": "9.9.9",
-            "signals": {"compiled": {"temp_bytes": 1}}}
-    report = perfgate.check_baseline(
-        {"jax": jax.__version__, "compiled": {"temp_bytes": 10**9}}, base
-    )
-    assert report.ok
-    assert any("not compared" in n for n in report.notes)
-
-
-def _round(number, payload):
-    return perfgate.BenchRound(number, f"BENCH_r{number:02d}.json", payload)
-
-
-def test_gate_toy_hardware_regression_and_wedge_honesty():
-    """tokens/sec drop beyond tolerance between two MEASURED rounds is a
-    finding; a wedged round in between contributes a note, never a pass
-    or a false failure."""
-    hist = perfgate.History(rounds=[
-        _round(1, {"value": 60.0, "tokens_per_sec": 26000}),
-        _round(2, {"value": 0.0, "error": "device probe hung"}),
-        _round(3, {"value": 61.0, "tokens_per_sec": 18000}),
-    ])
-    report = perfgate.check_history(hist)
-    series = [f.series for f in report.findings]
-    assert "hardware.tokens_per_sec" in series
-    line = str(next(f for f in report.findings
-                    if f.series == "hardware.tokens_per_sec"))
-    assert "26,000" in line and "18,000" in line and "\n" not in line
-    # fwd tflops moved +1.7%: no finding
-    assert "hardware.fwd_tflops" not in series
-    assert any("round 2" in n and "no hardware measurement" in n
-               for n in report.notes)
-
-
-def test_gate_toy_latency_direction():
-    """decode ms/token is lower-is-better: an INCREASE is the finding."""
-    hist = perfgate.History(rounds=[
-        _round(1, {"value": 60.0, "decode_ms_per_token": 1.0}),
-        _round(2, {"value": 60.0, "decode_ms_per_token": 1.5}),
-    ])
-    report = perfgate.check_history(hist)
-    assert [f.series for f in report.findings] == [
-        "hardware.decode_ms_per_token"
-    ]
-    # and the reverse (a speedup) is clean
-    hist2 = perfgate.History(rounds=[
-        _round(1, {"value": 60.0, "decode_ms_per_token": 1.5}),
-        _round(2, {"value": 60.0, "decode_ms_per_token": 1.0}),
-    ])
-    assert perfgate.check_history(hist2).ok
-
-
-def test_gate_acknowledged_drift_downgrades_to_note():
-    """The conscious-override escape for HISTORY drift: once the current
-    build matches a re-recorded baseline for the same series, archived
-    round-to-round drift demotes to a note — an intentional collective
-    change is not a permanent red gate.  Unacknowledged drift stays a
-    finding."""
-    hist_report = perfgate.GateReport(findings=[
-        perfgate.GateFinding("fingerprint.ring.ppermute", 7, 9,
-                             "drift r1 -> r2: 7 -> 9"),
-        perfgate.GateFinding("fingerprint.ulysses.all_to_all", 4, 6,
-                             "drift r1 -> r2: 4 -> 6"),
-    ])
-    base_report = perfgate.GateReport(
-        checked=["fingerprint.ring.ppermute"],  # passed vs baseline
-        findings=[],
-    )
-    perfgate._downgrade_acknowledged_drift(hist_report, base_report)
-    assert [f.series for f in hist_report.findings] == [
-        "fingerprint.ulysses.all_to_all"
-    ]
-    assert any("acknowledged" in n for n in hist_report.notes)
-
-
-def test_gate_toy_round_fingerprint_drift():
-    """Fingerprint drift BETWEEN bench rounds (both wedged — the CPU
-    signal lands regardless) is caught without any baseline."""
-    fp1 = {"ring": {"ppermute": 7}, "contract_ok": True}
-    fp2 = {"ring": {"ppermute": 9}, "contract_ok": True}
-    hist = perfgate.History(rounds=[
-        _round(1, {"value": 0.0, "error": "wedged",
-                   "collective_fingerprint": fp1}),
-        _round(2, {"value": 0.0, "error": "wedged",
-                   "collective_fingerprint": fp2}),
-    ])
-    report = perfgate.check_history(hist)
-    assert len(report.findings) == 1
-    assert report.findings[0].series == "fingerprint.ring.ppermute"
-
-
-def test_history_ingest(tmp_path):
-    """BENCH_r*.json (driver-wrapped or bare) + results.jsonl rows +
-    probe_failure rows all land in one History."""
-    (tmp_path / "docs" / "hwlogs").mkdir(parents=True)
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "parsed": {"value": 68.99, "tokens_per_sec": 26549},
-    }))
-    # tail-only wrapping (no parsed key) and a bare payload
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "n": 2, "tail": 'garbage\n{"value": 0.0, "error": "wedged"}\n',
-    }))
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps({
-        "value": 70.0, "metric": "x",
-    }))
-    (tmp_path / "BENCH_rBAD.json").write_text("{not json")
-    rows = [
-        {"step": "fwd262k", "date": "2026-07-29",
-         "result": {"value": 68.99}},
-        {"step": "probe_failure", "date": "2026-08-01",
-         "result": {"error": "hung"}},
-        {"step": "probe_failure", "date": "2026-08-02",
-         "result": {"error": "hung again"}},
-    ]
-    (tmp_path / "docs" / "hwlogs" / "results.jsonl").write_text(
-        "\n".join(json.dumps(r) for r in rows) + "\ntorn{"
-    )
-    hist = perfgate.load_history(str(tmp_path))
-    assert [r.number for r in hist.rounds] == [1, 2, 3]
-    assert [r.probe_ok for r in hist.rounds] == [True, False, True]
-    assert len(hist.probe_failures) == 2
-    assert hist.hwlog["fwd262k"]["result"]["value"] == 68.99
 
 
 # ----------------------------------------------------------------------

@@ -185,14 +185,20 @@ def test_derived_counts_match_lowered_module():
 
 
 def test_protocol_fingerprint_deterministic(devices):
-    """The perf gate pins this family exactly: two collections must be
-    identical, violations zero, and the derived counts embedded."""
+    """Two collections must be identical, violations zero, the derived
+    counts embedded, and the model's event count per ring size the
+    recorded one (an edit to the hop schedule or the PROTOCOL table
+    moves it)."""
     fp = sv.protocol_fingerprint()
     assert fp == sv.protocol_fingerprint()
     assert fp["violations"] == 0
     assert fp["rows"] == len(PROTOCOL)
     assert fp["counts"] == sv.derived_fused_counts()
     assert fp["plain_ops"] == fp["q8_ops"] == 34
+    assert fp["rings"] == {
+        "ring2": 38, "ring3": 102, "ring4": 196, "ring5": 320,
+        "ring6": 474, "ring7": 658, "ring8": 872,
+    }
 
 
 # ----------------------------------------------------------------------

@@ -575,7 +575,7 @@ def test_attention_logit_summaries_match_dense_oracle(rng):
 def test_steptimer_percentiles(monkeypatch):
     t = {"now": 0.0}
     monkeypatch.setattr(
-        "ring_attention_tpu.utils.profiling.time.perf_counter",
+        "ring_attention_tpu.utils.tracing.time.perf_counter",
         lambda: t["now"],
     )
     timer = StepTimer(tokens_per_step=10)
@@ -595,7 +595,7 @@ def test_steptimer_percentiles(monkeypatch):
 def test_steptimer_monotonic_guard(monkeypatch):
     t = {"now": 100.0}
     monkeypatch.setattr(
-        "ring_attention_tpu.utils.profiling.time.perf_counter",
+        "ring_attention_tpu.utils.tracing.time.perf_counter",
         lambda: t["now"],
     )
     timer = StepTimer(tokens_per_step=10)

@@ -16,9 +16,9 @@ Pinned here at three levels:
   killed pre-commit, whose merged timeline must name the victim, the
   armed fault window, and the survivor's barrier wait (slow tier);
 
-plus the :class:`LatencyHistogram` contracts the perfgate latency
-family leans on (merge associativity, deterministic integer
-percentiles, codec round-trip, cross-scale rejection) and the HLO pin
+plus the :class:`LatencyHistogram` contracts (pinned bucket geometry,
+merge associativity, deterministic integer percentiles, codec
+round-trip, cross-scale rejection) and the HLO pin
 that a CONFIGURED tracer adds zero collectives to the compiled train
 step (host-side spans only — PR-4 style).
 """
@@ -211,11 +211,25 @@ def test_incident_reconstruction_synthetic():
 
 
 # ----------------------------------------------------------------------
-# LatencyHistogram: the perfgate latency family's substrate
+# LatencyHistogram
 # ----------------------------------------------------------------------
 
 
 def test_histogram_percentiles_are_deterministic_bucket_edges():
+    # the bucket geometry is part of every recorded latency: a changed
+    # edge or rank rule silently re-scales p50/p95/p99 across runs, so
+    # the table and a fixed sample's read-off are pinned as exact values
+    assert tracing.HIST_SCALE == "ns-pow2half-64"
+    assert tracing.HIST_BUCKETS == len(tracing.BUCKET_BOUNDS_NS) == 64
+    assert sum(tracing.BUCKET_BOUNDS_NS) == 10368968293527
+    h, x = LatencyHistogram(), 1
+    for _ in range(1000):
+        x = (x * 48271) % 2147483647  # minstd LCG: portable, seedless
+        h.record_ns(1_000 + x % 50_000_000)  # 1 us .. 50 ms spread
+    assert [h.percentile_ns(q) for q in (50, 95, 99)] == [
+        32768000, 65536000, 65536000,
+    ]
+
     h = LatencyHistogram()
     for ms in (1, 1, 2, 4, 8, 100):
         h.record(ms / 1e3)
@@ -261,38 +275,6 @@ def test_histogram_codec_roundtrip_and_scale_rejection():
     assert back.percentile_ns(95) == h.percentile_ns(95)
     with pytest.raises(ValueError, match="scale"):
         LatencyHistogram.from_dict({"scale": "ns-linear-10", "counts": {}})
-
-
-def test_perfgate_latency_family_is_pinned_and_gated():
-    from ring_attention_tpu.analysis import perfgate
-
-    sig = perfgate.latency_reference_signals()
-    # deterministic: no clock, no rng state — two calls are identical
-    assert sig == perfgate.latency_reference_signals()
-    assert sig["hist_scale"] == tracing.HIST_SCALE
-    assert sig["hist_buckets"] == tracing.HIST_BUCKETS
-    assert sig["edge_checksum"] == sum(tracing.BUCKET_BOUNDS_NS)
-    current = {"latency": sig}
-    baseline = {"signals": {"latency": dict(sig)}}
-    report = perfgate.check_baseline(current, baseline)
-    assert not [f for f in report.findings
-                if f.series.startswith("latency.")]
-    # a changed bucket rule fails the gate in one line, never silently
-    baseline["signals"]["latency"]["p95_ns"] = sig["p95_ns"] * 2
-    report = perfgate.check_baseline(current, baseline)
-    bad = [f for f in report.findings if f.series == "latency.p95_ns"]
-    assert bad, report.findings
-    # an absent family is a NOTE (subset run), not a silent pass
-    report = perfgate.check_baseline({}, baseline)
-    assert any("latency" in n for n in report.notes)
-
-
-def test_decode_series_registered_direction_lower_is_better():
-    from ring_attention_tpu.analysis.perfgate import HARDWARE_SERIES
-
-    for name in ("decode_ms_p50", "decode_ms_p95"):
-        key, direction = HARDWARE_SERIES[name]
-        assert key == name and direction == -1
 
 
 # ----------------------------------------------------------------------

@@ -13,8 +13,7 @@ the analytic ``hop_overlap_fraction`` of the metrics rows when both exist
 A metrics directory (or JSONL file) written by ``MetricsLogger``
 (``docs/observability.md``) prints the run summary, the per-metric table
 (last / mean / p50 / p95) and the comms accounting; ``--diff OLD NEW``
-compares two runs.  The metrics tables are stdlib-only; a capture's
-events need ``jax.profiler.ProfileData``.  Usage::
+compares two runs.  Usage::
 
   python tools/trace_report.py --xprof benchmarks/.trace/sc2-3b.train-64k
   python tools/trace_report.py --xprof DIR --window bench/token,bench/fetch --per bench/token
@@ -25,15 +24,9 @@ events need ``jax.profiler.ProfileData``.  Usage::
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import os
 import sys
 from collections import defaultdict
-
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_PKG_UTILS = os.path.join(
-    os.path.dirname(_HERE), "ring_attention_tpu", "utils"
-)
 
 # metric columns the table summarizes, in display order (other numeric
 # fields are appended alphabetically)
@@ -73,30 +66,28 @@ ACCOUNTING = [
 ]
 
 
-def _load_module(name: str, filename: str):
-    """Load a utils module by file path so this tool never imports the
-    package (whose ``__init__`` pulls in jax/flax) — the same pattern as
-    ``bench.py``'s parent process; both modules are stdlib-only at module
-    level by design.  Memoized: one exec per module per run."""
-    if name in sys.modules:
-        return sys.modules[name]
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(_PKG_UTILS, filename)
-    )
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
+def _utils(name: str):
+    """``ring_attention_tpu.utils.<name>``, imported on first use (the
+    package pulls in jax; ``--help`` does not need it)."""
+    import importlib
+
+    try:  # prefer the installed package (pip install -e .)
+        import ring_attention_tpu  # noqa: F401
+    except ModuleNotFoundError:  # running from a source checkout, any cwd
+        sys.path.insert(
+            0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        )
+    return importlib.import_module(f"ring_attention_tpu.utils.{name}")
 
 
 def _read_rows(path: str) -> list[dict]:
     """The library's own reader (``telemetry.read_metrics`` — the one the
     killed-writer tests pin)."""
-    return _load_module("_report_telemetry", "telemetry.py").read_metrics(path)
+    return _utils("telemetry").read_metrics(path)
 
 
 def _profiling():
-    return _load_module("_report_profiling", "profiling.py")
+    return _utils("profiling")
 
 
 def _percentile(values: list[float], q: float) -> float:
