@@ -450,7 +450,7 @@ def _census_rows(n_dev):
             return close(got, want, "partials")
 
         rows += [(f"flash_fwd_tile/{vname}", fwd),
-                 (f"flash_bwd_dkv+flash_bwd_dq/{vname}", bwd),
+                 (f"flash_bwd_dkv_dq/{vname}", bwd),
                  (f"flash_partials_tile/{vname}", partials)]
 
     # decode kernels: one query token against a 2-block GQA 8/2 cache

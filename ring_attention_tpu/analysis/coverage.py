@@ -530,8 +530,9 @@ def prove_case(case: CoverageCase) -> CoverageReport:
             report.work += plan.work_tiles
             report.edge += plan.edge_tiles
             report.violations.extend(verify_plan(plan, instances, label))
-            # the backward dk/dv pass builds k-major tables from the same
-            # hint — same oracle, transposed accumulator lifecycle
+            # the one-pass backward walks k-major tables built from the
+            # same hint — same oracle, transposed accumulator lifecycle;
+            # every band tile once is dq's proof as well as dk/dv's
             plan_k = band_plan((n, nk), (case.block, case.block), hint,
                                windowed=windowed,
                                doc_starts=case.doc_starts,

@@ -17,12 +17,12 @@ runtime scalars in SMEM so one compiled kernel
 serves every ring position under SPMD (the reference compiles
 ``CAUSAL_MASK_DIAGONAL`` variants instead, ref ``triton_flash_attn.py:216-221``).
 
-The backward is two kernels without atomics — a dk/dv pass (grid over KV
-blocks, queries streamed) and a dq pass (grid over Q blocks, KV streamed) —
-where the reference's Triton backward needs sequence-parallel
-``atomic_add`` workarounds (ref ``triton_flash_attn.py:763-776``); TPU has
-no relaxed atomics, and the two-pass structure is also what keeps every
-matmul on the MXU with static layouts.
+The backward is one kernel without atomics — a k-major pass (grid over KV
+blocks, queries streamed) that makes the scores and dP once a tile and
+from them dk/dv (VMEM accumulators) and dq (read-add-written in HBM by
+hand-started copies) — where the reference's Triton backward needs
+sequence-parallel ``atomic_add`` workarounds (ref
+``triton_flash_attn.py:763-776``); TPU has no relaxed atomics.
 
 GQA: query heads are served by ``kv_head = q_head // g`` through BlockSpec
 index maps (no materialized repeat); dk/dv are emitted per query head and
@@ -36,7 +36,7 @@ KV double-buffered via in-kernel async remote DMA, the ``(acc, m, l)``
 carry living in VMEM scratch (local tier) or staged per tile through an
 HBM spill (remote tier).  ``impl="fused"`` on
 ``ring_flash_attention`` selects it; the backward retains this module's
-two-pass kernels.
+one-pass kernel.
 """
 
 from __future__ import annotations
@@ -91,15 +91,15 @@ DEFAULT_BLOCK_K = 1024
 # array extent must be a multiple of this on the last axis (and of 8 on
 # the second-to-last), so spans the kernels tile are padded to it.
 LANE = 128
-# Per-pass backward tile defaults, used when the caller pins neither the
-# shared block_q/block_k nor the per-pass overrides.  None = inherit
-# DEFAULT_BLOCK_Q/K; the on-chip `tools/tpu_kernel_validate.py --bwd-sweep`
-# results get pinned HERE (VERDICT r3 next #3) so every backward call
-# site (ring hops, zigzag, single-sweep custom_vjp) picks them up.
-DEFAULT_BLOCK_Q_DKV: int | None = None
-DEFAULT_BLOCK_K_DKV: int | None = None
-DEFAULT_BLOCK_Q_DQ: int | None = None
-DEFAULT_BLOCK_K_DQ: int | None = None
+# One-pass backward (_bwd_kernel).  _DQ_SLOTS: the (block_q, d) f32 dq
+# tiles a launch keeps in VMEM — the one being accumulated, the next
+# one's prefetch and the previous one's write-back (1.5 MB at the default
+# block and d = 128).  _TF_QFIRST: its own tile flag, beside the _TF_*
+# flags of the compact grids below — the first tile of the k-major walk
+# that touches its q block, whose dq tile starts from zeros instead of a
+# read.
+_DQ_SLOTS = 3
+_TF_QFIRST = 16
 
 
 def _unify_vma(*arrays):
@@ -582,7 +582,7 @@ def band_plan(
         cross-document tiles (``doc_aligned=True``); otherwise the plan
         mirrors the launch-time fallback — band-only tables,
         ``doc_aligned=False``, the document mask left to runtime ids.
-      outer_is_q: q-major iteration (fwd/dq passes) vs k-major (dk/dv).
+      outer_is_q: q-major iteration (forward) vs k-major (backward).
     """
     nq, nk = int(shape[0]), int(shape[1])
     bq, bk = _block_sizes(nq, nk, *(block_sizes or (None, None)))
@@ -631,8 +631,8 @@ def _band_tables(n_q_blocks, n_k_blocks, bq, bk, hint, windowed,
     """(t_q, t_k, flags) int32 tables enumerating active band tiles.
 
     Iteration order is outer-major so the inner dimension carries the
-    accumulator: q-major for the fwd/dq passes (carry = online softmax /
-    dq), k-major for the dk/dv pass.  Rows with no active tile get one
+    accumulator: q-major for the forward (carry = online softmax), k-major
+    for the backward (carry = dk/dv).  Rows with no active tile get one
     dummy entry (flags = FIRST|LAST, no WORK) so their zero-initialized
     output block is still written, matching the rectangular grid's
     behavior for fully-masked rows.
@@ -811,7 +811,7 @@ def _fwd_kernel(*refs, compact: bool, masked: bool, segmented: bool,
 def _softclamp(s, clamp, exp2):
     """Clamp a score tile in natural units: ``c * tanh(s_nat / c)``, with
     ``s`` (and the result) in log2 units when ``exp2`` — the one clamp
-    basis transform shared by the fwd tile and both bwd recomputes."""
+    basis transform shared by the fwd tile and the bwd recompute."""
     if exp2:
         return jnp.tanh(s * (LN2 / clamp)) * (clamp * LOG2E)
     return jnp.tanh(s / clamp) * clamp
@@ -819,7 +819,7 @@ def _softclamp(s, clamp, exp2):
 
 def _softclamp_grad_factor(s_clamped, clamp, exp2):
     """tanh' = 1 - (clamped_natural / c)^2 from the post-clamp scores
-    (log2-basis under ``exp2``); multiplies ds in both bwd passes."""
+    (log2-basis under ``exp2``); multiplies ds in the backward tile."""
     s_nat = s_clamped * LN2 if exp2 else s_clamped
     return 1.0 - (s_nat / clamp) ** 2
 
@@ -1702,41 +1702,99 @@ def finalize_partials(p: FlashPartials) -> tuple[jax.Array, jax.Array]:
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels
+# Backward kernel
 # ---------------------------------------------------------------------------
 
 
-def _bwd_parse_refs(refs, compact, masked, segmented, bq, bk):
-    """Shared ref/position parsing for both backward kernels.
+def _bwd_tables(n_q_blocks, n_k_blocks, bq, bk, hint, windowed, doc_starts):
+    """The k-major band tables (:func:`_band_tables`) as the one-pass
+    backward walks them: every entry that is the first to touch its q
+    block carries ``_TF_QFIRST``, and a q block no entry touches (rows
+    the band leaves empty) gets a flag-only entry at the end, so that its
+    dq tile is still written, as zeros."""
+    tq, tk, tf = (
+        t.tolist()
+        for t in _band_tables(n_q_blocks, n_k_blocks, bq, bk, hint, windowed,
+                              outer_is_q=False, doc_starts=doc_starts)
+    )
+    touched = set()
+    for i, qi in enumerate(tq):
+        if qi not in touched:
+            touched.add(qi)
+            tf[i] |= _TF_QFIRST
+    for qi in range(n_q_blocks):
+        if qi not in touched:
+            tq.append(qi)
+            tk.append(tk[-1])  # the resident KV block: nothing to fetch
+            tf.append(_TF_QFIRST)
+    return (np.asarray(tq, np.int32), np.asarray(tk, np.int32),  # ra: allow(RA009 trace-time static tile tables — python ints, never traced)
+            np.asarray(tf, np.int32))  # ra: allow(RA009 trace-time static tile tables — python ints, never traced)
 
-    Ref layout (pallas passes scalar-prefetch, inputs, outputs, scratch
-    positionally; the static flags say which are present):
+
+def _bwd_kernel(*refs, compact: bool, masked: bool, segmented: bool,
+                nq_blocks: int, **tile_kw):
+    """One-pass backward: the grid holds a KV block and streams the query
+    blocks of its band (rect grid ``(bh, ki, qi)``; compact grid
+    ``(bh, t)`` over the k-major tables of :func:`_bwd_tables`).  Each
+    step makes ``sT``/``pT``/``dpT`` once and from them all three
+    gradients (:func:`_bwd_tile`).
+
+    dk/dv accumulate in VMEM scratch over the consecutive steps of a KV
+    block, as a dk/dv pass would.  A q block's dq is revisited once per KV
+    block, *not* consecutively, so it lives in HBM (``dq_hbm``; its rows
+    padded to whole :data:`LANE` multiples, the only rows Mosaic lets a
+    hand-written copy slice) and every step read-add-writes its
+    ``(bq, d)`` tile through ``_DQ_SLOTS`` VMEM slots: the read of step
+    ``s + 1`` starts before the compute of step ``s``, the write of step
+    ``s`` is waited for two steps later.  The first step to touch a q
+    block zero-fills its slot instead of reading.  A step whose tile is
+    the one step ``s - 1`` or ``s - 2`` writes (e.g. one q block a KV
+    block) is *deferred*: not prefetched, it reads after waiting for that
+    write.  The pipeline drains at the end of each ``bh`` row, so rows
+    are independent ("parallel").
+
+    Ref layout:
       scalars: offs (+ tq/tk/tf tile tables when ``compact``)
       inputs:  q, do, lse, delta, k, v (+ kv mask when ``masked``)
                (+ q/kv segment ids when ``segmented``)
-      then kernel-specific outputs + scratch (the ``rest`` return).
-
-    Returns ``(offs_ref, tiles, kvm_ref, qseg_ref, kseg_ref, first, last,
-    row0, col0, tf, rest)`` where ``first``/``last`` bound the inner
-    (accumulator-carrying) dimension, ``tiles = (q, do, lse, delta, k,
-    v)`` refs, and ``tf`` is the compact grid's per-tile flag word (None
-    on rectangular grids, whose callers derive first/last/row0/col0 from
-    ``pl.program_id`` instead — those five slots come back as None here).
+      outputs: dq (HBM), dk, dv
+      scratch: dk, dv (bk, d) f32, dq slots (_DQ_SLOTS, bq, d) f32, read
+               and write DMA semaphores (_DQ_SLOTS,) each
     """
+    bq, bk = tile_kw["bq"], tile_kw["bk"]
+    tile_kw = dict(tile_kw, masked=masked, segmented=segmented)
     if compact:
         offs_ref, tq_ref, tk_ref, tf_ref = refs[:4]
         idx = 4
-        t = pl.program_id(1)
-        tf = tf_ref[t]
+        step = pl.program_id(1)
+        steps = pl.num_programs(1)
+        tf = tf_ref[step]
         first = (tf & _TF_FIRST) != 0
         last = (tf & _TF_LAST) != 0
-        row0, col0 = tq_ref[t] * bq, tk_ref[t] * bk
-        tf_or_none = tf
+        row0, col0 = tq_ref[step] * bq, tk_ref[step] * bk
+
+        def q_block(s):
+            return tq_ref[s]
+
+        def fresh(s):  # no earlier step wrote this step's dq tile
+            return (tf_ref[s] & _TF_QFIRST) != 0
     else:
         offs_ref = refs[0]
         idx = 1
-        first = last = row0 = col0 = tf_or_none = None  # caller fills in
-    tiles = refs[idx:idx + 6]
+        ki, qi = pl.program_id(1), pl.program_id(2)
+        first = qi == 0
+        last = qi == nq_blocks - 1
+        row0, col0 = qi * bq, ki * bk
+        step = ki * nq_blocks + qi
+        steps = pl.num_programs(1) * nq_blocks
+
+        def q_block(s):
+            return lax.rem(s, nq_blocks)
+
+        def fresh(s):
+            return s < nq_blocks
+
+    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref = refs[idx:idx + 6]
     idx += 6
     kvm_ref = refs[idx] if masked else None
     idx += 1 if masked else 0
@@ -1744,50 +1802,113 @@ def _bwd_parse_refs(refs, compact, masked, segmented, bq, bk):
     if segmented:
         qseg_ref, kseg_ref = refs[idx:idx + 2]
         idx += 2
-    return (offs_ref, tiles, kvm_ref, qseg_ref, kseg_ref, first, last,
-            row0, col0, tf_or_none, refs[idx:])
+    dq_hbm, dk_ref, dv_ref, dk, dv, dq_buf, read_sem, write_sem = refs[idx:]
 
+    bh = pl.program_id(0)
 
-def _bwd_dkv_kernel(*refs, compact: bool, masked: bool, segmented: bool,
-                    nq_blocks: int, **tile_kw):
-    """dk/dv pass: the grid holds a KV block and streams query blocks
-    (rect grid ``(bh, ki, qi)``; compact grid k-major tile tables)."""
-    bq, bk = tile_kw["bq"], tile_kw["bk"]
-    tile_kw = dict(tile_kw, masked=masked, segmented=segmented)
-    (offs_ref, tiles, kvm_ref, qseg_ref, kseg_ref, first, last, row0, col0,
-     tf, rest) = _bwd_parse_refs(refs, compact, masked, segmented, bq, bk)
-    if not compact:
-        ki, qi = pl.program_id(1), pl.program_id(2)
-        first = qi == 0
-        last = qi == nq_blocks - 1
-        row0, col0 = qi * bq, ki * bk
-    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref = tiles
-    dk_ref, dv_ref, dk, dv = rest
+    def dq_tile(s):
+        return dq_hbm.at[bh, pl.ds(pl.multiple_of(q_block(s) * bq, bq), bq)]
+
+    def read(s):
+        slot = lax.rem(s, _DQ_SLOTS)
+        return pltpu.make_async_copy(  # ra: allow(RA013 local HBM<->VMEM copy of this launch's own dq tile — no peer, no barrier, drained every bh row)
+            dq_tile(s), dq_buf.at[slot], read_sem.at[slot])
+
+    def write(s):
+        # a wait only needs the slot's semaphore and the tile's size, so
+        # waits on older steps address the current tile (no table reads
+        # at negative steps)
+        slot = lax.rem(s, _DQ_SLOTS)
+        return pltpu.make_async_copy(  # ra: allow(RA013 local HBM<->VMEM copy of this launch's own dq tile — no peer, no barrier, drained every bh row)
+            dq_buf.at[slot], dq_tile(step), write_sem.at[slot])
+
+    def deferred(s):
+        """Does step ``s`` read a tile that step ``s - 1`` or ``s - 2``
+        is still writing?"""
+        hit = False
+        for back in (1, 2):
+            prev = jnp.maximum(s - back, 0)
+            hit = hit | ((s >= back) & (q_block(s) == q_block(prev)))
+        return hit
+
+    nxt = jnp.minimum(step + 1, steps - 1)
+    def_prev, def_cur, def_next = (deferred(jnp.maximum(step - 1, 0)),
+                                   deferred(step), deferred(nxt))
+    fresh_cur = fresh(step)
+
+    @pl.when((step >= 2) & ~def_prev)
+    def _free_slot():  # the slot the next prefetch lands in
+        write(step - 2).wait()
+
+    @pl.when(def_cur)
+    def _read_after_write():
+        write(step - 1).wait()
+        read(step).start()
+
+    @pl.when((step + 1 < steps) & ~def_next & ~fresh(nxt))
+    def _prefetch():
+        read(nxt).start()
 
     @pl.when(first)
     def _init():
         dk[:] = jnp.zeros_like(dk)
         dv[:] = jnp.zeros_like(dv)
 
-    tile = _tile_closure(_dkv_tile, tile_kw, offs_ref, q_ref, do_ref, lse_ref,
+    slot = lax.rem(step, _DQ_SLOTS)
+
+    @pl.when(fresh_cur)
+    def _zero_fill():
+        dq_buf[slot] = jnp.zeros(dq_buf.shape[1:], dq_buf.dtype)
+
+    @pl.when(~fresh_cur)
+    def _await_read():
+        read(step).wait()
+
+    tile = _tile_closure(_bwd_tile, tile_kw, offs_ref, q_ref, do_ref, lse_ref,
                          delta_ref, k_ref, v_ref, kvm_ref, qseg_ref, kseg_ref,
-                         dk, dv, row0, col0)
+                         dk, dv, dq_buf.at[slot], row0, col0)
     if compact:
         _dispatch_tile_compact(tf, tile)
     else:
         _dispatch_tile(offs_ref, row0, col0, bq, bk, tile_kw["causal"],
                        tile_kw["windowed"], tile)
+    write(step).start()
 
     @pl.when(last)
     def _write():
         dk_ref[0] = dk[:]
         dv_ref[0] = dv[:]
 
+    @pl.when(step == steps - 1)
+    def _drain():
+        @pl.when((step >= 1) & ~def_cur)
+        def _():
+            write(step - 1).wait()
 
-def _dkv_tile(offs_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-              kvm_ref, qseg_ref, kseg_ref, dk, dv, row0, col0, *, scale,
+        write(step).wait()
+
+
+def _bwd_vmem_limit(bq, bk, d_pad, itemsize, softclamp):
+    """Scoped-VMEM request of the one-pass backward, or None while it
+    fits the 16 MiB every generation grants by default: the pipeline's
+    double-buffered blocks (the ``(bq, 1)`` columns are lane-padded), the
+    dk/dv accumulators and dq slots, and the score-sized tiles one step
+    keeps live (``sT``/``pT``, ``dpT``, ``dsT`` in f32, the two bf16
+    operands and the transposed one; the clamp keeps two more)."""
+    blocks = 2 * (2 * bq * d_pad * itemsize + 2 * bq * LANE * 4
+                  + 2 * bk * d_pad * itemsize + 2 * bk * d_pad * 4)
+    scratch = (2 * bk + _DQ_SLOTS * bq) * d_pad * 4
+    live = (8 if softclamp else 6) * bq * bk * 4
+    need = blocks + scratch + live
+    return need if need > 16 * 2**20 else None
+
+
+def _bwd_tile(offs_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+              kvm_ref, qseg_ref, kseg_ref, dk, dv, dq, row0, col0, *, scale,
               softclamp_value, causal, windowed, masked, segmented, bq, bk,
               exp2=False):
+    """Five products a tile, all k-major: ``sT``, ``dV += pT·dO``,
+    ``dpT``, ``dK += dsT·Q``, ``dQ += dsTᵀ·K``."""
     kb = k_ref[0]
     qb = q_ref[0]
     # sT: (bk, bq) = k . q^T (contract d on both)
@@ -1823,84 +1944,18 @@ def _dkv_tile(offs_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     dsT = pT * (dpT - jnp.swapaxes(delta_ref[0], 0, 1))
     if softclamp_value is not None:
         dsT = dsT * _softclamp_grad_factor(sT, softclamp_value, exp2)
-    if scale != 1.0:  # folded q̃ makes dsT·q̃ carry the factor exactly
-        dsT = dsT * scale
+    if scale != 1.0:  # folded q̃ makes dsT·q̃ carry the factor exactly,
+        dsT = dsT * scale  # and dq is post-scaled once on the output
+    dsT = dsT.astype(qb.dtype)
     dk[:] = dk[:] + lax.dot_general(
-        dsT.astype(qb.dtype), qb, (((1,), (0,)), ((), ())),
+        dsT, qb, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-
-
-def _bwd_dq_kernel(*refs, compact: bool, masked: bool, segmented: bool,
-                   nk_blocks: int, **tile_kw):
-    """dq pass: the grid holds a Q block and streams KV blocks
-    (rect grid ``(bh, qi, ki)``; compact grid q-major tile tables)."""
-    bq, bk = tile_kw["bq"], tile_kw["bk"]
-    tile_kw = dict(tile_kw, masked=masked, segmented=segmented)
-    (offs_ref, tiles, kvm_ref, qseg_ref, kseg_ref, first, last, row0, col0,
-     tf, rest) = _bwd_parse_refs(refs, compact, masked, segmented, bq, bk)
-    if not compact:
-        qi, ki = pl.program_id(1), pl.program_id(2)
-        first = ki == 0
-        last = ki == nk_blocks - 1
-        row0, col0 = qi * bq, ki * bk
-    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref = tiles
-    dq_ref, dq = rest
-
-    @pl.when(first)
-    def _init():
-        dq[:] = jnp.zeros_like(dq)
-
-    tile = _tile_closure(_dq_tile, tile_kw, offs_ref, q_ref, do_ref, lse_ref,
-                         delta_ref, k_ref, v_ref, kvm_ref, qseg_ref, kseg_ref,
-                         dq, row0, col0)
-    if compact:
-        _dispatch_tile_compact(tf, tile)
-    else:
-        _dispatch_tile(offs_ref, row0, col0, bq, bk, tile_kw["causal"],
-                       tile_kw["windowed"], tile)
-
-    @pl.when(last)
-    def _write():
-        dq_ref[0] = dq[:]
-
-
-def _dq_tile(offs_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-             kvm_ref, qseg_ref, kseg_ref, dq, row0, col0, *, scale,
-             softclamp_value, causal, windowed, masked, segmented, bq, bk,
-             exp2=False):
-    qb = q_ref[0]
-    kb = k_ref[0]
-    s = lax.dot_general(
-        qb, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if scale != 1.0:  # static: folded into q for power-of-two scales
-        s = s * scale
-    if softclamp_value is not None:
-        s = _softclamp(s, softclamp_value, exp2)
-
-    p = (jnp.exp2 if exp2 else jnp.exp)(s - lse_ref[0])
-    keep = _tile_keep(
-        offs_ref, row0, col0, (bq, bk), 0, causal, windowed,
-        kvm_ref if masked else None,
-        qseg_ref if segmented else None,
-        kseg_ref if segmented else None,
-    )
-    if keep is not None:
-        p = jnp.where(keep, p, 0.0)
-
-    dob = do_ref[0]
-    dp = lax.dot_general(
-        dob, v_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta_ref[0])
-    if softclamp_value is not None:
-        ds = ds * _softclamp_grad_factor(s, softclamp_value, exp2)
-    if scale != 1.0:  # folded q̃: dq is post-scaled once on the output
-        ds = ds * scale
-    dq[:] = dq[:] + lax.dot_general(
-        ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
+    # dq: (bq, d) = dsT^T . k (contract the key rows of both), into the
+    # first d lanes of its lane-padded slot
+    d = kb.shape[-1]
+    dq[:, :d] = dq[:, :d] + lax.dot_general(
+        dsT, kb, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -1920,10 +1975,6 @@ def pallas_flash_backward(
     softclamp_value: float | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
-    block_q_dkv: int | None = None,
-    block_k_dkv: int | None = None,
-    block_q_dq: int | None = None,
-    block_k_dq: int | None = None,
     band_hint: tuple[int, int, int, int] | None = None,
     interpret: bool | None = None,
     exp2: bool | None = None,
@@ -1931,25 +1982,24 @@ def pallas_flash_backward(
     doc_starts: tuple[int, ...] | None = None,
     compute_dtype: str | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Two-pass flash backward. Returns (dq, dk, dv), all f32, dk/dv with
-    ``hk`` heads (GQA group-summed).
+    """One-pass flash backward (kernel ``flash_bwd_dkv_dq``). Returns
+    (dq, dk, dv), all f32, dk/dv with ``hk`` heads (GQA group-summed).
 
-    The two passes stream in opposite directions (dk/dv holds KV and
-    streams queries; dq holds Q and streams KV), so their optimal tile
-    shapes differ; ``block_*_dkv`` / ``block_*_dq`` override the shared
-    ``block_q`` / ``block_k`` per pass.
+    One launch walks the band k-major and per tile makes the scores and
+    ``dP`` once: five matrix products and one ``exp`` sweep for dv, dk
+    and dq together (:func:`_bwd_kernel` says how dq, which no
+    consecutive run of steps owns, is accumulated in HBM).
 
     ``segment_ids``/``doc_starts`` mirror the forward (packed sequences):
-    cross-document terms drop out of ``p`` in both passes, and a
-    block-aligned declared layout drops cross-document tiles from each
-    pass's compact grid at trace time (checked against that pass's block
-    sizes independently).
+    cross-document terms drop out of ``p``, and a block-aligned declared
+    layout drops cross-document tiles from the compact grid at trace
+    time.
 
     ``compute_dtype`` is the knob SURFACE for the int8 backward; this
     round only ``None`` (bf16 matmuls) is implemented — the dk/dv/dq
     error budget does not yet admit int8 recompute (docs/precision.md §5),
-    so an int8-forward model differentiates through exact-residual bf16
-    backward passes."""
+    so an int8-forward model differentiates through an exact-residual
+    bf16 backward."""
     if compute_dtype is not None:
         raise NotImplementedError(
             f"pallas_flash_backward: compute_dtype={compute_dtype!r} — the "
@@ -1965,10 +2015,10 @@ def pallas_flash_backward(
     doc_starts = _check_doc_starts(doc_starts, nq, nk)
 
     # power-of-two scale folds into q here too (exact, see _flash_fwd_call):
-    # s/sT recompute unchanged, dk = dsT·q̃ absorbs the factor exactly
+    # sT recomputes unchanged, dk = dsT·q̃ absorbs the factor exactly
     # (dk = scale·dsTᵀ·q = dsTᵀ·(scale·q)), and dq comes out unscaled —
     # multiplied once on the (nq, d) output below instead of per (bq, bk)
-    # tile.  Deletes BOTH per-tile score-path multiplies from each pass.
+    # tile.  Deletes both per-tile score-path multiplies.
     # In exp2 mode (RING_ATTN_EXP2=1) the fold is scale*log2e and lse
     # converts to log2 units once out here, so the in-tile p recompute is
     # a bare exp2; dk then carries a surplus log2e absorbed by a ln2
@@ -1988,25 +2038,7 @@ def pallas_flash_backward(
         dq_post_scale = scale
         scale = 1.0
 
-    # per-call override > swept per-pass default > shared block_q/block_k
-    if block_q_dkv is None and block_q is None:
-        block_q_dkv = DEFAULT_BLOCK_Q_DKV
-    if block_k_dkv is None and block_k is None:
-        block_k_dkv = DEFAULT_BLOCK_K_DKV
-    if block_q_dq is None and block_q is None:
-        block_q_dq = DEFAULT_BLOCK_Q_DQ
-    if block_k_dq is None and block_k is None:
-        block_k_dq = DEFAULT_BLOCK_K_DQ
-    bq1, bk1 = _block_sizes(
-        nq, nk,
-        block_q_dkv if block_q_dkv is not None else block_q,
-        block_k_dkv if block_k_dkv is not None else block_k,
-    )
-    bq2, bk2 = _block_sizes(
-        nq, nk,
-        block_q_dq if block_q_dq is not None else block_q,
-        block_k_dq if block_k_dq is not None else block_k,
-    )
+    bq, bk = _block_sizes(nq, nk, block_q, block_k)
     interpret = _interpret_default() if interpret is None else interpret
 
     causal = causal_offset is not None
@@ -2018,254 +2050,165 @@ def pallas_flash_backward(
 
     hint = _normalize_hint(causal, windowed, causal_offset, window_lo,
                            band_hint)
-    # each pass has its own grid/tables: the SMEM cap demotes them
-    # independently (per-pass block sizes can put one over, not the other),
-    # and the trace-time doc skip needs the layout aligned to that pass's
-    # own block sizes
-    compact_dkv = compact_dq = False
-    docs_dkv = docs_dq = None
-    dkv_tabs = dq_tabs = []
-    if hint is not None:
-        if doc_starts is not None:
-            if _docs_block_aligned(doc_starts, bq1, bk1):
-                docs_dkv = doc_starts
-            if _docs_block_aligned(doc_starts, bq2, bk2):
-                docs_dq = doc_starts
-        tiles_dkv = _band_tile_count(
-            nq // bq1, nk // bk1, bq1, bk1, hint, windowed, outer_is_q=False,
-            doc_starts=docs_dkv,
+    compact = hint is not None
+    # trace-time doc skip needs a compact grid AND a block-aligned layout
+    doc_tables = (
+        doc_starts
+        if compact and doc_starts is not None
+        and _docs_block_aligned(doc_starts, bq, bk)
+        else None
+    )
+    tabs = []
+    if compact:
+        tiles = _band_tile_count(
+            nq // bq, nk // bk, bq, bk, hint, windowed, outer_is_q=False,
+            doc_starts=doc_tables,
         )
-        tiles_dq = _band_tile_count(
-            nq // bq2, nk // bk2, bq2, bk2, hint, windowed, outer_is_q=True,
-            doc_starts=docs_dq,
-        )
-        compact_dkv = tiles_dkv <= _MAX_COMPACT_TILES
-        compact_dq = tiles_dq <= _MAX_COMPACT_TILES
-        if not compact_dkv:
-            _warn_demoted("bwd dk/dv", tiles_dkv, stacklevel=3)
-            docs_dkv = None
-        if not compact_dq:
-            _warn_demoted("bwd dq", tiles_dq, stacklevel=3)
-            docs_dq = None
-        if compact_dkv:
-            dkv_tabs = [
+        compact = tiles <= _MAX_COMPACT_TILES
+        if compact:
+            tabs = [
                 jnp.asarray(t)
-                for t in _band_tables(nq // bq1, nk // bk1, bq1, bk1, hint,
-                                      windowed, outer_is_q=False,
-                                      doc_starts=docs_dkv)
+                for t in _bwd_tables(nq // bq, nk // bk, bq, bk, hint,
+                                     windowed, doc_tables)
             ]
-        if compact_dq:
-            dq_tabs = [
-                jnp.asarray(t)
-                for t in _band_tables(nq // bq2, nk // bk2, bq2, bk2, hint,
-                                      windowed, outer_is_q=True,
-                                      doc_starts=docs_dq)
-            ]
-    # runtime segment refs are needed by any pass whose tables don't carry
-    # the document mask; a pass whose tables DO carry it skips the refs
-    if doc_starts is not None and q_seg is None and not (
-        docs_dkv is not None and docs_dq is not None
-    ):
+        else:
+            _warn_demoted("bwd", tiles, stacklevel=3)
+            doc_tables = None
+    if doc_tables is not None:
+        # the tables carry the whole document mask: ship no segment refs
+        q_seg = kv_seg = None
+    elif doc_starts is not None and q_seg is None:
+        # misaligned/demoted declared layout: realize it as runtime ids
         q_seg = kv_seg = _doc_runtime_ids(doc_starts, nq, b)
-    seg_dkv = q_seg is not None and docs_dkv is None
-    seg_dq = q_seg is not None and docs_dq is None
+    segmented = q_seg is not None
+
     unified = _unify_vma(
-        q, k, v, do, lse, delta, kv_mask, q_seg, kv_seg, offs,
-        *dkv_tabs, *dq_tabs
+        q, k, v, do, lse, delta, kv_mask, q_seg, kv_seg, offs, *tabs
     )
     q, k, v, do, lse, delta, kv_mask, q_seg, kv_seg, offs = unified[:10]
-    dkv_tabs = unified[10:10 + len(dkv_tabs)]
-    dq_tabs = unified[10 + len(dkv_tabs):]
-    if q_seg is not None:
-        q_seg = q_seg.astype(jnp.int32)
-        kv_seg = kv_seg.astype(jnp.int32)
-    qr = q.reshape(b * h, nq, d)
-    dor = do.reshape(b * h, nq, d).astype(q.dtype)
-    lser = lse.reshape(b * h, nq, 1)
-    deltar = delta.reshape(b * h, nq, 1)
-    kr = k.reshape(b * hk, nk, d)
-    vr = v.reshape(b * hk, nk, d)
+    tabs = unified[10:]
 
-    def q_map(bh, xi, yi, *_):
-        del yi
-        return (bh, xi, 0)
+    if compact:
+        q_map, kv_map, kvm_map, qsm_map, k_out_map = _compact_maps(h, hk, g)
+        scalars = (offs, *tabs)
+        grid = (b * h, tabs[0].shape[0])
+    else:
+        scalars = (offs,)
+        grid = (b * h, nk // bk, nq // bq)
 
-    def q_map_inner(bh, ki, qi, *_):
-        del ki
-        return (bh, qi, 0)
+        def q_map(bh, ki, qi, *_):
+            return (bh, qi, 0)
 
-    def kv_map_outer(bh, ki, qi, *_):
-        del qi
-        b_idx = bh // h
-        kvh = (bh % h) // g
-        return (b_idx * hk + kvh, ki, 0)
+        def kv_map(bh, ki, qi, *_):
+            return ((bh // h) * hk + (bh % h) // g, ki, 0)
 
-    def kv_map_inner(bh, qi, ki, *_):
-        b_idx = bh // h
-        kvh = (bh % h) // g
-        return (b_idx * hk + kvh, ki, 0)
+        def kvm_map(bh, ki, qi, *_):
+            return (bh // h, ki)
 
-    # masked/segmented ride the kernel partials per pass (the two passes
-    # can differ on segmented when only one pass's tables carry the docs)
-    common1 = dict(
+        def qsm_map(bh, ki, qi, *_):
+            return (bh // h, qi)
+
+        def k_out_map(bh, ki, qi, *_):
+            return (bh, ki, 0)
+
+    # a bh row owns its dq tiles and drains its copies, so rows are
+    # independent; within a row every step shares dk/dv or a dq tile
+    semantics = ("parallel",) + ("arbitrary",) * (len(grid) - 1)
+    kernel = functools.partial(
+        _bwd_kernel,
+        compact=compact,
+        masked=masked,
+        segmented=segmented,
+        nq_blocks=nq // bq,
         scale=scale,
         softclamp_value=softclamp_value,
         causal=causal,
         windowed=windowed,
-        bq=bq1,
-        bk=bk1,
+        bq=bq,
+        bk=bk,
         exp2=exp2,
-    )
-    common2 = dict(common1, bq=bq2, bk=bk2)
-
-    # ---- dk/dv pass: grid (bh, k blocks, q blocks), or compacted band ----
-    if compact_dkv:
-        (dkv_q_map, dkv_kv_map, dkv_kvm_map, dkv_qsm_map,
-         dkv_out_map) = _compact_maps(h, hk, g)
-        dkv_scalars = (offs, *dkv_tabs)
-        dkv_grid = (b * h, dkv_tabs[0].shape[0])
-        dkv_semantics = ("parallel", "arbitrary")
-    else:
-        dkv_q_map = q_map_inner
-        dkv_kv_map = kv_map_outer
-        dkv_kvm_map = lambda bh, ki, qi, *_: (bh // h, ki)  # noqa: E731
-        dkv_qsm_map = lambda bh, ki, qi, *_: (bh // h, qi)  # noqa: E731
-        dkv_out_map = lambda bh, ki, qi, *_: (bh, ki, 0)  # noqa: E731
-        dkv_scalars = (offs,)
-        dkv_grid = (b * h, nk // bk1, nq // bq1)
-        dkv_semantics = ("parallel", "parallel", "arbitrary")
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel,
-        compact=compact_dkv,
-        masked=masked,
-        segmented=seg_dkv,
-        nq_blocks=nq // bq1,
-        **common1,
     )
 
     in_specs = [
-        pl.BlockSpec((1, bq1, d), dkv_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq1, d), dkv_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq1, 1), dkv_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq1, 1), dkv_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk1, d), dkv_kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk1, d), dkv_kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bq, d), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bq, d), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bq, 1), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bq, 1), q_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, d), kv_map, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, d), kv_map, memory_space=pltpu.VMEM),
     ]
-    inputs = [qr, dor, lser, deltar, kr, vr]
-    # per-token operands, oriented per pass (_token_vectors): the dk/dv
-    # tiles are transposed (keys on rows), so k-side vectors are columns
-    # there and rows in the dq pass; q-side vectors the other way round
+    inputs = [
+        q.reshape(b * h, nq, d),
+        do.reshape(b * h, nq, d).astype(q.dtype),
+        lse.reshape(b * h, nq, 1),
+        delta.reshape(b * h, nq, 1),
+        k.reshape(b * hk, nk, d),
+        v.reshape(b * hk, nk, d),
+    ]
+    # per-token operands (_token_vectors): the tiles are transposed (keys
+    # on rows), so k-side vectors are columns and q-side vectors rows
     if masked:
-        kvm = kv_mask.astype(jnp.int32)
-        in_specs.append(_token_spec(bk1, True, dkv_kvm_map))
-        inputs.append(_token_vectors(kvm, True))
-    if seg_dkv:
+        in_specs.append(_token_spec(bk, True, kvm_map))
+        inputs.append(_token_vectors(kv_mask.astype(jnp.int32), True))
+    if segmented:
         in_specs += [
-            _token_spec(bq1, False, dkv_qsm_map),
-            _token_spec(bk1, True, dkv_kvm_map),
+            _token_spec(bq, False, qsm_map),
+            _token_spec(bk, True, kvm_map),
         ]
-        inputs += [_token_vectors(q_seg, False), _token_vectors(kv_seg, True)]
+        inputs += [_token_vectors(q_seg.astype(jnp.int32), False),
+                   _token_vectors(kv_seg.astype(jnp.int32), True)]
 
-    _log_launch("flash_bwd_dkv", nq, nk, h, hk, d, bq1, bk1, dkv_grid,
-                compact_dkv)
-    dk_h, dv_h = pl.pallas_call(
-        dkv_kernel,
+    d_pad = d + (-d) % LANE
+    name = "flash_bwd_dkv_dq"
+    _log_launch(name, nq, nk, h, hk, d, bq, bk, grid, compact)
+    dq, dk_h, dv_h = pl.pallas_call(
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(dkv_scalars),
-            grid=dkv_grid,
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, bk1, d), dkv_out_map, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, bk1, d), dkv_out_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, bk, d), k_out_map, memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, bk, d), k_out_map, memory_space=pltpu.VMEM),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bk1, d), jnp.float32),
-                pltpu.VMEM((bk1, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((_DQ_SLOTS, bq, d_pad), jnp.float32),
+                pltpu.SemaphoreType.DMA((_DQ_SLOTS,)),  # ra: allow(RA013 completion semaphores of the launch's own dq copies, see _bwd_kernel)
+                pltpu.SemaphoreType.DMA((_DQ_SLOTS,)),  # ra: allow(RA013 completion semaphores of the launch's own dq copies, see _bwd_kernel)
             ],
         ),
         out_shape=[
+            _sds((b * h, nq, d_pad), jnp.float32, q),
             _sds((b * h, nk, d), jnp.float32, q),
             _sds((b * h, nk, d), jnp.float32, q),
         ],
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=dkv_semantics
+            dimension_semantics=semantics,
+            vmem_limit_bytes=_bwd_vmem_limit(
+                bq, bk, d_pad, q.dtype.itemsize, softclamp_value is not None),
         ),
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(*dkv_scalars, *inputs)
+        name=name,
+    )(*scalars, *inputs)
 
-    # GQA: sum per-query-head dk/dv over the group
+    # GQA: dk/dv leave the kernel per query head and are summed over the
+    # group here.  Summing them in the kernel (the group innermost in the
+    # grid) was built and works, but with 1.5 GB less live around the
+    # launch XLA's memory scheduler picks another order for the whole
+    # train step, whose peak is 0.85 GiB higher (PERF.md section 6, PR 31)
     dk = dk_h.reshape(b, hk, g, nk, d).sum(axis=2)
     dv = dv_h.reshape(b, hk, g, nk, d).sum(axis=2)
     if dkv_post_scale != 1.0:
         # exp2 mode: dsT·q̃ carries a surplus log2e; ln2 restores it
         # (one (nk, d) f32 multiply vs one per (bq, bk) tile)
         dk = dk * dkv_post_scale
-
-    # ---- dq pass: grid (bh, q blocks, k blocks), or compacted band ----
-    if compact_dq:
-        dq_q_map, dq_kv_map, dq_kvm_map, dq_qsm_map, _ = _compact_maps(h, hk, g)
-        dq_scalars = (offs, *dq_tabs)
-        dq_grid = (b * h, dq_tabs[0].shape[0])
-        dq_semantics = ("parallel", "arbitrary")
-    else:
-        dq_q_map = q_map
-        dq_kv_map = kv_map_inner
-        dq_kvm_map = lambda bh, qi, ki, *_: (bh // h, ki)  # noqa: E731
-        dq_qsm_map = lambda bh, qi, ki, *_: (bh // h, qi)  # noqa: E731
-        dq_scalars = (offs,)
-        dq_grid = (b * h, nq // bq2, nk // bk2)
-        dq_semantics = ("parallel", "parallel", "arbitrary")
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel,
-        compact=compact_dq,
-        masked=masked,
-        segmented=seg_dq,
-        nk_blocks=nk // bk2,
-        **common2,
-    )
-
-    in_specs = [
-        pl.BlockSpec((1, bq2, d), dq_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq2, d), dq_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq2, 1), dq_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq2, 1), dq_q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk2, d), dq_kv_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk2, d), dq_kv_map, memory_space=pltpu.VMEM),
-    ]
-    inputs = [qr, dor, lser, deltar, kr, vr]
-    if masked:
-        inputs.append(_token_vectors(kvm, False))
-        in_specs.append(_token_spec(bk2, False, dq_kvm_map))
-    if seg_dq:
-        in_specs += [
-            _token_spec(bq2, True, dq_qsm_map),
-            _token_spec(bk2, False, dq_kvm_map),
-        ]
-        inputs += [_token_vectors(q_seg, True), _token_vectors(kv_seg, False)]
-
-    _log_launch("flash_bwd_dq", nq, nk, h, hk, d, bq2, bk2, dq_grid,
-                compact_dq)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(dq_scalars),
-            grid=dq_grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bq2, d), dq_q_map, memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.VMEM((bq2, d), jnp.float32)],
-        ),
-        out_shape=_sds((b * h, nq, d), jnp.float32, q),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=dq_semantics
-        ),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(*dq_scalars, *inputs)
-
+    dq = dq[:, :, :d].reshape(b, h, nq, d)
     if dq_post_scale != 1.0:
         dq = dq * dq_post_scale  # f32 output, power-of-two: exact
-    return dq.reshape(b, h, nq, d), dk, dv
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------

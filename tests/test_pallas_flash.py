@@ -1,7 +1,7 @@
 """Parity: Pallas flash kernels (interpret mode on CPU) vs the oracle.
 
 The kernels are exercised through the same contract as the XLA blockwise
-path: forward outputs, lse, partial merging, and the two-pass backward must
+path: forward outputs, lse, partial merging, and the one-pass backward must
 match ``default_attention`` and its autodiff gradients.  On CPU the kernels
 run in Pallas interpreter mode; identical code compiles to Mosaic on TPU.
 """
@@ -406,38 +406,129 @@ def test_band_hint_superset_merges_exactly(rng):
         np.testing.assert_allclose(a, b, atol=1e-5, err_msg=name)
 
 
-@pytest.mark.parametrize(
-    "traced,masked", [(False, False), (True, False), (True, True)],
-    ids=["compact", "rectangular", "rectangular-masked"],
-)
-def test_backward_per_pass_block_sizes(rng, traced, masked):
-    """dkv and dq passes accept independent tile shapes on both grids."""
+def _race_checked_interpret():
+    """The TPU interpreter with its race detector on, and a function that
+    says whether the launches since then raced (None where this jax has
+    no such interpreter: the values are still compared)."""
+    try:
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpret,
+        )
+        from jax.experimental.pallas import tpu as pltpu
+
+        params = pltpu.InterpretParams(detect_races=True,
+                                       dma_execution_mode="on_wait")
+        pltpu.reset_tpu_interpret_mode_state()  # an earlier test's verdict
+    except (ImportError, AttributeError, TypeError):
+        return True, lambda: None
+    return params, lambda: tpu_interpret.races.races_found
+
+
+# name: (shape kwargs, launch kwargs, options).  Shape: h, hk, nq, nk, d.
+# Options: traced (causal_offset / window_lo passed as traced scalars),
+# mask, seg (runtime segment ids), race (run under the race detector).
+_BWD_CASES = {
+    "unmasked-rect": (dict(), dict(), dict()),
+    "causal-compact": (dict(), dict(causal_offset=0), dict()),
+    "window-compact-g2": (dict(h=4, hk=2),
+                          dict(causal_offset=0, window_lo=-23), dict()),
+    "causal-rect-traced": (dict(), dict(causal_offset=0), dict(traced=True)),
+    "window-rect-traced": (dict(), dict(causal_offset=0, window_lo=-23),
+                           dict(traced=True)),
+    "kv-mask": (dict(), dict(), dict(mask=True)),
+    "kv-mask-causal-traced": (dict(h=4, hk=2), dict(causal_offset=0),
+                              dict(mask=True, traced=True)),
+    "segment-ids": (dict(), dict(causal_offset=0), dict(seg=True)),
+    "doc-starts-aligned": (dict(), dict(causal_offset=0,
+                                        doc_starts=(0, 16, 48)), dict()),
+    "doc-starts-misaligned": (dict(), dict(causal_offset=0,
+                                           doc_starts=(0, 24)), dict()),
+    "softclamp": (dict(), dict(causal_offset=0, softclamp_value=2.0), dict()),
+    "exp2": (dict(h=4, hk=2), dict(causal_offset=0, exp2=True), dict()),
+    "exp2-softclamp": (dict(), dict(softclamp_value=2.0, exp2=True), dict()),
+    "odd-scale": (dict(d=24), dict(causal_offset=0), dict()),
+    "ring-hop-nq<nk": (dict(nq=32, nk=64),
+                       dict(causal_offset=31, band_hint=(32, 30, 0, 0)),
+                       dict(traced=True)),
+    "ring-hop-striped": (dict(h=4, hk=2),
+                         dict(causal_offset=-1, band_hint=(0, -1, 0, 0)),
+                         dict(traced=True)),
+    "rows-without-keys": (dict(nq=64, nk=32), dict(causal_offset=-32), dict()),
+    "gqa-6": (dict(h=6, hk=1, nq=32, nk=32), dict(causal_offset=0), dict()),
+    "gqa-12": (dict(h=12, hk=1, nq=32, nk=32), dict(causal_offset=0), dict()),
+    "d128": (dict(h=1, nq=32, nk=32, d=128), dict(causal_offset=0), dict()),
+    "hazard-one-q-block": (dict(nq=16, nk=64), dict(), dict(race=True)),
+    "hazard-two-q-blocks": (dict(nq=32, nk=64), dict(), dict(race=True)),
+    "hazard-window-tail": (dict(), dict(causal_offset=0, window_lo=-7),
+                           dict(race=True)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BWD_CASES))
+def test_one_pass_backward_parity(rng, case):
+    """dq, dk, dv of the one-pass kernel against the XLA blockwise
+    backward, over every variant the launcher takes: both grids, every
+    mask, both score bases, GQA groups, a ring hop's traced offset under
+    its hint, q rows no key reaches, and the tiles whose dq read would
+    overtake an unfinished write (run under the race detector)."""
+    from ring_attention_tpu.ops.flash import flash_backward_blocks
     from ring_attention_tpu.ops.pallas_flash import pallas_flash_backward
 
-    q, k, v = make_qkv(rng, b=1, h=2, n=256, d=32)
+    shape, kw, opt = _BWD_CASES[case]
+    h, hk = shape.get("h", 2), shape.get("hk", shape.get("h", 2))
+    nq, nk, d = shape.get("nq", 64), shape.get("nk", 64), shape.get("d", 16)
+    g, blk = h // hk, 16
+    q = jnp.asarray(rng.standard_normal((1, h, nq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, hk, nk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, hk, nk, d)), jnp.float32)
     do = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
-    mask = jnp.asarray(rng.random((1, 256)) > 0.3) if masked else None
-    scale = q.shape[-1] ** -0.5
+    mask = jnp.asarray(rng.random((1, nk)) > 0.3) if opt.get("mask") else None
+    seg = None
+    if opt.get("seg"):
+        seg = jnp.asarray(np.arange(nq) // 24, jnp.int32)[None]
+    elif "doc_starts" in kw:  # the reference takes the layout as ids
+        seg = jnp.asarray(
+            np.searchsorted(kw["doc_starts"], np.arange(nq), side="right"),
+            jnp.int32)[None]
+    scale = d ** -0.5
+    co, wlo = kw.get("causal_offset"), kw.get("window_lo")
+    clamp = kw.get("softclamp_value")
+
     parts = pallas_flash_partials(
-        q, k, v, mask, scale=scale, causal_offset=0,
-        block_q=64, block_k=64, interpret=True,
+        q, k, v, mask, scale=scale, causal_offset=co, window_lo=wlo,
+        softclamp_value=clamp, segment_ids=seg, block_q=blk, block_k=blk,
+        interpret=True,
     )
     out, lse = finalize_partials(parts)
     delta = (do * out).sum(-1)
+    want = flash_backward_blocks(
+        do, q, k, v, lse.reshape(1, hk, g, nq), delta.reshape(1, hk, g, nq),
+        scale=scale, bucket_size=blk, causal_offset=co, window_lo=wlo,
+        kv_mask=mask, softclamp_value=clamp, q_segment_ids=seg,
+        kv_segment_ids=seg,
+    )
 
-    def run(**blocks):
-        co = jnp.int32(0) if traced else 0
-        f = lambda c: pallas_flash_backward(  # noqa: E731
-            do, q, k, v, lse, delta, mask, scale=scale, causal_offset=c,
-            interpret=True, **blocks,
-        )
-        return jax.jit(f)(co) if traced else f(co)
+    interpret, raced = (_race_checked_interpret() if opt.get("race")
+                        else (True, lambda: None))
+    static = {x: kw[x] for x in ("softclamp_value", "exp2", "doc_starts",
+                                 "band_hint") if x in kw}
+    if opt.get("seg"):
+        static["segment_ids"] = seg
 
-    base = run(block_q=64, block_k=64)
-    split = run(block_q_dkv=32, block_k_dkv=128,
-                block_q_dq=128, block_k_dq=32)
-    for a, b, name in zip(base, split, ("dq", "dk", "dv")):
-        np.testing.assert_allclose(a, b, atol=1e-4, err_msg=name)
+    def run(co, wlo):
+        return pallas_flash_backward(
+            do, q, k, v, lse, delta, mask, scale=scale, causal_offset=co,
+            window_lo=wlo, block_q=blk, block_k=blk, interpret=interpret,
+            **static)
+
+    if opt.get("traced"):
+        got = jax.jit(lambda c, w: run(c, w if wlo is not None else None))(
+            jnp.int32(co), jnp.int32(wlo or 0))
+    else:
+        got = run(co, wlo)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4, err_msg=name)
+    assert not raced(), "a dq copy raced with another"
 
 
 def test_carry_resume_matches_merge(rng):

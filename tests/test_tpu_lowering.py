@@ -11,6 +11,7 @@ per-row block specs and the 1x1 tile an odd sequence length produced.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -57,9 +58,10 @@ VARIANTS = {
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
 def test_flash_fwd_and_bwd_lower(variant):
-    """flash_fwd_tile + flash_bwd_dkv + flash_bwd_dq, per mask variant.
-    ``segment_ids`` at batch 2: a 2-D ``(b, n)`` operand blocked
-    ``(1, block)`` is legal only at batch 1, which is how it hid."""
+    """flash_fwd_tile + flash_bwd_dkv_dq (the one-pass backward, with its
+    hand-started dq copies), per mask variant.  ``segment_ids`` at batch
+    2: a 2-D ``(b, n)`` operand blocked ``(1, block)`` is legal only at
+    batch 1, which is how it hid."""
     kw, shape = VARIANTS[variant]
     q, k, v = qkv(**shape)
     if variant.startswith("segment_ids"):
@@ -70,12 +72,14 @@ def test_flash_fwd_and_bwd_lower(variant):
             q, k, v, causal=True, interpret=False, **kw
         ).astype(jnp.float32).sum()
 
-    tpu_lower(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    lowered = tpu_lower(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.mlir_module()))
+    assert kernels == {"flash_fwd_tile", "flash_bwd_dkv_dq"}
 
 
 def test_flash_padding_mask_lowers_at_batch_2():
     """Non-causal attention with a key-padding mask: the mask rides the
-    same per-token layout as the segment ids, fwd and both bwd passes."""
+    same per-token layout as the segment ids, fwd and bwd."""
     q, k, v = qkv(b=2)
     mask = jnp.ones((2, N), jnp.bool_)
 

@@ -30,9 +30,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=262144)
     ap.add_argument("--sweep", action="store_true")
-    ap.add_argument("--bwd-sweep", action="store_true",
-                    help="sweep per-pass backward block sizes "
-                         "(block_*_dkv / block_*_dq, VERDICT r2 #5)")
     ap.add_argument("--heads", type=int, default=8)
     ap.add_argument("--kv-heads", type=int, default=None,
                     help="GQA: fewer KV heads (BASELINE config 4 is 32/4)")
@@ -571,70 +568,6 @@ def main() -> None:
             "mode": "fwdbwd", "seq": seq,
             "error": f"{type(e).__name__}: {str(e)[:160]}",
         }))
-
-    if not args.bwd_sweep:
-        return
-
-    # ---- backward-pass block sweep: time pallas_flash_backward alone with
-    # per-pass tile overrides; stage 1 sweeps the dk/dv pass with the dq
-    # pass pinned, stage 2 vice versa (independent grids, VERDICT r2 #5)
-    from ring_attention_tpu.ops.pallas_flash import pallas_flash_backward
-
-    parts = pallas_flash_partials(q, k, v, scale=scale, causal_offset=0,
-                                  interpret=args.interpret)
-    out, lse = finalize_partials(parts)
-    delta = (do.astype(jnp.float32) * out).sum(-1)
-    lse = jax.block_until_ready(lse)
-    # executed matmuls: dkv pass (sT, dv, dpT, dk) + dq pass (s, dp, dq)
-    flops_bwd = 7 * 2 * seq * seq * h * d * 0.5
-
-    def bwd_only_chained(blocks):
-        @jax.jit
-        def chained(do, q, k, v, lse, delta):
-            def body(c, _):
-                dq, dk, dv = pallas_flash_backward(
-                    c, q, k, v, lse, delta, scale=scale, causal_offset=0,
-                    interpret=args.interpret, **blocks,
-                )
-                nxt = (c + 1e-6 * dq.astype(c.dtype)
-                       + (dk.mean() + dv.mean()).astype(c.dtype) * 1e-9)
-                return nxt, dq[0, 0, 0, 0]
-            _, ys = jax.lax.scan(body, do, None, length=iters)
-            return ys.sum()
-        return chained
-
-    pairs = [(512, 512), (512, 1024), (1024, 512), (1024, 1024),
-             (1024, 2048), (2048, 512), (2048, 1024), (512, 2048)]
-    results = {}
-    for stage, prefix in (("dkv", "block_{}_dkv"), ("dq", "block_{}_dq")):
-        for bq, bk in pairs:
-            blocks = {prefix.format("q"): bq, prefix.format("k"): bk}
-            try:
-                compile_s, secs = timed_chained(
-                    bwd_only_chained(blocks), (do, q, k, v, lse, delta), iters
-                )
-                results[(stage, bq, bk)] = secs
-                print(json.dumps({
-                    "mode": f"bwd-{stage}", "seq": seq,
-                    "block_q": bq, "block_k": bk,
-                    "tflops": round(flops_bwd / secs / 1e12, 1),
-                    "ms": round(secs * 1e3, 1),
-                    "compile_s": round(compile_s, 1),
-                }))
-            except Exception as e:  # noqa: BLE001 - sweep survives rejects
-                print(json.dumps({
-                    "mode": f"bwd-{stage}", "seq": seq,
-                    "block_q": bq, "block_k": bk,
-                    "error": f"{type(e).__name__}: {str(e)[:160]}",
-                }))
-    for stage in ("dkv", "dq"):
-        timed = {k_: v_ for k_, v_ in results.items() if k_[0] == stage}
-        if timed:
-            best = min(timed, key=timed.get)
-            print(json.dumps({
-                "mode": f"bwd-{stage}-best", "block_q": best[1],
-                "block_k": best[2], "ms": round(timed[best] * 1e3, 1),
-            }))
 
 
 if __name__ == "__main__":
