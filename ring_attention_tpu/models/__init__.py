@@ -1,4 +1,4 @@
-from .attention import RingAttention
+from .attention import LatentAttention, RingAttention
 from .config import LayerConfig, ModelConfig
 from .layers import FeedForward, GatedFeedForward, RMSNorm
 from .moe import RoutedFeedForward
@@ -7,6 +7,7 @@ from .transformer import RingTransformer
 
 __all__ = [
     "RingAttention",
+    "LatentAttention",
     "FeedForward",
     "GatedFeedForward",
     "RoutedFeedForward",

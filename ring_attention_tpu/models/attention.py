@@ -40,7 +40,17 @@ from ..ops.pallas_flash import (
     pallas_flash_decode_q8,
     quantize_kv_cache,
 )
-from ..ops.rotary import apply_rotary, hybrid_positions, ring_positions, rotary_freqs
+from ..ops.pallas_latent import (
+    latent_decode_attention,
+    pallas_flash_decode_latent,
+)
+from ..ops.rotary import (
+    YarnScaling,
+    apply_rotary,
+    hybrid_positions,
+    ring_positions,
+    rotary_freqs,
+)
 from ..parallel.hybrid import hybrid_attention
 from ..parallel.mesh import (
     RING_AXIS,
@@ -65,6 +75,7 @@ from ..parallel.zigzag import zigzag_attention, zigzag_positions
 from ..utils import compat
 from ..utils.validate import check_model_input
 from .layers import RMSNorm
+from .moe import COUNTERS
 
 
 class RingAttention(nn.Module):
@@ -467,12 +478,17 @@ class RingAttention(nn.Module):
             out = out[:, :n_orig]
         return out
 
+    def _rotate(self, q, k, positions):
+        """``q`` and ``k`` rotated to ``positions`` (one per row of their
+        sequence axis), or as they are in a layer without positions."""
+        if not self.rotary:
+            return q, k
+        freqs = rotary_freqs(positions, self.dim_head, self.rotary_theta)
+        return apply_rotary(q, freqs), apply_rotary(k, freqs)
+
     def _local_attend(self, q, k, v, mask, segment_ids=None):
         n = q.shape[2]
-        if self.rotary:
-            freqs = rotary_freqs(jnp.arange(n), self.dim_head, self.rotary_theta)
-            q = apply_rotary(q, freqs)
-            k = apply_rotary(k, freqs)
+        q, k = self._rotate(q, k, jnp.arange(n))
         # a mask-declared packing: doc_starts feed the Pallas compact
         # grid directly; the XLA/oracle paths realize them as runtime ids
         form = self._mask_form()
@@ -578,10 +594,8 @@ class RingAttention(nn.Module):
         def core(q, k, v, seg):
             if self.rotary:
                 rank = lax.axis_index(SEQ_AXIS)
-                pos = zigzag_positions(n_local, rank, ring_size)
-                freqs = rotary_freqs(pos, self.dim_head, self.rotary_theta)
-                q = apply_rotary(q, freqs)
-                k = apply_rotary(k, freqs)
+                q, k = self._rotate(
+                    q, k, zigzag_positions(n_local, rank, ring_size))
             return zigzag_attention(
                 q, k, v, SEQ_AXIS,
                 bucket_size=self.bucket_size,
@@ -605,10 +619,8 @@ class RingAttention(nn.Module):
         def core(q, k, v, mask, seg):
             if self.rotary:
                 rank = lax.axis_index(SEQ_AXIS)
-                pos = ring_positions(n_local, rank, striped=False, world=ring_size)
-                freqs = rotary_freqs(pos, self.dim_head, self.rotary_theta)
-                q = apply_rotary(q, freqs)
-                k = apply_rotary(k, freqs)
+                q, k = self._rotate(q, k, ring_positions(
+                    n_local, rank, striped=False, world=ring_size))
             return ulysses_attention(
                 q, k, v, SEQ_AXIS,
                 causal=self._eff_causal(),
@@ -654,9 +666,7 @@ class RingAttention(nn.Module):
                     lax.axis_index(RING_AXIS),
                     ulysses=ulysses, ring=ring_size, striped=self.striped,
                 )
-                freqs = rotary_freqs(pos, self.dim_head, self.rotary_theta)
-                q_r = apply_rotary(q, freqs)
-                k_r = apply_rotary(k, freqs)
+                q_r, k_r = self._rotate(q, k, pos)
             else:
                 q_r, k_r = q, k
             return hybrid_attention(
@@ -695,9 +705,7 @@ class RingAttention(nn.Module):
                 pos = ring_positions(
                     n_local, rank, striped=self.striped, world=ring_size
                 )
-                freqs = rotary_freqs(pos, self.dim_head, self.rotary_theta)
-                q_r = apply_rotary(q, freqs)
-                k_r = apply_rotary(k, freqs)
+                q_r, k_r = self._rotate(q, k, pos)
             else:
                 q_r, k_r = q, k
             return ring_flash_attention(
@@ -748,12 +756,7 @@ class RingAttention(nn.Module):
         positions are explicit.  Returns ``(out (b,1,dim), cache_k, cache_v)``.
         """
         q, k, v, gate = self._project_qkv(x)
-        if self.rotary:
-            freqs = rotary_freqs(
-                jnp.reshape(pos, (1,)), self.dim_head, self.rotary_theta
-            )
-            q = apply_rotary(q, freqs)
-            k = apply_rotary(k, freqs)
+        q, k = self._rotate(q, k, jnp.reshape(pos, (1,)))
 
         ring = self.use_ring and not self.force_regular_attn and self._ring_size() > 1
         # the local cache is a ring buffer: writes land at pos % size and
@@ -882,10 +885,7 @@ class RingAttention(nn.Module):
                     f"max_lookback_seq_len ({self._eff_lookback()})"
                 )
         q, k, v, gate = self._project_qkv(x)
-        if self.rotary:
-            freqs = rotary_freqs(jnp.arange(n), self.dim_head, self.rotary_theta)
-            q = apply_rotary(q, freqs)
-            k = apply_rotary(k, freqs)
+        q, k = self._rotate(q, k, jnp.arange(n))
 
         ring = self.use_ring and not self.force_regular_attn and self._ring_size() > 1
         if ring:
@@ -1049,3 +1049,233 @@ class RingAttention(nn.Module):
             out_specs=(rep, cache_spec, cache_spec),
             check_vma=not self._use_pallas(),
         )(q, k, v, cache_k, cache_v, pos)
+
+
+class LatentAttention(RingAttention):
+    """Latent attention (MLA, after the public DeepSeek-V3 block): queries
+    through a ``q_latent_dim`` bottleneck with a norm, keys and values
+    through one ``kv_latent_dim`` latent a position with a norm, plus
+    ``qk_rope_dim`` rotary dimensions a position that all heads share; a
+    head's q.k width is ``dim_head = qk_nope_dim + qk_rope_dim`` and its
+    value width ``v_dim``.
+
+    One layer, two arithmetic forms.  ``__call__`` and ``prefill`` compute
+    the *expanded* form: the latent is projected up to per-head keys and
+    values and the layer is ordinary multi-head attention, so every path
+    ``RingAttention`` has (the oracle, the XLA scan, the Pallas kernel, the
+    sequence-parallel schemes) applies as it stands.  They take one width
+    for q, k and v, so ``v`` goes in zero-padded to ``dim_head`` and the
+    output's padding is dropped before ``to_out`` (ROADMAP R4 is a kernel
+    that takes the two widths).  ``decode_step`` computes the *absorbed*
+    form over the latent cache: ``W_UK`` is folded into the query and
+    ``W_UV`` applied to the attended latents, so no key or value is ever
+    expanded or cached (``ops/pallas_latent.py``).
+
+    The cache, one pair a layer: ``cache_k`` the rotated positional keys
+    transposed ``(b, 1, qk_rope_dim, capacity)``, ``cache_v`` the normed
+    latents ``(b, 1, capacity, kv_latent_dim)``, which serve as keys and as
+    values: ``kv_latent_dim + qk_rope_dim`` values a position, stored once
+    (why two arrays: ``ops/pallas_latent.py``).
+
+    The softmax scale ``dim_head ** -0.5 * m * m`` (``m`` the yarn
+    ``mscale``) reaches every path by folding ``m * m`` into the queries.
+    """
+
+    q_latent_dim: int = 0
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_dim: int = 0
+    rope_scaling: YarnScaling | None = None
+
+    def setup(self):
+        if self.quantize_cache or self.qk_norm or self.out_gate:
+            raise ValueError(
+                "LatentAttention: a latent layer has no int8 cache, per-head "
+                "q/k norm or output gate")
+        h = self.heads
+
+        def dense(width):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype)
+
+        self.prenorm = RMSNorm(self.dim, self.norm_eps)
+        self.to_q_latent = dense(self.q_latent_dim)
+        self.q_latent_norm = RMSNorm(self.q_latent_dim, self.norm_eps)
+        self.to_q = dense(h * self.dim_head)
+        self.to_kv_latent = dense(self.kv_latent_dim + self.qk_rope_dim)
+        self.kv_latent_norm = RMSNorm(self.kv_latent_dim, self.norm_eps)
+        # per head (k_nope | v), W_UK and W_UV side by side: a bare matrix,
+        # because the absorbed form multiplies by its two halves apart and
+        # the prefill expands a session at a time inside ``lax.map``
+        self.to_kv = self.param(
+            "to_kv", nn.initializers.lecun_normal(),
+            (self.kv_latent_dim, h * (self.qk_nope_dim + self.v_dim)))
+        self.to_out = dense(self.dim)
+
+    def _query_latents(self, normed):
+        with jax.named_scope("attn/latent_q"):
+            return self.q_latent_norm(self.to_q_latent(normed))
+
+    def _queries(self, c_q, w=None):
+        """``(b, h, n, dim_head)`` from the normed query latents: per head
+        (q_nope | q_rope), unrotated, the yarn part of the softmax scale
+        folded in.  ``w``: ``to_q``'s matrix, for a caller under
+        ``lax.map``, where no variable may be read."""
+        with jax.named_scope("attn/latent_q"):
+            b, n, _ = c_q.shape
+            q = self.to_q(c_q) if w is None else jnp.dot(c_q, w)
+            q = q.reshape(b, n, self.heads, self.dim_head).transpose(0, 2, 1, 3)
+            m = 1.0 if self.rope_scaling is None else (
+                self.rope_scaling.softmax_mscale)
+            return q if m == 1.0 else q * jnp.asarray(m * m, q.dtype)
+
+    def _latents(self, normed, positions=None):
+        """The normed latents ``(b, n, kv_latent_dim)`` and the positional
+        keys ``(b, 1, n, qk_rope_dim)``, rotated to ``positions`` (None: as
+        they are, for a caller whose path rotates)."""
+        with jax.named_scope("attn/latent_kv"):
+            c, k_r = jnp.split(self.to_kv_latent(normed),
+                               [self.kv_latent_dim], axis=-1)
+            k_r = k_r[:, None]
+            if positions is not None:
+                k_r = self._rotate_rope(k_r, positions)
+            return self.kv_latent_norm(c), k_r
+
+    def _write(self, cache_k, cache_v, c, k_r, at):
+        """The caches with the latents ``c`` and the rotated ``k_r`` of
+        consecutive positions written from position ``at``."""
+        with jax.named_scope("attn/latent_kv"):
+            return (
+                lax.dynamic_update_slice(
+                    cache_k, k_r.swapaxes(2, 3).astype(cache_k.dtype),
+                    (0, 0, 0, at)),
+                lax.dynamic_update_slice(
+                    cache_v, c[:, None].astype(cache_v.dtype), (0, 0, at, 0)))
+
+    def _rotate_rope(self, x, positions):
+        """``x: (..., n, qk_rope_dim)`` rotated to ``positions``."""
+        freqs = rotary_freqs(positions, self.qk_rope_dim, self.rotary_theta,
+                             self.rope_scaling)
+        x = apply_rotary(x, freqs)
+        m = 1.0 if self.rope_scaling is None else (
+            self.rope_scaling.rotation_mscale)
+        return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+    def _rotate(self, q, k, positions):
+        """The rotary columns of ``(b, h, n, dim_head)`` queries and
+        expanded keys (the last ``qk_rope_dim``) rotated to ``positions``."""
+        def rotated(x):
+            nope, rope = jnp.split(x, [self.qk_nope_dim], axis=-1)
+            return jnp.concatenate(
+                [nope, self._rotate_rope(rope, positions)], axis=-1)
+
+        return rotated(q), rotated(k)
+
+    def _expand(self, c, k_r):
+        """Per-head keys ``(b, h, n, dim_head)`` (k_nope | the shared
+        ``k_r``) and values zero-padded to the same width, from the latents
+        ``(b, n, kv_latent_dim)`` and ``k_r: (b, 1, n, qk_rope_dim)``.
+        (``to_kv`` is an array since ``setup``, not a variable read here, so
+        the prefill may call this under ``lax.map``.)"""
+        with jax.named_scope("attn/expand"):
+            b, n, _ = c.shape
+            h = self.heads
+            kv = jnp.dot(c, self.to_kv.astype(c.dtype)).reshape(
+                b, n, h, self.qk_nope_dim + self.v_dim).transpose(0, 2, 1, 3)
+            k_n, v = jnp.split(kv, [self.qk_nope_dim], axis=-1)
+            k = jnp.concatenate([k_n, jnp.broadcast_to(
+                k_r.astype(k_n.dtype), (b, h, n, self.qk_rope_dim))], axis=-1)
+            v = jnp.pad(v, [(0, 0)] * 3 + [(0, self.dim_head - self.v_dim)])
+            return k, v
+
+    def _project_qkv(self, x: jax.Array):
+        """The forward's expanded q, k and v; the caller's path rotates
+        (``_rotate``), so ``k_r`` is expanded unrotated here."""
+        normed = self.prenorm(x)
+        k, v = self._expand(*self._latents(normed))
+        return self._queries(self._query_latents(normed)), k, v, None
+
+    def _project_out(self, out: jax.Array, gate=None):
+        return super()._project_out(out[..., :self.v_dim], None)
+
+    def _off_the_ring(self, call: str) -> None:
+        if (self.use_ring and not self.force_regular_attn
+                and self._ring_size() > 1):
+            raise NotImplementedError(
+                f"LatentAttention.{call}: a latent cache is not sharded over "
+                f"a sequence mesh yet (ROADMAP R5); decode a latent model "
+                f"with mesh=None or use_ring=False")
+
+    def prefill(self, x, cache_k, cache_v):
+        """The prompt in one causal pass of the expanded form; the cache
+        gets the prompt's latents and rotated positional keys at [0, n)."""
+        self._off_the_ring("prefill")
+        n, size = x.shape[1], cache_v.shape[2]
+        if n > size:
+            raise ValueError(
+                f"prefill: prompt ({n}) longer than the latent cache ({size})")
+        normed = self.prenorm(x)
+        positions = jnp.arange(n)
+        c, k_r = self._latents(normed, positions)
+        cache_k, cache_v = self._write(cache_k, cache_v, c, k_r, 0)
+        c_q = self._query_latents(normed)
+        w_q = self.to_q.variables["params"]["kernel"].astype(c.dtype)
+
+        def one_session(args):
+            # (n, q_latent_dim), (n, kv_latent_dim), (1, n, qk_rope_dim)
+            c_q, c, k_r = (a[None] for a in args)
+            q_n, q_r = jnp.split(self._queries(c_q, w_q),
+                                 [self.qk_nope_dim], axis=-1)
+            q = jnp.concatenate(
+                [q_n, self._rotate_rope(q_r, positions)], axis=-1)
+            k, v = self._expand(c, k_r)
+            return self._attend(q, k, v, causal=True)[0, ..., :self.v_dim]
+
+        # a session at a time: expanded to every head, 16,384 tokens of q,
+        # of k and of v are 1 GB each (192 columns pad to 256 lanes)
+        out = lax.map(one_session, (c_q, c, k_r))
+        if self._use_pallas():
+            # as RingAttention.prefill: tie the cache write to its layer
+            out, cache_k, cache_v = lax.optimization_barrier(
+                (out, cache_k, cache_v))
+        return self._project_out(out), cache_k, cache_v
+
+    def decode_step(self, x, cache_k, cache_v, pos):
+        """One token in the absorbed form: this position's latent and
+        rotated ``k_r`` are written at ``pos``, then every head attends
+        positions ``[0, pos]`` of the latents themselves."""
+        self._off_the_ring("decode_step")
+        b, h = x.shape[0], self.heads
+        dn, dv, dl = self.qk_nope_dim, self.v_dim, self.kv_latent_dim
+        normed = self.prenorm(x)
+        position = jnp.reshape(pos, (1,))
+        c, k_r = self._latents(normed, position)
+        cache_k, cache_v = self._write(cache_k, cache_v, c, k_r, pos)
+        q = self._queries(self._query_latents(normed))
+        q_n, q_r = jnp.split(q[:, :, 0], [dn], axis=-1)
+        q_r = self._rotate_rope(q_r[:, :, None], position)[:, :, 0]
+        with jax.named_scope("attn/absorb"):
+            w = self.to_kv.astype(q_n.dtype)
+            w_uk, w_uv = jnp.split(w.reshape(dl, h, dn + dv), [dn], axis=-1)
+            scale = self.dim_head ** -0.5
+            q_lat = (jnp.einsum("bhn,lhn->bhl", q_n, w_uk,
+                                preferred_element_type=jnp.float32)
+                     * scale).astype(q_n.dtype)
+            q_r = (q_r.astype(jnp.float32) * scale).astype(q_n.dtype)
+        kv_mask = self._buffer_mask(cache_v.shape[2], pos, b)
+        if not self.is_initializing():
+            self.sow(COUNTERS, "latent_cache_bytes_read",
+                     kv_mask.sum() * (dl + self.qk_rope_dim)
+                     * cache_v.dtype.itemsize,
+                     init_fn=lambda: None, reduce_fn=lambda _, new: new)
+        if self._use_pallas():
+            o_lat = pallas_flash_decode_latent(
+                q_lat, q_r, cache_v, cache_k, kv_mask)
+        else:
+            o_lat = latent_decode_attention(
+                q_lat, q_r, cache_v, cache_k, kv_mask).astype(q_n.dtype)
+        with jax.named_scope("attn/absorb"):
+            out = jnp.einsum("bhl,lhv->bhv", o_lat, w_uv,
+                             preferred_element_type=jnp.float32)
+            out = out.astype(q_n.dtype).reshape(b, 1, h * dv)
+        return self.to_out(out), cache_k, cache_v

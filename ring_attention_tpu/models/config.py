@@ -18,6 +18,8 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass
 
+from ..ops.rotary import YarnScaling
+
 FFN_KINDS = ("gelu", "gated", "routed")
 
 
@@ -55,6 +57,22 @@ class ModelConfig:
     route_scale: float = 1.0
     first_expert: int = 0
     experts_held: int = 0
+    # group-limited choice: the router's experts in ``expert_groups``
+    # consecutive groups, of which a token keeps ``groups_per_token`` (by
+    # the sum of each group's two best biased scores) and chooses inside
+    expert_groups: int = 1
+    groups_per_token: int = 1
+    # latent attention (all five or none): queries through a
+    # ``q_latent_dim`` bottleneck, keys and values through one
+    # ``kv_latent_dim`` latent a position plus ``qk_rope_dim`` rotary
+    # dimensions shared by the heads; a head's q.k width is ``qk_nope_dim +
+    # qk_rope_dim`` (``dim_head``) and its value width ``v_dim``
+    q_latent_dim: int = 0
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_dim: int = 0
+    rope_scaling: YarnScaling | None = None
 
     def __post_init__(self):
         for i, layer in enumerate(self.layers):
@@ -75,6 +93,32 @@ class ModelConfig:
                 f"num_experts and the held experts [{self.first_expert}, "
                 f"{self.first_expert + self.experts_held}) inside "
                 f"[0, {self.num_experts})")
+        if not (0 < self.groups_per_token <= self.expert_groups) or (
+                self.expert_groups > 1 and (
+                    self.num_experts % self.expert_groups
+                    or self.experts_per_token > self.groups_per_token
+                    * (self.num_experts // self.expert_groups))):
+            raise ValueError(
+                f"ModelConfig: {self.num_experts} experts do not make "
+                f"{self.expert_groups} equal groups of which a token keeps "
+                f"{self.groups_per_token} and chooses "
+                f"{self.experts_per_token} experts inside")
+        latent = (self.q_latent_dim, self.kv_latent_dim, self.qk_nope_dim,
+                  self.qk_rope_dim, self.v_dim)
+        if any(latent) and not (
+                all(w > 0 for w in latent) and self.qk_rope_dim % 2 == 0
+                and self.dim_head == self.qk_nope_dim + self.qk_rope_dim
+                and self.kv_heads == self.heads):
+            raise ValueError(
+                f"ModelConfig: latent attention needs all of q_latent_dim, "
+                f"kv_latent_dim, qk_nope_dim, qk_rope_dim (even) and v_dim "
+                f"(got {latent}), dim_head = qk_nope_dim + qk_rope_dim and "
+                f"kv_heads = heads")
+
+    @property
+    def latent(self) -> bool:
+        """Whether the attention layers are latent attention."""
+        return self.kv_latent_dim > 0
 
     @property
     def depth(self) -> int:
@@ -111,12 +155,13 @@ class ModelConfig:
                 f"(model_type, else architectures); there are "
                 f"{', '.join(sorted(_FAMILIES))} (models/config.py)")
         heads = d["num_attention_heads"]
-        return cls(
+        fields = dict(
             num_tokens=d["vocab_size"], dim=d["hidden_size"], heads=heads,
             dim_head=d.get("head_dim") or d["hidden_size"] // heads,
             kv_heads=d.get("num_key_value_heads") or heads,
             ffn_dim=d["intermediate_size"],
-            rotary_theta=float(d.get("rope_theta", 10000.0)), **family(d))
+            rotary_theta=float(d.get("rope_theta", 10000.0)))
+        return cls(**{**fields, **family(d)})
 
     @classmethod
     def from_file(cls, path: str) -> "ModelConfig":
@@ -168,4 +213,53 @@ def _afmoe(d: dict) -> dict:
         experts_held=d["num_experts"])
 
 
-_FAMILIES = {"starcoder2": _starcoder2, "afmoe": _afmoe}
+def _dots_vlm(d: dict) -> dict:
+    """The language model of ``dots.vlm1`` is the DeepSeek-V3 block (the
+    config names its keys one for one; after the public
+    ``modeling_deepseek.py``): latent attention with a norm on each
+    bottleneck and yarn-scaled rotary dimensions shared by the heads, plain
+    pre-norm residuals, ``first_k_dense_replace`` leading gated-SiLU layers
+    and then sigmoid-routed experts with a shared one, chosen inside the
+    ``topk_group`` best of ``n_group`` groups.  The vision tower and the
+    multi-token-prediction module are not built (ROADMAP, Reach).  Where
+    the file holds a chip's share, ``n_routed_experts`` counts the experts
+    held here, ``published.n_routed_experts`` the router's width and
+    ``first_expert`` the first one held."""
+    latent = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+              "qk_rope_head_dim", "v_head_dim")
+    missing = [k for k in latent if not d.get(k)]
+    if missing:
+        raise ValueError(
+            f"ModelConfig: a dots_vlm file gives every latent width; "
+            f"missing {', '.join(missing)}")
+    if (d.get("scoring_func") != "sigmoid" or d.get("topk_method") != "noaux_tc"
+            or not d.get("norm_topk_prob", True)
+            or d.get("moe_layer_freq", 1) != 1):
+        raise ValueError(
+            "ModelConfig: the routed layer is written for sigmoid scores, "
+            "the bias-corrected group-limited choice (noaux_tc), weights "
+            "normalised over the chosen experts (norm_topk_prob) and a "
+            "routed layer in every layer after the dense ones")
+    depth, dense = d["num_hidden_layers"], d["first_k_dense_replace"]
+    return dict(
+        layers=tuple(LayerConfig(ffn="gated" if i < dense else "routed")
+                     for i in range(depth)),
+        dim_head=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        kv_heads=d["num_attention_heads"],
+        norm_eps=d["rms_norm_eps"],
+        q_latent_dim=d["q_lora_rank"], kv_latent_dim=d["kv_lora_rank"],
+        qk_nope_dim=d["qk_nope_head_dim"], qk_rope_dim=d["qk_rope_head_dim"],
+        v_dim=d["v_head_dim"],
+        rope_scaling=YarnScaling.from_dict(d.get("rope_scaling")),
+        num_experts=d.get("published", d)["n_routed_experts"],
+        experts_per_token=d["num_experts_per_tok"],
+        expert_dim=d["moe_intermediate_size"],
+        shared_expert_dim=d["moe_intermediate_size"] * d["n_shared_experts"],
+        route_scale=d["routed_scaling_factor"],
+        first_expert=d.get("first_expert", 0),
+        experts_held=d["n_routed_experts"],
+        expert_groups=d["n_group"], groups_per_token=d["topk_group"])
+
+
+_FAMILIES = {"starcoder2": _starcoder2, "afmoe": _afmoe,
+             "dots_vlm": _dots_vlm}

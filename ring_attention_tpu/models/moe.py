@@ -3,7 +3,9 @@
 ``RoutedFeedForward`` is one holder's part of an expert-parallel layer:
 the router scores all ``num_experts`` published experts with a sigmoid,
 picks ``experts_per_token`` of them (a per-expert bias moves the choice
-and never the weights), normalises the chosen scores over all of the
+and never the weights; with ``expert_groups`` > 1 only inside the
+``groups_per_token`` groups of consecutive experts whose two best biased
+scores sum highest), normalises the chosen scores over all of the
 chosen, and this holder computes the ``experts_held`` consecutive experts
 from ``first_expert`` for the (token, expert) pairs that fell on them,
 added to the shared expert's output.  What the absent experts would have
@@ -46,6 +48,8 @@ class RoutedFeedForward(nn.Module):
     route_scale: float = 1.0
     norm_eps: float = 1e-12
     dtype: jnp.dtype | None = None
+    expert_groups: int = 1  # 1: the choice is over all experts
+    groups_per_token: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -68,7 +72,8 @@ class RoutedFeedForward(nn.Module):
             scores = jax.nn.sigmoid(jnp.dot(
                 m.astype(jnp.float32), router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST))
-            _, chosen = lax.top_k(scores + bias, self.experts_per_token)
+            _, chosen = lax.top_k(self._eligible(scores + bias),
+                                  self.experts_per_token)
             top = jnp.take_along_axis(scores, chosen, axis=-1)
             weights = self.route_scale * top / (
                 top.sum(-1, keepdims=True) + 1e-20)
@@ -80,6 +85,22 @@ class RoutedFeedForward(nn.Module):
                     d, self.shared_dim, dtype=self.dtype, prenorm=False,
                     name="shared")(m)
         return out.astype(x.dtype).reshape(b, n, d)
+
+    def _eligible(self, biased):
+        """The biased scores ``(tokens, experts)`` a token may choose from:
+        all of them, or with groups only those of its best groups, a group
+        ranked by the sum of its two largest (the others at -inf)."""
+        groups = self.expert_groups
+        if groups == 1:
+            return biased
+        with jax.named_scope("moe/groups"):
+            tokens, experts = biased.shape
+            by_group = biased.reshape(tokens, groups, experts // groups)
+            rank = lax.top_k(by_group, min(2, experts // groups))[0].sum(-1)
+            _, kept = lax.top_k(rank, self.groups_per_token)
+            keep = (kept[:, :, None] == jnp.arange(groups)).any(1)
+            return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(
+                tokens, experts)
 
     def _routed(self, m, chosen, weights, gate_up, down):
         """Sum over the held experts a token chose of ``weight x expert(m)``,
@@ -103,9 +124,16 @@ class RoutedFeedForward(nn.Module):
             starts, n_here = ends - counts, ends[-1]
             order = jnp.pad(order, (0, passes * rows - pairs))
         # free unless the caller asks: apply(..., mutable=["counters"])
-        for name, value in (("tokens_per_expert", counts),
-                            ("held_share", n_here / pairs),
-                            ("experts_touched", jnp.sum(counts > 0))):
+        counters = [("tokens_per_expert", counts),
+                    ("held_share", n_here / pairs),
+                    ("experts_touched", jnp.sum(counts > 0))]
+        if self.expert_groups > 1:  # every holder's pairs, by group
+            group = chosen.reshape(pairs) // (
+                self.num_experts // self.expert_groups)
+            counters.append(("pairs_per_group", jnp.sum(
+                group[:, None] == jnp.arange(self.expert_groups), axis=0,
+                dtype=jnp.int32)))
+        for name, value in counters:
             if not self.is_initializing():
                 self.sow(COUNTERS, name, value, init_fn=lambda: None,
                          reduce_fn=lambda _, new: new)

@@ -44,7 +44,7 @@ from ..parallel.sharding import (
     pad_to_multiple,
 )
 from ..utils.validate import check_tokens_input
-from .attention import RingAttention
+from .attention import LatentAttention, RingAttention
 from .. import masks as mask_algebra
 from .config import ModelConfig
 from .layers import FeedForward, GatedFeedForward, RMSNorm
@@ -205,7 +205,14 @@ class RingTransformer(nn.Module):
         # per-layer selectable
         ffn = {"gelu": FeedForward, "gated": GatedFeedForward,
                "routed": RoutedFeedForward}
-        attn_classes = [RingAttention] * self.depth
+        # a latent model's attention layers, and the widths only they take
+        latent = dict(
+            q_latent_dim=cfg.q_latent_dim, kv_latent_dim=cfg.kv_latent_dim,
+            qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
+            v_dim=cfg.v_dim, rope_scaling=cfg.rope_scaling,
+        ) if cfg.latent else {}
+        attn_classes = [
+            LatentAttention if cfg.latent else RingAttention] * self.depth
         ff_classes = [ffn[layer.ffn] for layer in cfg.layers]
         if self.remat:
             def rematted(classes):
@@ -246,6 +253,7 @@ class RingTransformer(nn.Module):
                 out_gate=cfg.attn_gate,
                 norm_eps=cfg.norm_eps,
                 dtype=self.dtype,
+                **latent,
             )
             for attn_cls, layer, layer_mask in zip(
                 attn_classes, cfg.layers, self._masks()
@@ -280,7 +288,9 @@ class RingTransformer(nn.Module):
             experts_per_token=cfg.experts_per_token,
             experts_held=cfg.experts_held, first_expert=cfg.first_expert,
             shared_dim=cfg.shared_expert_dim, route_scale=cfg.route_scale,
-            norm_eps=cfg.norm_eps, dtype=self.dtype)
+            norm_eps=cfg.norm_eps, dtype=self.dtype,
+            expert_groups=cfg.expert_groups,
+            groups_per_token=cfg.groups_per_token)
 
     def _ring_size(self) -> int:
         """Total sequence-parallel world (both axes of a factored mesh)."""
@@ -605,7 +615,11 @@ class RingTransformer(nn.Module):
         With ``quantize_cache`` each per-layer entry is a
         ``(values int8, scales f32)`` tuple (see
         ``RingAttention.quantize_cache``); otherwise a dense array in the
-        model dtype."""
+        model dtype.  A latent layer's pair is its rotated positional keys,
+        transposed, and its latents (``LatentAttention``): ``k`` is
+        ``(batch, 1, qk_rope_dim, max_len)`` and ``v`` ``(batch, 1, max_len,
+        kv_latent_dim)``, ``kv_latent_dim + qk_rope_dim`` values a position,
+        and no expanded key or value."""
         ring = self._ring_size()
         assert max_len % max(ring, 1) == 0
         if ring > 1 and self.mesh is not None and is_factored(self.mesh):
@@ -616,6 +630,19 @@ class RingTransformer(nn.Module):
             )
         kvh = self.kv_heads or self.heads
         dtype = self.dtype or jnp.float32
+        cfg = self._config()
+        if cfg.latent:
+            if ring > 1:
+                raise NotImplementedError(
+                    "init_cache: a latent cache is not sharded over a "
+                    "sequence mesh yet (ROADMAP R5); decode a latent model "
+                    "with mesh=None or use_ring=False")
+            return {
+                "k": [jnp.zeros((batch, 1, cfg.qk_rope_dim, max_len), dtype)
+                      for _ in cfg.layers],
+                "v": [jnp.zeros((batch, 1, max_len, cfg.kv_latent_dim), dtype)
+                      for _ in cfg.layers],
+            }
 
         def make_entry(size):
             shape = (batch, kvh, size, self.dim_head)
@@ -645,7 +672,7 @@ class RingTransformer(nn.Module):
         sizes = [
             max_len if layer.window is None or ring > 1
             else min(max_len, layer.window)
-            for layer in self._config().layers
+            for layer in cfg.layers
         ]
         return {
             "k": [make_entry(s) for s in sizes],

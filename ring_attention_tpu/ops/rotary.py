@@ -20,6 +20,9 @@ float32 (the reference forces fp32 via autocast-off, ref
 
 from __future__ import annotations
 
+import math
+from dataclasses import dataclass
+
 import jax
 import jax.numpy as jnp
 
@@ -64,9 +67,81 @@ def hybrid_positions(
     return ring_rank * (ulysses * n_local) + j
 
 
-def rotary_freqs(positions: jax.Array, dim: int, theta: float = 10000.0) -> jax.Array:
-    """Angles ``(n, dim)`` for NeoX-style (half-rotation) rotary embedding."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+@dataclass(frozen=True)
+class YarnScaling:
+    """A published config's ``rope_scaling`` of type ``yarn`` (after the
+    public DeepSeek-V3 implementation): frequencies that turn more than
+    ``beta_fast`` times over the original context keep their value, those
+    that turn fewer than ``beta_slow`` times are divided by ``factor``, and
+    a linear ramp blends the ones between."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @classmethod
+    def from_dict(cls, d: dict | None) -> "YarnScaling | None":
+        """``None`` for a null ``rope_scaling``; any type but yarn, or a
+        yarn without its two required keys, is a one-line error."""
+        if d is None:
+            return None
+        kind = d.get("type", d.get("rope_type"))
+        missing = {"factor", "original_max_position_embeddings"} - set(d)
+        if kind != "yarn" or missing:
+            raise ValueError(
+                f"rope_scaling: the rotary embedding scales by type 'yarn' "
+                f"with factor and original_max_position_embeddings; got "
+                f"type {kind!r}, missing {sorted(missing)}")
+        return cls(
+            factor=float(d["factor"]),
+            original_max_position=int(d["original_max_position_embeddings"]),
+            beta_fast=float(d.get("beta_fast", 32)),
+            beta_slow=float(d.get("beta_slow", 1)),
+            mscale=float(d.get("mscale", 1)),
+            mscale_all_dim=float(d.get("mscale_all_dim", 0)))
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        """``m``: the softmax scale of a yarn model is ``d ** -0.5 * m * m``."""
+        return self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def rotation_mscale(self) -> float:
+        """What cos and sin are multiplied by (1 where the two agree)."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    def inv_freq(self, dim: int, theta: float) -> jax.Array:
+        extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+        def turns_at(beta):  # the index whose frequency turns beta times
+            return (dim * math.log(self.original_max_position
+                                   / (beta * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = min(max(math.floor(turns_at(self.beta_fast)), 0), dim // 2 - 1)
+        high = min(max(math.ceil(turns_at(self.beta_slow)), 0), dim // 2 - 1)
+        span = high - low if high > low else 0.001
+        ramp = jnp.clip(
+            (jnp.arange(dim // 2, dtype=jnp.float32) - low) / span, 0.0, 1.0)
+        return extra / self.factor * ramp + extra * (1.0 - ramp)
+
+
+def rotary_freqs(positions: jax.Array, dim: int, theta: float = 10000.0,
+                 scaling: YarnScaling | None = None) -> jax.Array:
+    """Angles ``(n, dim)`` for NeoX-style (half-rotation) rotary embedding;
+    ``scaling`` blends the frequencies as yarn does."""
+    if scaling is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    else:
+        inv_freq = scaling.inv_freq(dim, theta)
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.concatenate([freqs, freqs], axis=-1)
 

@@ -385,11 +385,19 @@ STAGES: list[tuple[str, str, str, str, str | None]] = [
     ("final_norm", "final norm", _C, _L, None),
     # the routed feed-forward (models/moe.py) and the gated attention's
     # own parts, ahead of the module rows they sit inside
+    ("moe/groups", "expert group choice", _C, "router", None),
     ("moe/router", "expert router", _C, "router", None),
     ("moe/dispatch", "expert dispatch", _C, "experts", None),
     ("moe/experts", "expert products", _C, "experts", None),
     ("moe/shared", "shared expert", _C, "experts", None),
     ("moe/combine", "expert combine", _C, "experts", None),
+    # latent attention's own parts (models/attention.py LatentAttention);
+    # its decode kernel is a flash kernel by its name (flash_decode_latent)
+    ("attn/latent_q", "latent query projections", _C, "latent attention", None),
+    ("attn/latent_kv", "latent key/value and cache write", _C,
+     "latent attention", None),
+    ("attn/expand", "latent expanded to heads", _C, "latent attention", None),
+    ("attn/absorb", "latent absorbed products", _C, "latent attention", None),
     ("attn/qk_norm", "q/k norm", _C, "attention projections", None),
     ("attn/gate", "attention output gate", _C, "attention projections", None),
     ("post_attn_norms_", "post-attention norm", _C, "attention projections", None),

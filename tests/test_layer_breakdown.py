@@ -193,7 +193,7 @@ def test_stages_table_names_every_layer_once_per_needle():
     assert {row[3] for row in STAGES} == {
         "ring", "flash kernels", "xla flash", "optimizer", "loss and head",
         "feed-forward", "attention projections", "embed", "experts",
-        "router"}
+        "router", "latent attention"}
     assert {row[4] for row in STAGES} <= {None, "backward", "update"}
     # a kernel is a kernel wherever it is called from; the rest by scope
     assert layer_of("flash_partials_tile.3",
@@ -207,6 +207,17 @@ def test_stages_table_names_every_layer_once_per_needle():
     assert layer_of("ragged-dot-none.2", path.format("experts"))[0] == "experts"
     assert layer_of("fusion.4", path.format("router"))[0] == "router"
     assert layer_of("fusion.5", "jit(f)/attn_layers_1.prefill/attn/gate/mul")[
+        0] == "attention projections"
+    # so do a latent layer's: its projections by scope, its kernel by name,
+    # the group choice inside the router's scope
+    step = "jit(f)/RingTransformer.decode_step/attn_layers_2.decode_step/{}"
+    for scope in ("attn/latent_q/dot", "attn/latent_kv/dynamic_update_slice",
+                  "attn/expand/dot", "attn/absorb/dot_general"):
+        assert layer_of("fusion.6", step.format(scope))[0] == "latent attention"
+    assert layer_of("flash_decode_latent.2", step.format("pallas_call")) == (
+        "flash kernels", "forward")
+    assert layer_of("fusion.8", path.format("router/moe/groups"))[0] == "router"
+    assert layer_of("fusion.8", step.format("to_out/dot"))[
         0] == "attention projections"
 
 

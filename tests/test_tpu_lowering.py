@@ -77,6 +77,23 @@ def test_flash_fwd_and_bwd_lower(variant):
     assert kernels == {"flash_fwd_tile", "flash_bwd_dkv_dq"}
 
 
+@pytest.mark.parametrize("sessions, capacity", [(4, 131072), (1, 4096)])
+def test_latent_decode_lowers_at_the_published_widths(sessions, capacity):
+    """flash_decode_latent at dots.vlm1's widths (128 heads, a 512-wide
+    latent, 64 rotary dimensions), the timed program's shapes and the
+    check's: lane-aligned blocks of both cache arrays and the mask row."""
+    from ring_attention_tpu.ops.pallas_latent import pallas_flash_decode_latent
+
+    lowered = tpu_lower(
+        lambda ql, qr, c, kr, m: pallas_flash_decode_latent(
+            ql, qr, c, kr, m, interpret=False),
+        sds(sessions, 128, 512), sds(sessions, 128, 64),
+        sds(sessions, 1, capacity, 512), sds(sessions, 1, 64, capacity),
+        sds(sessions, capacity, dtype=jnp.bool_))
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.mlir_module())
+    assert kernels == ["flash_decode_latent"]
+
+
 def test_flash_padding_mask_lowers_at_batch_2():
     """Non-causal attention with a key-padding mask: the mask rides the
     same per-token layout as the segment ids, fwd and bwd."""
