@@ -20,9 +20,11 @@ ref ``tree_attn_decoding.py``).
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
+from flax.linen.dtypes import promote_dtype
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -54,6 +56,24 @@ from .remat import REMAT_POLICIES, resolve_remat_policy
 PROBES = "probes"  # the flax collection the stack walker sows into
 
 
+def _nll_parts(
+    logits: jax.Array,  # (..., vocab), any float dtype
+    labels: jax.Array,  # (...)
+    valid: jax.Array,  # (...) bool
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``_position_nll`` with the two float32 arrays its gradient is made
+    of: ``(nll, logits, logsumexp)``."""
+    # not a flax method, so it has no scope of its own: name it for the
+    # profiler's layer table (utils/profiling.py STAGES)
+    with jax.named_scope("loss/nll"):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        chosen = jnp.take_along_axis(
+            lf, jnp.where(valid, labels, 0)[..., None], axis=-1
+        )[..., 0]
+        return jnp.where(valid, lse - chosen, 0.0), lf, lse
+
+
 def _position_nll(
     logits: jax.Array,  # (..., vocab), any float dtype
     labels: jax.Array,  # (...)
@@ -66,15 +86,76 @@ def _position_nll(
     ``(..., vocab)`` f32 array.  THE loss math shared by the dense and
     chunked CE paths — the chunked path's value-identity guarantee
     depends on both calling exactly this."""
-    # not a flax method, so it has no scope of its own: name it for the
-    # profiler's layer table (utils/profiling.py STAGES)
-    with jax.named_scope("loss/nll"):
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        chosen = jnp.take_along_axis(
-            lf, jnp.where(valid, labels, 0)[..., None], axis=-1
-        )[..., 0]
-        return jnp.where(valid, lse - chosen, 0.0)
+    return _nll_parts(logits, labels, valid)[0]
+
+
+def _head_logits(x_c: jax.Array, kernel: jax.Array, dtype):
+    """One chunk's logits as ``nn.Dense(use_bias=False, dtype=dtype)``
+    makes them (both operands in the compute dtype, no wider output), and
+    the two operands as they entered the product."""
+    x_c, w = promote_dtype(x_c, kernel, dtype=dtype)
+    logits = lax.dot_general(x_c, w, (((x_c.ndim - 1,), (0,)), ((), ())))
+    return logits, x_c, w
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_nll_sum(
+    xs: jax.Array,  # (nc, ..., c, dim) chunked features
+    kernel: jax.Array,  # (dim, vocab) the head's parameter
+    labels: jax.Array,  # (nc, ..., c)
+    valid: jax.Array,  # (nc, ..., c) bool
+    dtype,  # the head's compute dtype (None: the operands' own)
+) -> jax.Array:
+    """Summed nll of every chunk: a scan with one product a chunk.  This
+    body is the call that is not differentiated; under ``jax.grad`` the
+    rules below run instead and make the gradient in the same scan."""
+
+    def step(total, inp):
+        x_c, lab_c, val_c = inp
+        logits, _, _ = _head_logits(x_c, kernel, dtype)
+        return total + _position_nll(logits, lab_c, val_c).sum(), None
+
+    total, _ = lax.scan(step, jnp.float32(0.0), (xs, labels, valid))
+    return total
+
+
+def _chunked_nll_sum_fwd(xs, kernel, labels, valid, dtype):
+    """The loss and, chunk by chunk from the logits it already holds, the
+    whole gradient: ``dlogits = (softmax - onehot) * valid`` depends on
+    nothing but the chunk, so ``dx`` is a stacked output and ``dW`` a
+    float32 carry.  Three vocabulary-sized products a chunk."""
+    lead = tuple(range(xs.ndim - 2))  # a chunk's axes but the features'
+
+    def step(carry, inp):
+        total, dw = carry
+        x_c, lab_c, val_c = inp
+        logits, x_c, w = _head_logits(x_c, kernel, dtype)
+        nll, lf, lse = _nll_parts(logits, lab_c, val_c)
+        with jax.named_scope("loss/grad"):
+            onehot = lab_c[..., None] == jnp.arange(lf.shape[-1])
+            dlogits = jnp.where(
+                val_c[..., None], jnp.exp(lf - lse[..., None]) - onehot, 0.0)
+            # rounded where autodiff rounds the cotangent of the logits
+            dlogits = dlogits.astype(logits.dtype)
+            dx_c = lax.dot_general(
+                dlogits, w, (((dlogits.ndim - 1,), (1,)), ((), ())))
+            dw = dw + lax.dot_general(
+                x_c, dlogits, ((lead, lead), ((), ())),
+                preferred_element_type=jnp.float32)
+        return (total + nll.sum(), dw), dx_c.astype(xs.dtype)
+
+    init = (jnp.float32(0.0), jnp.zeros(kernel.shape, jnp.float32))
+    (total, dw), dx = lax.scan(step, init, (xs, labels, valid))
+    return total, (dx, dw.astype(kernel.dtype))
+
+
+def _chunked_nll_sum_bwd(dtype, residuals, g):
+    dx, dw = residuals
+    # integer labels and the boolean mask take no cotangent
+    return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), None, None
+
+
+_chunked_nll_sum.defvjp(_chunked_nll_sum_fwd, _chunked_nll_sum_bwd)
 
 
 class RingTransformer(nn.Module):
@@ -146,10 +227,10 @@ class RingTransformer(nn.Module):
     # collectives (pinned: analysis/contracts.py "blockwise_ffn" row).
     # None = dense FFN; shard lengths that don't divide are padded.
     ff_chunk_size: int | None = None
-    # chunked cross-entropy: compute the loss as a rematted lax.scan over
-    # sequence chunks of this size, so at most (b, chunk, vocab) logits
-    # ever materialize — at a real LM vocab the full logits tensor is the
-    # long-context memory wall.  None = single dense logits+CE (fine for
+    # chunked cross-entropy: compute the loss as a lax.scan over sequence
+    # chunks of this size (it makes its own gradient: _chunked_nll_sum),
+    # so at most (b, chunk, vocab) logits ever materialize — at a real LM
+    # vocab the full logits tensor is the long-context memory wall.  None = single dense logits+CE (fine for
     # small vocab).  The full memory story (why, when, and how this
     # composes with ff_chunk_size / remat_policy / offload) lives in
     # docs/memory.md.
@@ -538,13 +619,19 @@ class RingTransformer(nn.Module):
         valid: jax.Array,  # (b, n) bool, from _valid_labels, same layout
         shards: int = 1,  # sequence shards the features arrive in
     ) -> jax.Array:
-        """Cross-entropy as a rematted scan over sequence chunks.
+        """Cross-entropy as a scan over sequence chunks that makes its own
+        gradient (``_chunked_nll_sum``).
 
-        Peak memory is one chunk's logits ``(b, chunk, vocab)`` per device
-        — forward AND backward (the remat recomputes each chunk's
-        projection in the grad pass; dW accumulates across scan steps).
+        Peak memory is one chunk's logits ``(b, chunk, vocab)`` per device.
+        Undifferentiated, the scan is one product a chunk.  Under
+        ``jax.grad`` the same scan also makes ``dx`` (stacked) and the
+        head's ``dW`` (a float32 carry) from the logits it holds, so
+        nothing is recomputed and the backward only scales both by the
+        incoming cotangent: three vocabulary-sized products a chunk.
         Value-identical to the dense path (same f32 lse-minus-chosen per
-        position).
+        position).  A ``jax.custom_vjp``: forward-mode differentiation
+        (``jax.jvp``, a Hessian) through the chunked loss is refused; the
+        dense path (``loss_chunk_size=None``) takes it.
 
         Chunks are taken WITHIN each sequence shard, as
         ``FeedForward._chunked`` takes them: ``(b, n, d) -> (nc, b,
@@ -585,25 +672,13 @@ class RingTransformer(nn.Module):
                 )
             return a
 
-        xs = (chunks(x), chunks(labels), chunks(valid, value=False))
-
-        def body(mdl, carry, inp):
-            x_c, lab_c, val_c = inp
-            nll = _position_nll(mdl.to_logits(x_c), lab_c, val_c)
-            s, cnt = carry
-            return (s + nll.sum(), cnt + val_c.sum()), None
-
-        scan = nn.scan(
-            nn.remat(body, prevent_cse=False),
-            variable_broadcast="params",
-            split_rngs={"params": False},
-            in_axes=0,
-            out_axes=0,
-        )
-        (total, count), _ = scan(
-            self, (jnp.float32(0.0), jnp.int32(0)), xs
-        )
-        return total / jnp.maximum(count, 1)
+        if self.is_initializing():
+            self.to_logits(x[:, :1])  # makes the head's parameter, named as ever
+        kernel = self.to_logits.variables["params"]["kernel"]
+        total = _chunked_nll_sum(
+            chunks(x), kernel, chunks(labels), chunks(valid, value=False),
+            self.dtype)
+        return total / jnp.maximum(valid.sum(), 1)
 
     # ------------------------------------------------------------------
     # Incremental decoding

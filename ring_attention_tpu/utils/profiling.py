@@ -378,6 +378,9 @@ STAGES: list[tuple[str, str, str, str, str | None]] = [
     ("train/optimizer", "optimizer update", _C, "optimizer", "update"),
     ("train/clip", "gradient clip and guard", _C, "optimizer", "update"),
     ("train/accumulate", "gradient accumulation", _C, "optimizer", "update"),
+    # ahead of the method it sits in: the chunked loss's gradient products,
+    # made in its forward scan, are a stage of their own
+    ("loss/grad", "loss gradient", _C, _L, None),
     ("_chunked_ce", "loss", _C, _L, None),
     ("loss/nll", "loss", _C, _L, None),
     ("_valid_labels", "loss", _C, _L, None),

@@ -298,9 +298,14 @@ def test_train_step_every_pass_appears(train_capture):
     assert {r["pass"] for r in rows if r["layer"] not in ("idle", "other")
             } == {"forward", "recompute", "backward", "update"}
     cells = {(r["layer"], r["pass"]) for r in rows if r["ms"] > 0}
-    for layer in ("feed-forward", "attention projections", "loss and head"):
+    for layer in ("feed-forward", "attention projections"):
         assert {(layer, "forward"), (layer, "recompute"),
                 (layer, "backward")} <= cells
+    # the chunked cross-entropy makes its gradient in its forward scan:
+    # nothing of it is recomputed
+    assert {("loss and head", "forward"),
+            ("loss and head", "backward")} <= cells
+    assert ("loss and head", "recompute") not in cells
     assert ("optimizer", "update") in cells
     assert ("embed", "forward") in cells and ("embed", "backward") in cells
     # save_attn keeps the attention output: flash runs forward and backward
@@ -317,8 +322,9 @@ def test_train_step_every_pass_appears(train_capture):
     ("train/optimizer", "optimizer", {"update"}),
     ("train/clip", "optimizer", {"update"}),
     ("train/accumulate", "optimizer", {"update"}),
-    ("_chunked_ce", "loss and head", {"forward", "recompute", "backward"}),
-    ("loss/nll", "loss and head", {"forward", "recompute", "backward"}),
+    ("_chunked_ce", "loss and head", {"forward", "backward"}),
+    ("loss/nll", "loss and head", {"forward"}),
+    ("loss/grad", "loss and head", {"forward"}),
     ("final_norm", "loss and head", {"forward", "backward"}),
     ("ff_layers_", "feed-forward", {"forward", "recompute", "backward"}),
 ])

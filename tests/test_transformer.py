@@ -366,7 +366,7 @@ def _ce_mesh(layout):
     pytest.param(4, "data2-seq4", 65, None, id="data2-seq4"),
 ])
 def test_chunked_ce_matches_dense(rng, chunk, layout, seq_len, extra):
-    """loss_chunk_size: the rematted chunk-scan loss (and its gradients)
+    """loss_chunk_size: the chunk-scan loss (and the gradients it makes)
     equals the dense logits+CE path — including a chunk size that doesn't
     divide the sequence, one larger than the sequence (clamped), an
     ignore_index tail, and every sequence-parallel layout, where the
@@ -425,6 +425,166 @@ def test_chunked_ce_program_does_not_materialize_logits(rng):
     )(params)
     full = f"1,{n},{VOCAB}"
     assert full not in str(jaxpr), f"found full-logits shape ({full})"
+
+
+def _vocab_products(closed_jaxpr, vocab):
+    """Every ``dot_general`` of a traced program with a vocabulary-sized
+    operand or result, scan bodies and the other sub-programs included."""
+    from ring_attention_tpu.analysis.contracts import _sub_jaxprs
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general" and any(
+                    vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars)):
+                found.append(eqn)
+            for value in eqn.params.values():
+                for sub in _sub_jaxprs(value):
+                    walk(sub)
+
+    walk(closed_jaxpr.jaxpr)
+    return found
+
+
+def _small_model(**kw):
+    return RingTransformer(
+        num_tokens=VOCAB, dim=32, depth=1, heads=2, dim_head=16, causal=True,
+        bucket_size=8, use_ring=False, **kw)
+
+
+@pytest.mark.parametrize("differentiated,products", [(False, 1), (True, 3)])
+def test_chunked_ce_products_a_chunk(rng, differentiated, products):
+    """The chunk scan makes its own gradient: the differentiated program
+    holds three vocabulary-sized products (logits, dx, dW), all in the one
+    scan body, and nothing recomputes the logits; the undifferentiated one
+    holds the logits' product and no gradient product."""
+    model = _small_model(loss_chunk_size=8)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (2, 33)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+
+    def loss(p):
+        return model.apply(p, tokens, return_loss=True)
+
+    program = jax.value_and_grad(loss) if differentiated else loss
+    found = _vocab_products(jax.make_jaxpr(program)(params), VOCAB)
+    assert len(found) == products, found
+    results = sorted(eqn.outvars[0].aval.shape for eqn in found)
+    logits, dx, dw = (2, 8, VOCAB), (2, 8, 32), (32, VOCAB)
+    assert results == ([dx, logits, dw] if differentiated else [logits])
+
+
+@pytest.mark.parametrize("case", ["scaled-cotangent", "no-valid-label"])
+def test_chunked_ce_backward_rule(rng, case):
+    """The backward rule is a multiplication by the incoming cotangent:
+    ``grad(3 * loss) = 3 * grad(loss)``; and a batch with no valid label
+    gives zero gradients and nothing non-finite."""
+    model = _small_model(loss_chunk_size=8)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (2, 33)), jnp.int32)
+    tokens = tokens.at[0, 20:].set(-1)  # ignore_index tail in row 0
+    params = model.init(jax.random.PRNGKey(0), jnp.abs(tokens))
+    scale, call = 3.0, {}
+    if case == "no-valid-label":
+        call["example_mask"] = jnp.asarray([False, False])
+
+    def loss(p, k=1.0):
+        return k * model.apply(p, tokens, return_loss=True, **call)
+
+    value, grads = jax.jit(jax.value_and_grad(loss))(params)
+    scaled = jax.jit(jax.grad(lambda p: loss(p, scale)))(params)
+    for g, gs in zip(jax.tree.leaves(grads), jax.tree.leaves(scaled)):
+        assert bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(gs, scale * g, rtol=1e-5, atol=1e-6)
+        if case == "no-valid-label":
+            assert not np.asarray(g).any()
+    if case == "no-valid-label":
+        assert float(value) == 0.0
+    else:
+        head = grads["params"]["to_logits"]["kernel"]
+        assert float(jnp.abs(head).max()) > 0
+
+
+def test_chunked_ce_bfloat16_gradients(rng):
+    """bfloat16 compute: ``dx`` and ``dW`` of the in-scan gradient sit
+    inside bfloat16 rounding of autodiff's on the dense path, and ``dW``
+    (accumulated in float32 across the chunks) is no further from a
+    float32 reference than autodiff of the chunk scan was, which rounds
+    each chunk's product to bfloat16 before the float32 sum."""
+    from ring_attention_tpu.models.transformer import (
+        _chunked_nll_sum,
+        _head_logits,
+        _position_nll,
+    )
+
+    nc, b, c, dim = 8, 2, 16, 32
+    xs = jnp.asarray(rng.standard_normal((nc, b, c, dim)), jnp.bfloat16)
+    kernel = jnp.asarray(rng.standard_normal((dim, VOCAB)) * dim ** -0.5,
+                         jnp.float32)
+    labels = jnp.asarray(rng.integers(0, VOCAB, (nc, b, c)), jnp.int32)
+    valid = jnp.asarray(rng.random((nc, b, c)) < 0.8)
+
+    def dense(dtype):
+        def total(x, w):
+            x = x if dtype else x.astype(jnp.float32)
+            return _position_nll(
+                _head_logits(x, w, dtype)[0], labels, valid).sum()
+        return total
+
+    def grads(fn):
+        dx, dw = jax.jit(jax.grad(fn, argnums=(0, 1)))(xs, kernel)
+        return np.asarray(dx, np.float32), np.asarray(dw, np.float32)
+
+    bf16 = jnp.bfloat16
+    dx, dw = grads(lambda x, w: _chunked_nll_sum(x, w, labels, valid, bf16))
+    # the undecorated function: the same scan, differentiated by autodiff
+    dx_scan, dw_scan = grads(
+        lambda x, w: _chunked_nll_sum.fun(x, w, labels, valid, bf16))
+    dx_dense, dw_dense = grads(dense(bf16))
+    _, dw_f32 = grads(dense(None))
+
+    def assert_close(got, want, ulps):
+        # bfloat16 keeps 8 bits: half an ulp is 2 ** -9 of the value
+        np.testing.assert_allclose(
+            got, want, rtol=ulps * 2.0 ** -8,
+            atol=ulps * 2.0 ** -8 * np.abs(want).max())
+
+    assert_close(dx, dx_dense, 4)
+    assert_close(dw, dw_dense, 4)
+    assert_close(dx, dx_scan, 1)  # rounded at the same place, chunk by chunk
+
+    def off(a):
+        return float(np.linalg.norm(a - dw_f32) / np.linalg.norm(dw_f32))
+
+    assert off(dw) <= off(dw_scan), (off(dw), off(dw_scan))
+    assert off(dw) <= off(dw_dense) * 1.05, (off(dw), off(dw_dense))
+
+
+def test_chunked_ce_gradient_accumulation(rng):
+    """``make_train_step(accum_steps=2)`` over the chunked loss gives the
+    update of ``accum_steps=1`` (plain SGD at rate 1: the update is the
+    gradient), and both the dense path's."""
+    import optax
+
+    from ring_attention_tpu.utils import make_train_step
+
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (4, 33)), jnp.int32)
+    chunked, dense = _small_model(loss_chunk_size=8), _small_model()
+    params = dense.init(jax.random.PRNGKey(0), tokens)
+    opt = optax.sgd(1.0)
+
+    def update(model, accum):
+        step = jax.jit(make_train_step(
+            lambda p, t: model.apply(p, t, return_loss=True), opt,
+            accum_steps=accum))
+        new, _, loss = step(params, opt.init(params), tokens)
+        return loss, jax.tree.map(lambda a, b: a - b, params, new)
+
+    loss_1, grad_1 = update(chunked, 1)
+    for model, accum in ((chunked, 2), (dense, 1)):
+        loss, grad = update(model, accum)
+        np.testing.assert_allclose(loss, loss_1, rtol=2e-6)
+        for a, b in zip(jax.tree.leaves(grad), jax.tree.leaves(grad_1)):
+            np.testing.assert_allclose(a, b, atol=5e-6)
 
 
 def _hlo_shapes(lines):
