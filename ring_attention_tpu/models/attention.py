@@ -183,6 +183,10 @@ class RingAttention(nn.Module):
     # attention output before to_out
     out_gate: bool = False
     norm_eps: float = 1e-12
+    # the softmax scale, where it is not ``dim_head ** -0.5`` (which every
+    # kernel and oracle applies): the ratio of the two is folded into the
+    # queries once, at ``_project_qkv``'s end, so it reaches every path
+    softmax_scale: float | None = None
     dtype: jnp.dtype | None = None
 
     def setup(self):
@@ -371,6 +375,8 @@ class RingAttention(nn.Module):
         if self.out_gate:
             with jax.named_scope("attn/gate"):
                 gate = self.to_gate(normed)
+        if self.softmax_scale is not None:
+            q = q * jnp.asarray(self.softmax_scale * dh ** 0.5, q.dtype)
         return q, k, v, gate
 
     def _project_out(self, out: jax.Array, gate: jax.Array | None):
@@ -1089,10 +1095,11 @@ class LatentAttention(RingAttention):
     rope_scaling: YarnScaling | None = None
 
     def setup(self):
-        if self.quantize_cache or self.qk_norm or self.out_gate:
+        if (self.quantize_cache or self.qk_norm or self.out_gate
+                or self.softmax_scale is not None):
             raise ValueError(
                 "LatentAttention: a latent layer has no int8 cache, per-head "
-                "q/k norm or output gate")
+                "q/k norm, output gate or softmax_scale")
         h = self.heads
 
         def dense(width):

@@ -3,9 +3,10 @@ stack from.
 
 One ``ModelConfig`` says everything about the block that the arithmetic
 depends on: the widths, one ``LayerConfig`` per layer (its attention
-window, whether it rotates, which feed-forward it has), the norms and the
-routed experts.  How the stack is *run* (mesh, kernels, remat, chunking)
-stays with ``RingTransformer``'s own options and is not here.
+window, whether it rotates, which mixer and which feed-forward it has),
+the norms, the multipliers and the routed experts.  How the stack is *run*
+(mesh, kernels, remat, chunking) stays with ``RingTransformer``'s own
+options and is not here.
 
 ``RingTransformer.from_config(config, **options)`` is the constructor.
 The older keyword constructor (``num_tokens, dim, depth, heads, ...``)
@@ -21,6 +22,8 @@ from dataclasses import dataclass
 from ..ops.rotary import YarnScaling
 
 FFN_KINDS = ("gelu", "gated", "routed")
+MIXERS = ("attention", "mamba")
+ROUTER_SCORES = ("sigmoid", "softmax")
 
 
 @dataclass(frozen=True)
@@ -30,6 +33,10 @@ class LayerConfig:
     window: int | None = None
     rotary: bool = True  # False: no positional encoding in this layer
     ffn: str = "gelu"  # one of FFN_KINDS
+    # what stands where the attention module stands: "mamba" is a Mamba-2
+    # state-space mixer (models/ssm.py), whose cache entry is a state and
+    # not rows; ``window`` and ``rotary`` say nothing about such a layer
+    mixer: str = "attention"  # one of MIXERS
 
 
 @dataclass(frozen=True)
@@ -73,6 +80,25 @@ class ModelConfig:
     qk_rope_dim: int = 0
     v_dim: int = 0
     rope_scaling: YarnScaling | None = None
+    # the Mamba-2 mixer of the "mamba" layers (all six or none): ``ssm_heads``
+    # heads of ``ssm_head_dim`` channels, each with a ``ssm_head_dim`` x
+    # ``ssm_state`` float32 state; B and C shared by the heads of a group;
+    # ``ssm_conv`` taps of causal depthwise convolution; the chunked form
+    # hands the state on every ``ssm_chunk`` positions
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 0
+    # each sub-block's output is multiplied by it before the residual add,
+    # the logits by ``logit_scale``; 1.0: not at all
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    softmax_scale: float | None = None  # None: dim_head ** -0.5
+    tie_embeddings: bool = False  # the head is the embedding, transposed
+    # "softmax": weights are the softmax over the chosen logits, no bias
+    router_score: str = "sigmoid"  # one of ROUTER_SCORES
 
     def __post_init__(self):
         for i, layer in enumerate(self.layers):
@@ -80,6 +106,24 @@ class ModelConfig:
                 raise ValueError(
                     f"ModelConfig: layer {i} has ffn {layer.ffn!r}; the "
                     f"kinds are {', '.join(FFN_KINDS)}")
+            if layer.mixer not in MIXERS:
+                raise ValueError(
+                    f"ModelConfig: layer {i} has mixer {layer.mixer!r}; the "
+                    f"mixers are {', '.join(MIXERS)}")
+        if self.router_score not in ROUTER_SCORES:
+            raise ValueError(
+                f"ModelConfig: router_score {self.router_score!r}; the "
+                f"score functions are {', '.join(ROUTER_SCORES)}")
+        ssm = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+               self.ssm_groups, self.ssm_conv, self.ssm_chunk)
+        mamba = any(layer.mixer == "mamba" for layer in self.layers)
+        if (any(ssm) or mamba) and not (
+                all(w > 0 for w in ssm) and self.ssm_groups == 1):
+            raise ValueError(
+                f"ModelConfig: a Mamba-2 mixer needs all of ssm_heads, "
+                f"ssm_head_dim, ssm_state, ssm_groups, ssm_conv and ssm_chunk "
+                f"(got {ssm}), and ssm_groups = 1 until a configuration "
+                f"needs another")
         if self.heads % self.kv_heads:
             raise ValueError(
                 f"ModelConfig: {self.heads} heads do not group over "
@@ -261,5 +305,56 @@ def _dots_vlm(d: dict) -> dict:
         expert_groups=d["n_group"], groups_per_token=d["topk_group"])
 
 
+def _granitemoehybrid(d: dict) -> dict:
+    """Granite 4.0-H (after transformers' ``modeling_granitemoehybrid.py``,
+    whose mixer is Bamba's Mamba-2 layer): ``layer_types`` says which layers
+    are Mamba-2 mixers and which grouped-query attention without positional
+    encoding and with the softmax scale ``attention_multiplier``; every
+    layer's feed-forward is softmax-routed experts plus a shared one, both
+    gated SiLU; plain pre-norm residuals whose sub-block outputs are
+    multiplied by ``residual_multiplier``; the embedding by
+    ``embedding_multiplier``; the tied head's logits divided by
+    ``logits_scaling``.  Where the file holds a chip's share,
+    ``num_local_experts`` counts the experts held here,
+    ``published.num_local_experts`` the router's width and ``first_expert``
+    the first one held."""
+    kinds = d["layer_types"]
+    if len(kinds) != d["num_hidden_layers"] or set(kinds) - set(MIXERS):
+        raise ValueError(
+            f"ModelConfig: {len(kinds)} layer_types ({sorted(set(kinds))}) "
+            f"for {d['num_hidden_layers']} num_hidden_layers of "
+            f"{', '.join(MIXERS)}")
+    if (d.get("position_embedding_type") != "nope" or d.get("mamba_proj_bias")
+            or not d.get("mamba_conv_bias") or d.get("attention_bias")
+            or d.get("normalization_function", "rmsnorm") != "rmsnorm"
+            or d.get("hidden_act") != "silu"
+            or d["mamba_expand"] * d["hidden_size"]
+            != d["mamba_n_heads"] * d["mamba_d_head"]):
+        raise ValueError(
+            "ModelConfig: the granitemoehybrid block is written for no "
+            "positional encoding (nope), RMSNorm, SiLU, a convolution with a "
+            "bias, projections without, and mamba_expand x hidden_size = "
+            "mamba_n_heads x mamba_d_head")
+    return dict(
+        layers=tuple(LayerConfig(rotary=False, ffn="routed", mixer=kind)
+                     for kind in kinds),
+        norm_eps=d["rms_norm_eps"],
+        embed_scale=float(d["embedding_multiplier"]),
+        residual_scale=float(d["residual_multiplier"]),
+        logit_scale=1.0 / d["logits_scaling"],
+        softmax_scale=float(d["attention_multiplier"]),
+        tie_embeddings=bool(d["tie_word_embeddings"]),
+        ssm_heads=d["mamba_n_heads"], ssm_head_dim=d["mamba_d_head"],
+        ssm_state=d["mamba_d_state"], ssm_groups=d["mamba_n_groups"],
+        ssm_conv=d["mamba_d_conv"], ssm_chunk=d["mamba_chunk_size"],
+        router_score="softmax",
+        num_experts=d.get("published", d)["num_local_experts"],
+        experts_per_token=d["num_experts_per_tok"],
+        expert_dim=d["intermediate_size"],
+        shared_expert_dim=d["shared_intermediate_size"],
+        first_expert=d.get("first_expert", 0),
+        experts_held=d["num_local_experts"])
+
+
 _FAMILIES = {"starcoder2": _starcoder2, "afmoe": _afmoe,
-             "dots_vlm": _dots_vlm}
+             "dots_vlm": _dots_vlm, "granitemoehybrid": _granitemoehybrid}

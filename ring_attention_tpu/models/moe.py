@@ -8,9 +8,12 @@ and never the weights; with ``expert_groups`` > 1 only inside the
 scores sum highest), normalises the chosen scores over all of the
 chosen, and this holder computes the ``experts_held`` consecutive experts
 from ``first_expert`` for the (token, expert) pairs that fell on them,
-added to the shared expert's output.  What the absent experts would have
-added is left out: on one chip the layer runs without its exchange, and
-nothing stands in for the other holders.
+added to the shared expert's output.  With ``router_score="softmax"`` the
+scores are the softmax of the router's logits and there is no bias:
+normalised over the chosen, the weights are the softmax over the chosen
+logits.  What the absent experts would have added is left out: on one chip
+the layer runs without its exchange, and nothing stands in for the other
+holders.
 
 No pair is dropped and there is no capacity factor.  The pairs are sorted
 by expert and go through ``lax.ragged_dot`` (on the TPU XLA lowers it to
@@ -18,10 +21,15 @@ a grouped matrix product that visits only the groups that have rows, so
 an expert no token chose reads no weights; measured against the other
 forms in CHANGES.md, PR 28) in passes of at most ``PASS_ROWS`` rows: a
 prefill whose pairs fall an eighth on this holder takes one pass, and the
-worst case, every pair here, takes more passes and not more memory.
+worst case, every pair here, takes more passes and not more memory.  A
+holder that expects more pairs than a pass takes (half of 72 experts held
+and ten a token: 163,840 of a 32,768-token prefill's) takes its tokens a
+block at a time, each block one pass (``_blocks``).
 """
 
 from __future__ import annotations
+
+import functools
 
 import flax.linen as nn
 import jax
@@ -50,6 +58,7 @@ class RoutedFeedForward(nn.Module):
     dtype: jnp.dtype | None = None
     expert_groups: int = 1  # 1: the choice is over all experts
     groups_per_token: int = 1
+    router_score: str = "sigmoid"  # or "softmax": no selection bias
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -60,8 +69,9 @@ class RoutedFeedForward(nn.Module):
                                             batch_axis=(0,))
         router = self.param("router", nn.initializers.lecun_normal(),
                             (d, self.num_experts))
-        bias = self.param("expert_bias", nn.initializers.zeros,
-                          (self.num_experts,))
+        softmax = self.router_score == "softmax"
+        bias = 0.0 if softmax else self.param(
+            "expert_bias", nn.initializers.zeros, (self.num_experts,))
         gate_up = self.param("experts_gate_up", init, (held, d, 2 * f))
         down = self.param("experts_down", init, (held, f, d))
         dtype = self.dtype or m.dtype
@@ -69,16 +79,22 @@ class RoutedFeedForward(nn.Module):
         with jax.named_scope("moe/router"):
             # float32 at full precision: it is 256 columns wide, and a
             # rounded score turns a choice that is near a tie
-            scores = jax.nn.sigmoid(jnp.dot(
+            logits = jnp.dot(
                 m.astype(jnp.float32), router.astype(jnp.float32),
-                precision=lax.Precision.HIGHEST))
-            _, chosen = lax.top_k(self._eligible(scores + bias),
-                                  self.experts_per_token)
+                precision=lax.Precision.HIGHEST)
+            scores = (jax.nn.softmax(logits, axis=-1) if softmax
+                      else jax.nn.sigmoid(logits))
+            _, chosen = lax.top_k(
+                self._eligible(scores if softmax else scores + bias),
+                self.experts_per_token)
             top = jnp.take_along_axis(scores, chosen, axis=-1)
             weights = self.route_scale * top / (
                 top.sum(-1, keepdims=True) + 1e-20)
-        out = self._routed(m.astype(dtype), chosen, weights,
-                           gate_up.astype(dtype), down.astype(dtype))
+        blocks = self._blocks(b * n)
+        routed = self._routed if blocks == 1 else functools.partial(
+            self._routed_in_blocks, blocks)
+        out = routed(m.astype(dtype), chosen, weights,
+                     gate_up.astype(dtype), down.astype(dtype))
         if self.shared_dim:
             with jax.named_scope("moe/shared"):
                 out = out + GatedFeedForward(
@@ -102,9 +118,62 @@ class RoutedFeedForward(nn.Module):
             return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(
                 tokens, experts)
 
-    def _routed(self, m, chosen, weights, gate_up, down):
+    def _count(self, chosen, counts, n_here):
+        """Sows a call's counters: free unless the caller asks,
+        ``apply(..., mutable=["counters"])``."""
+        pairs = chosen.size
+        counters = [("tokens_per_expert", counts),
+                    ("held_share", n_here / pairs),
+                    ("experts_touched", jnp.sum(counts > 0))]
+        if self.expert_groups > 1:  # every holder's pairs, by group
+            group = chosen.reshape(pairs) // (
+                self.num_experts // self.expert_groups)
+            counters.append(("pairs_per_group", jnp.sum(
+                group[:, None] == jnp.arange(self.expert_groups), axis=0,
+                dtype=jnp.int32)))
+        for name, value in counters:
+            if not self.is_initializing():
+                self.sow(COUNTERS, name, value, init_fn=lambda: None,
+                         reduce_fn=lambda _, new: new)
+
+    def _blocks(self, tokens: int) -> int:
+        """How many blocks the routed part takes a call's tokens in: one
+        (all of them, in passes) unless this holder expects more pairs than
+        a pass takes; then the fewest, a power of two that divides the
+        tokens, whose expected pairs leave a fifth of a pass to spare."""
+        expected = (tokens * self.experts_per_token * self.experts_held
+                    / self.num_experts)
+        blocks = 1
+        while (expected > 0.8 * PASS_ROWS * blocks
+               and tokens % (2 * blocks) == 0):
+            blocks *= 2
+        return blocks
+
+    def _routed_in_blocks(self, blocks, m, chosen, weights, gate_up, down):
+        """``_routed`` a block of tokens at a time.  A pass's combine
+        gathers ``experts_per_token`` rows for every token of its call
+        whichever pass holds them, and XLA:TPU gathers rows at a tenth of
+        the memory's rate: with half the experts held and ten a token the
+        whole call in five or six passes spent two thirds of a 32,768-token
+        prefill there, and a seed's routing decided which (PERF.md section
+        6, PR 34).  A block's pairs fit one pass, so every row is gathered
+        once."""
+        tokens, d = m.shape
+        k, held = self.experts_per_token, self.experts_held
+        local = chosen.reshape(-1) - self.first_expert
+        counts = jnp.sum(
+            jnp.where((local >= 0) & (local < held), local, held)[:, None]
+            == jnp.arange(held), axis=0, dtype=jnp.int32)
+        self._count(chosen, counts, counts.sum())
+        out = lax.map(
+            lambda block: self._routed(*block, gate_up, down, count=False),
+            (m.reshape(blocks, -1, d), chosen.reshape(blocks, -1, k),
+             weights.reshape(blocks, -1, k)))
+        return out.reshape(tokens, d)
+
+    def _routed(self, m, chosen, weights, gate_up, down, count=True):
         """Sum over the held experts a token chose of ``weight x expert(m)``,
-        float32 ``(tokens, dim)``."""
+        float32 ``(tokens, dim)``; ``count``: sow this call's counters."""
         tokens, d = m.shape
         k, held, f = self.experts_per_token, self.experts_held, self.expert_dim
         pairs = tokens * k
@@ -123,20 +192,8 @@ class RoutedFeedForward(nn.Module):
             ends = jnp.cumsum(counts)
             starts, n_here = ends - counts, ends[-1]
             order = jnp.pad(order, (0, passes * rows - pairs))
-        # free unless the caller asks: apply(..., mutable=["counters"])
-        counters = [("tokens_per_expert", counts),
-                    ("held_share", n_here / pairs),
-                    ("experts_touched", jnp.sum(counts > 0))]
-        if self.expert_groups > 1:  # every holder's pairs, by group
-            group = chosen.reshape(pairs) // (
-                self.num_experts // self.expert_groups)
-            counters.append(("pairs_per_group", jnp.sum(
-                group[:, None] == jnp.arange(self.expert_groups), axis=0,
-                dtype=jnp.int32)))
-        for name, value in counters:
-            if not self.is_initializing():
-                self.sow(COUNTERS, name, value, init_fn=lambda: None,
-                         reduce_fn=lambda _, new: new)
+        if count:
+            self._count(chosen, counts, n_here)
 
         def one_pass(lo):
             with jax.named_scope("moe/dispatch"):

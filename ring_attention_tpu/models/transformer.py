@@ -52,6 +52,7 @@ from .config import ModelConfig
 from .layers import FeedForward, GatedFeedForward, RMSNorm
 from .moe import RoutedFeedForward
 from .remat import REMAT_POLICIES, resolve_remat_policy
+from .ssm import Mamba2Mixer
 
 PROBES = "probes"  # the flax collection the stack walker sows into
 
@@ -293,7 +294,9 @@ class RingTransformer(nn.Module):
             v_dim=cfg.v_dim, rope_scaling=cfg.rope_scaling,
         ) if cfg.latent else {}
         attn_classes = [
-            LatentAttention if cfg.latent else RingAttention] * self.depth
+            Mamba2Mixer if layer.mixer == "mamba"
+            else LatentAttention if cfg.latent else RingAttention
+            for layer in cfg.layers]
         ff_classes = [ffn[layer.ffn] for layer in cfg.layers]
         if self.remat:
             def rematted(classes):
@@ -303,7 +306,8 @@ class RingTransformer(nn.Module):
             attn_classes = rematted(attn_classes)
             ff_classes = rematted(ff_classes)
         self.attn_layers = [
-            attn_cls(
+            self._state_space(attn_cls, cfg) if layer.mixer == "mamba"
+            else attn_cls(
                 dim=self.dim,
                 heads=self.heads,
                 dim_head=self.dim_head,
@@ -334,6 +338,7 @@ class RingTransformer(nn.Module):
                 out_gate=cfg.attn_gate,
                 norm_eps=cfg.norm_eps,
                 dtype=self.dtype,
+                softmax_scale=cfg.softmax_scale,
                 **latent,
             )
             for attn_cls, layer, layer_mask in zip(
@@ -351,7 +356,17 @@ class RingTransformer(nn.Module):
         self.post_ff_norms = [
             RMSNorm(self.dim, cfg.norm_eps) for _ in range(post)]
         self.final_norm = RMSNorm(self.dim, cfg.norm_eps)
-        self.to_logits = nn.Dense(self.num_tokens, use_bias=False, dtype=self.dtype)
+        if not cfg.tie_embeddings:  # a tied head is the embedding: _logits
+            self.to_logits = nn.Dense(
+                self.num_tokens, use_bias=False, dtype=self.dtype)
+
+    def _state_space(self, mixer_cls, cfg: ModelConfig):
+        return mixer_cls(
+            dim=self.dim, heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+            state=cfg.ssm_state, conv=cfg.ssm_conv, chunk=cfg.ssm_chunk,
+            norm_eps=cfg.norm_eps, use_ring=self.use_ring,
+            force_regular_attn=self.force_regular_attn, mesh=self.mesh,
+            use_pallas=self.use_pallas, impl=self.impl, dtype=self.dtype)
 
     def _feed_forward(self, ff_cls, kind: str, cfg: ModelConfig):
         if kind == "gelu":
@@ -371,7 +386,8 @@ class RingTransformer(nn.Module):
             shared_dim=cfg.shared_expert_dim, route_scale=cfg.route_scale,
             norm_eps=cfg.norm_eps, dtype=self.dtype,
             expert_groups=cfg.expert_groups,
-            groups_per_token=cfg.groups_per_token)
+            groups_per_token=cfg.groups_per_token,
+            router_score=cfg.router_score)
 
     def _ring_size(self) -> int:
         """Total sequence-parallel world (both axes of a factored mesh)."""
@@ -384,6 +400,16 @@ class RingTransformer(nn.Module):
         scale = self._config().embed_scale
         return x if scale == 1.0 else x * jnp.asarray(scale, x.dtype)
 
+    def _logits(self, x: jax.Array) -> jax.Array:
+        """The head: its own matrix, or the embedding's transpose where the
+        configuration ties them, times ``logit_scale``."""
+        cfg = self._config()
+        logits = self.embed.attend(x) if cfg.tie_embeddings else (
+            self.to_logits(x))
+        scale = cfg.logit_scale
+        return logits if scale == 1.0 else logits * jnp.asarray(
+            scale, logits.dtype)
+
     def _blocks(self, x: jax.Array, attend) -> jax.Array:
         """The one walk over the stack, for the forward, the prefill and
         the decode step alike: ``attend(i, attn, x)`` is layer ``i``'s
@@ -392,15 +418,21 @@ class RingTransformer(nn.Module):
         Each layer's attention output (what the kernels and the cache
         made, before any norm) is sown as ``probes/attn_out_<i>``: free
         unless the caller asks, ``apply(..., mutable=["probes"])``."""
-        sandwich = self._config().sandwich_norm
+        cfg = self._config()
+        sandwich = cfg.sandwich_norm
+
+        def scaled(y):  # a sub-block's output on its way to the residual
+            return y if cfg.residual_scale == 1.0 else y * jnp.asarray(
+                cfg.residual_scale, y.dtype)
+
         for i, (attn, ff) in enumerate(zip(self.attn_layers, self.ff_layers)):
             a = attend(i, attn, x)
             if not self.is_initializing():
                 self.sow(PROBES, f"attn_out_{i}", a, init_fn=lambda: None,
                          reduce_fn=lambda _, new: new)
-            x = (self.post_attn_norms[i](a) if sandwich else a) + x
+            x = scaled(self.post_attn_norms[i](a) if sandwich else a) + x
             f = ff(x)
-            x = (self.post_ff_norms[i](f) if sandwich else f) + x
+            x = scaled(self.post_ff_norms[i](f) if sandwich else f) + x
         return self.final_norm(x)
 
     def _cached_blocks(self, x, cache, attend):
@@ -581,7 +613,7 @@ class RingTransformer(nn.Module):
                 valid = layout_permute(valid, scheme, factor)
             return self._chunked_ce(x, labels, valid, shards)
 
-        logits = self.to_logits(x)
+        logits = self._logits(x)
 
         if ring > 1 and self.auto_shard:
             logits = layout_unpermute(logits, scheme, factor)
@@ -672,6 +704,12 @@ class RingTransformer(nn.Module):
                 )
             return a
 
+        cfg = self._config()
+        if cfg.tie_embeddings or cfg.logit_scale != 1.0:
+            raise NotImplementedError(
+                "RingTransformer: the chunked cross-entropy takes the head's "
+                "own matrix; a tied or scaled head trains with "
+                "loss_chunk_size=None")
         if self.is_initializing():
             self.to_logits(x[:, :1])  # makes the head's parameter, named as ever
         kernel = self.to_logits.variables["params"]["kernel"]
@@ -694,7 +732,10 @@ class RingTransformer(nn.Module):
         transposed, and its latents (``LatentAttention``): ``k`` is
         ``(batch, 1, qk_rope_dim, max_len)`` and ``v`` ``(batch, 1, max_len,
         kv_latent_dim)``, ``kv_latent_dim + qk_rope_dim`` values a position,
-        and no expanded key or value."""
+        and no expanded key or value.  A state-space layer's pair does not
+        grow with ``max_len``: ``k`` is the convolution's tail ``(batch, 1,
+        ssm_conv - 1, channels)`` and ``v`` its float32 state ``(batch,
+        ssm_heads, ssm_head_dim, ssm_state)`` (``Mamba2Mixer``)."""
         ring = self._ring_size()
         assert max_len % max(ring, 1) == 0
         if ring > 1 and self.mesh is not None and is_factored(self.mesh):
@@ -706,6 +747,13 @@ class RingTransformer(nn.Module):
         kvh = self.kv_heads or self.heads
         dtype = self.dtype or jnp.float32
         cfg = self._config()
+        mamba = [layer.mixer == "mamba" for layer in cfg.layers]
+        if any(mamba) and (ring > 1 or cfg.latent or self.quantize_cache):
+            raise NotImplementedError(
+                "init_cache: a state-space layer's state is not sharded over "
+                "a sequence mesh yet (ROADMAP R7) and stands beside neither a "
+                "latent nor an int8 cache; decode a hybrid model with "
+                "mesh=None or use_ring=False")
         if cfg.latent:
             if ring > 1:
                 raise NotImplementedError(
@@ -749,9 +797,16 @@ class RingTransformer(nn.Module):
             else min(max_len, layer.window)
             for layer in cfg.layers
         ]
+        # a state-space layer's pair is no rows: the convolution's tail and
+        # one float32 state, whatever ``max_len`` is (models/ssm.py)
+        channels = cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_state
+        tail = (batch, 1, cfg.ssm_conv - 1, channels)
+        state = (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
         return {
-            "k": [make_entry(s) for s in sizes],
-            "v": [make_entry(s) for s in sizes],
+            "k": [jnp.zeros(tail, dtype) if m else make_entry(s)
+                  for m, s in zip(mamba, sizes)],
+            "v": [jnp.zeros(state, jnp.float32) if m else make_entry(s)
+                  for m, s in zip(mamba, sizes)],
         }
 
     def decode_step(
@@ -765,7 +820,7 @@ class RingTransformer(nn.Module):
         x, cache = self._cached_blocks(
             self._embed(token[:, None]), cache,
             lambda attn, x, k, v: attn.decode_step(x, k, v, pos))
-        return self.to_logits(x)[:, 0], cache
+        return self._logits(x)[:, 0], cache
 
     def prefill(
         self,
@@ -779,7 +834,11 @@ class RingTransformer(nn.Module):
         x, cache = self._cached_blocks(
             self._embed(tokens), cache,
             lambda attn, x, k, v: attn.prefill(x, k, v))
-        return self.to_logits(x)[:, -1], cache
+        if self._config().tie_embeddings:
+            # XLA moves the slice through nn.Dense's product by itself and
+            # not through ``attend``'s: (b, n, vocab) logits otherwise
+            x = x[:, -1:]
+        return self._logits(x)[:, -1], cache
 
     def generate(
         self,

@@ -401,6 +401,15 @@ STAGES: list[tuple[str, str, str, str, str | None]] = [
      "latent attention", None),
     ("attn/expand", "latent expanded to heads", _C, "latent attention", None),
     ("attn/absorb", "latent absorbed products", _C, "latent attention", None),
+    # a Mamba-2 mixer's own parts (models/ssm.py); its decode kernel is a
+    # state space row by its name, wherever it is called from
+    ("ssm_decode_step", "state-space decode kernel", _C, "state space", None),
+    ("ssm/in_proj", "state-space input projection", _C, "state space", None),
+    ("ssm/conv", "state-space convolution", _C, "state space", None),
+    ("ssm/scan", "state-space chunked scan", _C, "state space", None),
+    ("ssm/step", "state-space recurrent step", _C, "state space", None),
+    ("ssm/gate_norm", "state-space gate and norm", _C, "state space", None),
+    ("ssm/out_proj", "state-space output projection", _C, "state space", None),
     ("attn/qk_norm", "q/k norm", _C, "attention projections", None),
     ("attn/gate", "attention output gate", _C, "attention projections", None),
     ("post_attn_norms_", "post-attention norm", _C, "attention projections", None),

@@ -379,7 +379,8 @@ def test_a_bad_configuration_is_a_one_line_error():
     "model_type": None, "architectures": ["Qwen3ForCausalLM"]}])
 def test_an_unknown_family_is_an_error_not_another_familys_block(name):
     config = {k: v for k, v in STARCODER2_TOY.items() if k != "model_type"}
-    with pytest.raises(ValueError, match="afmoe, dots_vlm, starcoder2"):
+    with pytest.raises(
+            ValueError, match="afmoe, dots_vlm, granitemoehybrid, starcoder2"):
         ModelConfig.from_dict({**config, **name})
 
 

@@ -193,7 +193,7 @@ def test_stages_table_names_every_layer_once_per_needle():
     assert {row[3] for row in STAGES} == {
         "ring", "flash kernels", "xla flash", "optimizer", "loss and head",
         "feed-forward", "attention projections", "embed", "experts",
-        "router", "latent attention"}
+        "router", "latent attention", "state space"}
     assert {row[4] for row in STAGES} <= {None, "backward", "update"}
     # a kernel is a kernel wherever it is called from; the rest by scope
     assert layer_of("flash_partials_tile.3",
@@ -217,6 +217,19 @@ def test_stages_table_names_every_layer_once_per_needle():
     assert layer_of("flash_decode_latent.2", step.format("pallas_call")) == (
         "flash kernels", "forward")
     assert layer_of("fusion.8", path.format("router/moe/groups"))[0] == "router"
+    # and a Mamba-2 mixer's: its six scopes by path (its norm sits inside
+    # ssm/in_proj, so nothing of it falls to attn_layers_), its kernel by name
+    for call, scope in (("prefill", "ssm/in_proj/prenorm/mul"),
+                        ("prefill", "ssm/conv/add"),
+                        ("prefill", "ssm/scan/while/body/dot_general"),
+                        ("decode_step", "ssm/step/exp"),
+                        ("decode_step", "ssm/gate_norm/gate_norm/mul"),
+                        ("decode_step", "ssm/out_proj/dot_general")):
+        assert layer_of("fusion.9", f"jit(f)/attn_layers_0.{call}/{scope}")[
+            0] == "state space"
+    assert layer_of("ssm_decode_step.3", step.format("ssm/step/pallas_call")
+                    ) == ("state space", "forward")
+    assert layer_of("ssm_decode_step.3", "")[0] == "state space"
     assert layer_of("fusion.8", step.format("to_out/dot"))[
         0] == "attention projections"
 

@@ -94,6 +94,25 @@ def test_latent_decode_lowers_at_the_published_widths(sessions, capacity):
     assert kernels == ["flash_decode_latent"]
 
 
+@pytest.mark.parametrize("sessions", [4, 1])
+def test_ssm_decode_step_lowers_at_the_published_widths(sessions):
+    """ssm_decode_step at granite-4.0-h-small's widths (128 heads of a 64 x
+    128 float32 state), the timed program's sessions and the check's: the
+    decays in SMEM, the heads' dt x transposed, the state aliased."""
+    from ring_attention_tpu.ops.pallas_ssm import pallas_ssm_decode_step
+
+    lowered = tpu_lower(
+        lambda s, x, b, c, dt, a, d: pallas_ssm_decode_step(
+            s, x, b, c, dt, a, d, interpret=False),
+        sds(sessions, 128, 64, 128, dtype=jnp.float32), sds(sessions, 128, 64),
+        sds(sessions, 128), sds(sessions, 128),
+        sds(sessions, 128, dtype=jnp.float32), sds(128, dtype=jnp.float32),
+        sds(128, dtype=jnp.float32))
+    module = lowered.mlir_module()
+    assert re.findall(r'kernel_name = "(\w+)"', module) == ["ssm_decode_step"]
+    assert "output_operand_aliases" in module or "operand_aliases" in module
+
+
 def test_flash_padding_mask_lowers_at_batch_2():
     """Non-causal attention with a key-padding mask: the mask rides the
     same per-token layout as the segment ids, fwd and bwd."""
