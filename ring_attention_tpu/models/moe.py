@@ -25,6 +25,16 @@ worst case, every pair here, takes more passes and not more memory.  A
 holder that expects more pairs than a pass takes (half of 72 experts held
 and ten a token: 163,840 of a 32,768-token prefill's) takes its tokens a
 block at a time, each block one pass (``_blocks``).
+
+A pass combines by token: each of a token's ``experts_per_token`` slots
+that holds a pair of this pass gathers the pair's row of the second
+product's output, and the token's sum is the selected ``weight x row``s
+in float32.  The rows of that output past the pass's last pair are not
+the product's to define and are never read: a held pair's place is below
+them by construction, so the slots' own ``mine`` is the whole condition,
+and nothing masks the product's output (such a mask read and wrote all
+``PASS_ROWS`` rows a pass, though half of them or fewer hold pairs;
+PERF.md section 6, PR 35).
 """
 
 from __future__ import annotations
@@ -124,7 +134,10 @@ class RoutedFeedForward(nn.Module):
         pairs = chosen.size
         counters = [("tokens_per_expert", counts),
                     ("held_share", n_here / pairs),
-                    ("experts_touched", jnp.sum(counts > 0))]
+                    ("experts_touched", jnp.sum(counts > 0)),
+                    # the rows the combine needs of the products' output:
+                    # one a held pair, of `pairs` slots
+                    ("combine_rows_copied", n_here)]
         if self.expert_groups > 1:  # every holder's pairs, by group
             group = chosen.reshape(pairs) // (
                 self.num_experts // self.expert_groups)
@@ -152,12 +165,12 @@ class RoutedFeedForward(nn.Module):
     def _routed_in_blocks(self, blocks, m, chosen, weights, gate_up, down):
         """``_routed`` a block of tokens at a time.  A pass's combine
         gathers ``experts_per_token`` rows for every token of its call
-        whichever pass holds them, and XLA:TPU gathers rows at a tenth of
-        the memory's rate: with half the experts held and ten a token the
-        whole call in five or six passes spent two thirds of a 32,768-token
-        prefill there, and a seed's routing decided which (PERF.md section
-        6, PR 34).  A block's pairs fit one pass, so every row is gathered
-        once."""
+        whichever pass holds them (XLA's shapes are static: an absent
+        slot copies a row too, some 60 ns a row on a v5e): with half the
+        experts held and ten a token the whole call in five or six passes
+        spent two thirds of a 32,768-token prefill there, and a seed's
+        routing decided which (PERF.md section 6, PR 34 and PR 35).  A
+        block's pairs fit one pass, so every row is gathered once."""
         tokens, d = m.shape
         k, held = self.experts_per_token, self.experts_held
         local = chosen.reshape(-1) - self.first_expert
@@ -206,13 +219,16 @@ class RoutedFeedForward(nn.Module):
                 y = lax.ragged_dot(nn.silu(h[:, :f]) * h[:, f:], down, sizes)
             with jax.named_scope("moe/combine"):
                 # rows past the last pair are not the product's to define
-                y = jnp.where((jnp.arange(rows) < n_here - lo)[:, None], y, 0)
+                # and may hold NaN: a held pair's place is below n_here, so
+                # `mine` is the whole condition, and it selects (a product
+                # with a zero weight would let an absent slot's row through)
                 at = place - lo
                 mine = here & (at >= 0) & (at < rows)
-                w = jnp.where(mine, weights, 0.0)
                 at = jnp.clip(at, 0, rows - 1)
-                return sum(w[:, j, None] * jnp.take(y, at[:, j], axis=0)
-                           for j in range(k))
+                return sum(
+                    jnp.where(mine[:, j, None], weights[:, j, None]
+                              * jnp.take(y, at[:, j], axis=0), 0.0)
+                    for j in range(k))
 
         if passes == 1:
             return one_pass(0)

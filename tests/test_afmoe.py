@@ -6,6 +6,7 @@ non-zero expert bias."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -13,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from ring_attention_tpu.models import (
     ModelConfig,
@@ -184,6 +186,7 @@ def test_counters_give_the_routers_choices(tiny):
     np.testing.assert_array_equal(got, routing["counts"])
     for c, row in zip(layers, got):
         assert float(c["held_share"]) == pytest.approx(row.sum() / 80)
+        assert int(c["combine_rows_copied"]) == row.sum()
         assert int(c["experts_touched"]) == int((row > 0).sum())
 
 
@@ -217,6 +220,14 @@ def routed_layer(held, first, **kw):
         norm_eps=1e-5, **kw)
 
 
+def held_part(p, held, first):
+    """A holder's parameters: the router whole, its own experts' matrices."""
+    part = {k: v for k, v in p.items() if k != "shared"}
+    part["experts_gate_up"] = p["experts_gate_up"][first:first + held]
+    part["experts_down"] = p["experts_down"][first:first + held]
+    return part
+
+
 @pytest.fixture(scope="module")
 def uncut():
     """One routed layer holding all 16 experts, its seeded parameters, an
@@ -248,11 +259,8 @@ def test_four_shares_and_the_shared_expert_once_make_the_layer(uncut):
     pairs = 0
     for share in range(4):
         held = slice(4 * share, 4 * share + 4)
-        part = {k: v for k, v in p.items() if k != "shared"}
-        part["experts_gate_up"] = p["experts_gate_up"][held]
-        part["experts_down"] = p["experts_down"][held]
         out, col = routed_layer(4, 4 * share).apply(
-            {"params": part}, x, mutable=["counters"])
+            {"params": held_part(p, 4, 4 * share)}, x, mutable=["counters"])
         total = total + out.reshape(48, 32)
         got = np.asarray(col["counters"]["tokens_per_expert"])
         np.testing.assert_array_equal(got, counts[held])
@@ -264,9 +272,7 @@ def test_four_shares_and_the_shared_expert_once_make_the_layer(uncut):
 def test_bias_moves_the_selection_and_not_the_weights(uncut):
     _, p, x, m, _, _ = uncut
     held = 5
-    part = {k: v for k, v in p.items() if k != "shared"}
-    part["experts_gate_up"] = p["experts_gate_up"][held:held + 1]
-    part["experts_down"] = p["experts_down"][held:held + 1]
+    part = held_part(p, 1, held)
     part["expert_bias"] = jnp.zeros(16).at[held].set(10.0)
     out, col = routed_layer(1, held).apply(
         {"params": part}, x, mutable=["counters"])
@@ -294,14 +300,93 @@ def test_more_pairs_than_a_pass_takes_more_passes(uncut, monkeypatch,
     and a pass of 40 rows, 192 pairs take five passes and give what one
     pass gives."""
     _, p, x, _, _, _ = uncut
-    part = {k: v for k, v in p.items() if k != "shared"}
-    part["experts_gate_up"] = p["experts_gate_up"][first:first + held]
-    part["experts_down"] = p["experts_down"][first:first + held]
-    layer = routed_layer(held, first)
+    part, layer = held_part(p, held, first), routed_layer(held, first)
     one = layer.apply({"params": part}, x)
     monkeypatch.setattr(moe, "PASS_ROWS", 40)
     many = jax.jit(lambda x: layer.apply({"params": part}, x))(x)
     np.testing.assert_allclose(many, one, atol=ATOL)
+
+
+def poison_the_rows_past_the_last_pair(monkeypatch):
+    """``lax.ragged_dot`` defines the rows of its groups; here it writes NaN
+    into every row past them, which it is free to do."""
+    ragged_dot = lax.ragged_dot
+
+    def poisoned(lhs, rhs, group_sizes, **kw):
+        out = ragged_dot(lhs, rhs, group_sizes, **kw)
+        defined = jnp.arange(out.shape[0]) < group_sizes.sum()
+        return jnp.where(defined[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
+
+
+@pytest.mark.parametrize("held,first", [(16, 0), (4, 8)])
+@pytest.mark.parametrize("how, blocks", [
+    ("one_pass", (1, 1)), ("several_passes", (1, 1)), ("in_blocks", (8, 2))])
+def test_rows_past_the_last_pair_are_never_read(uncut, monkeypatch, how,
+                                                blocks, held, first):
+    """The combine reads a row of the products' output only through a held
+    pair, whose place is below the pass's last pair: whatever the product
+    left in the rows past it (here NaN) reaches no token's sum, in one
+    pass, in five passes of 40 rows, and a block of tokens at a time."""
+    _, p, x, _, _, _ = uncut
+    part, layer = held_part(p, held, first), routed_layer(held, first)
+    want = layer.apply({"params": part}, x)
+    if how != "one_pass":
+        monkeypatch.setattr(moe, "PASS_ROWS", 40)
+    if how == "several_passes":
+        monkeypatch.setattr(RoutedFeedForward, "_blocks", lambda self, n: 1)
+    assert layer._blocks(48) == blocks[held == 4]
+    poison_the_rows_past_the_last_pair(monkeypatch)
+    got = jax.jit(lambda x: layer.apply({"params": part}, x))(x)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=ATOL)
+
+
+@pytest.mark.parametrize("held,first", [(16, 0), (4, 8)])
+@pytest.mark.parametrize("pass_rows", [None, 40])
+def test_combine_rows_copied_counts_the_held_pairs(uncut, monkeypatch, held,
+                                                   first, pass_rows):
+    """``combine_rows_copied`` is the rows the combine needs of the
+    products' output, one a held pair: ``held_share`` x slots, whether the
+    call runs in one pass or a block of tokens at a time; the other
+    counters read as before."""
+    _, p, x, _, _, counts = uncut
+    if pass_rows:
+        monkeypatch.setattr(moe, "PASS_ROWS", pass_rows)
+    _, col = routed_layer(held, first).apply(
+        {"params": held_part(p, held, first)}, x, mutable=["counters"])
+    col = col["counters"]
+    assert set(col) == {"tokens_per_expert", "held_share", "experts_touched",
+                        "combine_rows_copied"}
+    mine = counts[first:first + held]
+    np.testing.assert_array_equal(col["tokens_per_expert"], mine)
+    assert int(col["experts_touched"]) == int((mine > 0).sum())
+    slots = 48 * 4
+    assert col["combine_rows_copied"].dtype == jnp.int32
+    assert int(col["combine_rows_copied"]) == mine.sum()
+    assert int(col["combine_rows_copied"]) == round(
+        float(col["held_share"]) * slots)
+
+
+def test_the_combine_makes_nothing_of_the_products_whole_shape():
+    """A structural guard on a routed layer's lowered text: under
+    ``moe/combine`` no operation (a select, a call to ``where``, a product
+    with a mask) makes an array of the products' whole ``(rows, d)`` shape:
+    ``y`` is only gathered from.  48 tokens, 192 rows, d 32."""
+    layer, x = routed_layer(4, 8), jnp.zeros((2, 24, 32))
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    text = jax.jit(layer.apply).lower(params, x).as_text(debug_info=True)
+    scope = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    combine = [
+        line for line in text.splitlines()
+        if (at := re.search(r"loc\((#loc\d+)\)\s*$", line))
+        and "moe/combine" in scope.get(at.group(1), "")]
+    assert any("call @_take" in line for line in combine)  # the gathers
+    results = [re.findall(r"tensor<[^>]*>", line.rsplit("->", 1)[-1])[-1]
+               for line in combine if "tensor<" in line]
+    assert not [r for r in results if r.startswith("tensor<192x32x")]
+    assert [r for r in results if r.startswith("tensor<48x32x")]
 
 
 STARCODER2_TOY = dict(

@@ -504,12 +504,16 @@ STARCODER2_TOY = dict(
 # the parent commit of PR 32 (c1c81eb), jax 0.9.0, under this suite's
 # conftest (float32 products at the highest precision): a program that lowers to
 # the same text is the same program.  A later PR that changes one of these
-# programs on purpose records the new digest here and says so.
+# programs on purpose records the new digest here and says so.  PR 35 did,
+# for the four ``afmoe.*``: the routed layer's combine lost its mask over the
+# products' output (a select by each slot's ``mine`` stands in the sum) and
+# the layer sows ``combine_rows_copied``; the four ``starcoder2.*`` are the
+# parent's of PR 32 still.
 PARENT_TEXT = {
-    "afmoe.forward": "cf52dc2087084d9b",
-    "afmoe.loss": "b5f3d13ad0239666",
-    "afmoe.prefill": "a568134413c498aa",
-    "afmoe.decode_step": "e5b6632c744c04a5",
+    "afmoe.forward": "629a6614c80f67e3",
+    "afmoe.loss": "f96114b242b4eac2",
+    "afmoe.prefill": "869731c1d354bfe2",
+    "afmoe.decode_step": "fb612a400546567c",
     "starcoder2.forward": "02b8f88897eba8fd",
     "starcoder2.loss": "ec2608c6fb97c0e5",
     "starcoder2.prefill": "a5e0fd7bcc0ed9f4",

@@ -506,12 +506,15 @@ def test_a_mixer_takes_no_mask_and_no_packed_documents(tiny):
 # sha256 (first 16 hex digits) of ``jax.jit(call).lower(...).as_text()`` of
 # the dots toy (tests/test_mla.py TINY) at the parent commit of PR 34
 # (4d43669), jax 0.9.0, under this suite's conftest; the afmoe and starcoder2
-# toys' are in tests/test_mla.py and did not change either
+# toys' are in tests/test_mla.py and did not change either.  Re-recorded on
+# purpose in PR 35, all four: the routed layer's combine lost its mask over
+# the products' output and the layer sows ``combine_rows_copied``
+# (tests/test_mla.py's ``afmoe.*`` with them; its ``starcoder2.*`` stay).
 PARENT_TEXT = {
-    "dots_vlm.forward": "2a24606b5589edd4",
-    "dots_vlm.loss": "2bb20c945f1909c2",
-    "dots_vlm.prefill": "f8c503e405e5fa7a",
-    "dots_vlm.decode_step": "9c09b1b084a7172f",
+    "dots_vlm.forward": "76891bb80d72c746",
+    "dots_vlm.loss": "f6324ef1ed7dc5c2",
+    "dots_vlm.prefill": "3f1c597e269b623c",
+    "dots_vlm.decode_step": "4eb6ca5d976f4028",
 }
 
 
