@@ -8,13 +8,21 @@ reducer and its arguments; ``run.py`` looks the reducer up in
 ``REDUCERS`` and calls it as ``fn(trace, run, **args)``.  A reducer that
 finds nothing to read returns ``None`` and the metric is left out.
 
+A device operation also carries the path the program gave it
+(``jax.named_scope`` and flax module names, ``jit(step)/.../ff_layers_0/...``)
+and the pass JAX wrote into that path, so a metric can be a layer of the
+program: ``scope_time_ms`` with the layer's needles in the metric's own
+file.  There is no table of layers here.
+
 ``python3 benchmarks/reduce.py <file.xplane.pb>`` prints the planes, lines
-and busiest operation names of a trace: look at one by hand before writing
-a metric against it.
+and busiest operation names of a trace, then per chip the scope paths that
+took most time and the host events that were open longest: look at one by
+hand before writing a metric against it.
 """
 
 from __future__ import annotations
 
+import bisect
 import gzip
 import math
 import re
@@ -130,6 +138,7 @@ WORK = {f.__name__: f for f in (
 SPAN_PREFIX = "bench/"
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 _OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
 _SUFFIX = re.compile(r"(\.\d+)+$")
 
 
@@ -141,6 +150,14 @@ def op_name(raw: str) -> str:
     return _SUFFIX.sub("", name)
 
 
+def pass_of(scope: str) -> str:
+    """The pass JAX wrote into a path: ``rematted_computation`` under
+    ``nn.remat``'s backward, ``transpose(jvp(...))`` for the backward."""
+    if "rematted_computation" in scope:
+        return "recompute"
+    return "backward" if "transpose(" in scope else "forward"
+
+
 @dataclass
 class Op:
     name: str
@@ -148,24 +165,34 @@ class Op:
     end: float
     self_s: float  # duration less the operations nested inside it
     leaf: bool = True
+    scope: str = ""  # the instruction's op_name path; "" where it has none
+    pass_: str = "forward"  # forward | recompute | backward, by the path
 
 
 @dataclass
 class Trace:
     devices: list[list[Op]] = field(default_factory=list)  # one per chip
     spans: list[tuple[str, float, float]] = field(default_factory=list)
+    # every host event with a duration, the runtime's and the spans alike
+    host: list[tuple[str, float, float]] = field(default_factory=list)
 
 
-def nest(events: list[tuple[str, float, float]]) -> list[Op]:
-    """(name, start, duration) on one device line -> ``Op``s with self time.
-    A control-flow operation (a ``while``) spans the operations of its
-    body; its own time is what they leave.  Only an operation that lies
-    wholly inside another is nested in it (a nanosecond-long ``copy-start``
-    does not adopt the kernel that starts beside it)."""
+def nest(events: list[tuple]) -> list[Op]:
+    """(name, start, duration[, scope]) on one device line -> ``Op``s with
+    self time.  A control-flow operation (a ``while``) spans the operations
+    of its body; its own time is what they leave.  Only an operation that
+    lies wholly inside another is nested in it (a nanosecond-long
+    ``copy-start`` does not adopt the kernel that starts beside it)."""
     ops: list[Op] = []
     stack: list[Op] = []
-    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
-        op = Op(op_name(name), start, start + dur, dur)
+    short: dict[str, str] = {}  # a step's thousand names repeat every step
+    for name, start, dur, *scope in sorted(events,
+                                           key=lambda e: (e[1], -e[2])):
+        if name not in short:
+            short[name] = op_name(name)
+        op = Op(short[name], start, start + dur, dur)
+        if scope and scope[0]:
+            op.scope, op.pass_ = scope[0], pass_of(scope[0])
         while stack and (stack[-1].end <= start
                          or op.end > stack[-1].end + 1e-9):
             stack.pop()
@@ -177,36 +204,211 @@ def nest(events: list[tuple[str, float, float]]) -> list[Op]:
     return ops
 
 
-def _profile(path: str):
-    """The profile in ``path``; a ``.gz`` (the tests' recorded trace) is
-    read through memory."""
+# ``jax.profiler.ProfileData`` reads planes, lines and events, but not the
+# ``/host:metadata`` plane's ``HloProto``s, which alone hold each
+# instruction's ``op_name`` path.  The wire-format reader below reads just
+# those.  It is a copy of the program's (``utils/profiling.py``), kept here
+# so that the yardstick does not move when the program's reader is edited.
+# Field numbers are the public schema of tsl/profiler/protobuf/xplane.proto
+# and xla/service/hlo.proto; unknown fields are skipped by wire type.
+
+
+def _varint(buf: bytes, i: int) -> tuple[int, int]:
+    if buf[i] < 0x80:  # one byte: most keys, lengths and small ids
+        return buf[i], i + 1
+    r = s = 0
+    while True:
+        b = buf[i]
+        i += 1
+        r |= (b & 0x7F) << s
+        if not b & 0x80:
+            return r, i
+        s += 7
+
+
+def _wire_fields(buf: bytes):
+    """``(field_number, wire_type, value)`` of one message: wire type 0 ->
+    int, 2 -> bytes, 1 / 5 -> the raw 8 / 4 bytes.  Groups do not occur in
+    these protos; an unknown type ends the message rather than guess at
+    its framing."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        fn, wt = key >> 3, key & 7
+        if wt == 0:
+            v, i = _varint(buf, i)
+        elif wt == 2:
+            ln, i = _varint(buf, i)
+            v = buf[i:i + ln]
+            i += ln
+        elif wt in (1, 5):
+            ln = 8 if wt == 1 else 4
+            v = buf[i:i + ln]
+            i += ln
+        else:
+            return
+        yield fn, wt, v
+
+
+def _sub(buf: bytes, field_number: int):
+    return (v for fn, _, v in _wire_fields(buf) if fn == field_number)
+
+
+def _hlo_scopes(hlo_proto: bytes) -> dict[str, str]:
+    """``{instruction name: op_name path}`` of a serialized ``HloProto``.
+
+    HloProto.hlo_module=1 -> HloModuleProto.computations=3 ->
+    HloComputationProto.instructions=2 -> HloInstructionProto.name=1,
+    .metadata=7 -> OpMetadata.op_name=2, .id=35, .operand_ids=36.
+
+    What the compiler inserts itself (a ``copy-start`` that prefetches a
+    weight, a layout ``copy``) has no path, or only its argument's name.
+    It takes the path of the first instruction that uses it, else of its
+    first operand: a weight's prefetch belongs to the layer that
+    multiplies by it."""
+    scopes: dict[int, str] = {}
+    names: dict[int, str] = {}
+    operands: dict[int, list[int]] = {}
+    for module in _sub(hlo_proto, 1):
+        for comp in _sub(module, 3):
+            for instr in _sub(comp, 2):
+                name = scope = ""
+                uid = len(names)
+                ops: list[int] = []
+                for fn, wt, val in _wire_fields(instr):
+                    if fn == 1:
+                        name = val.decode(errors="replace")
+                    elif fn == 7:
+                        scope = next(_sub(val, 2), b"").decode(
+                            errors="replace")
+                    elif fn == 35 and wt == 0:
+                        uid = val
+                    elif fn == 36 and wt == 0:
+                        ops.append(val)
+                    elif fn == 36:  # packed
+                        i = 0
+                        while i < len(val):
+                            v, i = _varint(val, i)
+                            ops.append(v)
+                names[uid], scopes[uid], operands[uid] = name, scope, ops
+    users: dict[int, list[int]] = {}
+    for uid, ops in operands.items():
+        for op in ops:
+            users.setdefault(op, []).append(uid)
+    pathless = [u for u, s in scopes.items() if "/" not in s]
+    for _ in range(4):  # copy-start -> copy-done -> fusion is two rounds
+        for uid in pathless:
+            near = users.get(uid, []) + operands[uid]
+            scopes[uid] = next((scopes[n] for n in near
+                                if "/" in scopes.get(n, "")), scopes[uid])
+        pathless = [u for u in pathless if "/" not in scopes[u]]
+    return {names[u]: s for u, s in scopes.items() if names[u] and s}
+
+
+_PROGRAM_ID = re.compile(r"\((\d+)\)$")
+
+
+def _index_metadata_plane(plane: bytes, by_id: dict, by_module: dict):
+    """The ``/host:metadata`` plane: each event-metadata entry (field 4,
+    its XEventMetadata in field 2) is one profiled program whose
+    ``hlo_proto`` stat holds the serialized HloProto.  Indexed by the
+    entry's id (on a TPU the program's fingerprint, which also ends the
+    name of its ``XLA Modules`` events) and by the module's name less
+    that id."""
+    for entry in _sub(plane, 4):
+        for meta in _sub(entry, 2):
+            meta_id = None
+            module = ""
+            blobs: list[bytes] = []
+            for fn, wt, val in _wire_fields(meta):
+                if fn == 1 and wt == 0:
+                    meta_id = val
+                elif fn == 2:
+                    module = _PROGRAM_ID.sub("", val.decode(errors="replace"))
+                elif fn == 3:  # raw metadata bytes
+                    blobs.append(val)
+                elif fn == 5:  # an XStat whose bytes_value holds the proto
+                    blobs.extend(_sub(val, 6))
+            for scopes in filter(None, map(_hlo_scopes, blobs)):
+                if meta_id is not None:
+                    by_id.setdefault(meta_id, {}).update(scopes)
+                if module:
+                    by_module.setdefault(module, {}).update(scopes)
+
+
+def _xspace(path: str) -> bytes:
+    """The serialized profile in ``path``; a ``.gz`` (the tests' recorded
+    trace) is unpacked in memory."""
+    with (gzip.open if path.endswith(".gz") else open)(path, "rb") as f:
+        return f.read()
+
+
+def _profile(data: bytes):
     from jax.profiler import ProfileData
 
-    if path.endswith(".gz"):
-        with gzip.open(path, "rb") as f:
-            return ProfileData.from_serialized_xspace(f.read())
-    return ProfileData.from_file(path)
+    return ProfileData.from_serialized_xspace(data)
+
+
+def _scoped(lines: dict, by_id: dict, by_module: dict) -> list[tuple]:
+    """One chip's ``XLA Ops`` events as (name, start, duration, scope).  An
+    event carries only its times and the instruction's text; its program
+    is the enclosing event of the ``XLA Modules`` line, whose name ends in
+    the program's id, and the path is that program's for the
+    instruction's name."""
+    def table(program: str) -> dict:
+        found = _PROGRAM_ID.search(program)
+        return (by_id.get(found and int(found.group(1)))
+                or by_module.get(_PROGRAM_ID.sub("", program))
+                or (next(iter(by_module.values()))
+                    if len(by_module) == 1 else {}))
+
+    ran = sorted((int(e.start_ns), int(e.duration_ns), e.name)
+                 for e in lines.get(_MODULES_LINE, ()))
+    starts = [r[0] for r in ran]
+    tables = {"": table("")} | {r[2]: table(r[2]) for r in ran}
+    out = []
+    found: dict[tuple[str, str], str] = {}  # (program, event name) -> path
+    for e in lines.get(_OPS_LINE, ()):
+        name, start = e.name, e.start_ns
+        i = bisect.bisect_right(starts, start) - 1
+        inside = i >= 0 and start < ran[i][0] + ran[i][1]
+        key = (ran[i][2] if inside else "", name)
+        if key not in found:
+            # "%fusion.12 = bf16[...] fusion(...)" -> "fusion.12"
+            instruction = name.lstrip("%").split(" ", 1)[0]
+            found[key] = tables[key[0]].get(instruction, "")
+        out.append((name, start * 1e-9, e.duration_ns * 1e-9, found[key]))
+    return out
 
 
 def load_trace(path: str) -> Trace:
     """Read an ``.xplane.pb`` with jax's own reader: device operations from
-    each chip's ``XLA Ops`` line, and the harness's ``bench/`` spans from
-    the host's threads.  Both are on the profiler's one clock."""
+    each chip's ``XLA Ops`` line, each with its path from the metadata
+    plane; the harness's ``bench/`` spans and every other host event with
+    a duration from the host's threads.  All are on the profiler's one
+    clock.  A capture without a metadata plane reads with every scope
+    empty."""
+    data = _xspace(path)
+    by_id: dict[int, dict[str, str]] = {}
+    by_module: dict[str, dict[str, str]] = {}
+    for plane in _sub(data, 1):
+        if b"metadata" in next(_sub(plane, 2), b""):
+            _index_metadata_plane(plane, by_id, by_module)
     trace = Trace()
-    for plane in _profile(path).planes:
+    for plane in _profile(data).planes:
         if _DEVICE_PLANE.match(plane.name):
-            for line in plane.lines:
-                if line.name == _OPS_LINE:
-                    trace.devices.append(nest([
-                        (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
-                        for e in line.events]))
+            lines = {line.name: line.events for line in plane.lines}
+            if _OPS_LINE in lines:
+                trace.devices.append(nest(_scoped(lines, by_id, by_module)))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
+                    start = e.start_ns * 1e-9
+                    span = (e.name, start, start + e.duration_ns * 1e-9)
                     if e.name.startswith(SPAN_PREFIX):
-                        start = e.start_ns * 1e-9
-                        trace.spans.append(
-                            (e.name, start, start + e.duration_ns * 1e-9))
+                        trace.spans.append(span)
+                    if e.duration_ns > 0:
+                        trace.host.append(span)
     trace.spans.sort(key=lambda s: s[1])
     return trace
 
@@ -380,6 +582,75 @@ def exposed_time_ms(trace, run, name_regex, per=None, spans=None):
     return None if worst is None else 1e3 * worst / _units(run, per)
 
 
+def scope_time_ms(trace, run, scopes=None, exclude_scopes=None,
+                  name_regex=None, exclude_regex=None, passes=None,
+                  per=None, spans=None):
+    """Device time, per unit, of the operations whose path holds one of
+    the substrings ``scopes`` (any path where none is given) and none of
+    ``exclude_scopes``, whose name passes the two expressions as in
+    ``op_time_ms`` (a kernel is named by its own name wherever it is
+    called from, and is never its scope's), and whose pass is one of
+    ``passes``.  Self time, on the chip that spent most."""
+    win = window(trace, spans)
+    if win is None or not trace.devices:
+        return None
+    want = re.compile(name_regex) if name_regex else None
+    skip = re.compile(exclude_regex) if exclude_regex else None
+    memo: dict[tuple[str, str, str], bool] = {}
+
+    def selected(o: Op) -> bool:
+        key = (o.scope, o.name, o.pass_)
+        if key not in memo:
+            memo[key] = bool(
+                (scopes is None or any(s in o.scope for s in scopes))
+                and not any(s in o.scope for s in exclude_scopes or ())
+                and (want is None or want.search(o.name))
+                and not (skip and skip.search(o.name))
+                and (passes is None or o.pass_ in passes))
+        return memo[key]
+
+    per_device = [[o.self_s for o in ops
+                   if win[0] <= o.start < win[1] and selected(o)]
+                  for ops in trace.devices]
+    if not any(per_device):
+        return None
+    return 1e3 * max(sum(d) for d in per_device) / _units(run, per)
+
+
+def _open(trace, lo, hi, needles) -> Intervals:
+    """Where, inside ``[lo, hi]``, a host event whose name holds one of
+    ``needles`` is open."""
+    memo: dict[str, bool] = {}
+    out = []
+    for name, a, b in trace.host:
+        if a < hi and b > lo:
+            if name not in memo:
+                memo[name] = any(n in name for n in needles)
+            if memo[name]:
+                out.append((a, b))
+    return overlap(merged(out), [(lo, hi)])
+
+
+def host_activity_ms(trace, run, activity=None, exclude=None, spans=None,
+                     per=None):
+    """Host time, per unit, inside the window, during which a host event
+    whose name holds one of the substrings ``activity`` is open (any
+    instant where none is given) and none whose name holds one of
+    ``exclude``: the union over the host's threads, on the host's clock
+    alone.  It is not cut to the device's idle time: a capture puts the
+    device's clock and the host's on one axis only to about a millisecond
+    (PERF.md section 6, PR 36), which is more than a launch takes."""
+    win = window(trace, spans)
+    if win is None or not trace.host:
+        return None
+    open_ = [win] if activity is None else _open(trace, *win, activity)
+    if exclude:
+        open_ = overlap(open_, complement(_open(trace, *win, exclude), *win))
+    if not open_:
+        return None
+    return 1e3 * total(open_) / _units(run, per)
+
+
 def percentile(values: list[float], q: float) -> float:
     """Nearest-rank percentile: the smallest value with at least q% of the
     samples at or below it."""
@@ -406,6 +677,7 @@ def mfu(trace, run, work, rate):
 REDUCERS = {f.__name__: f for f in (
     op_time_ms, op_share, roofline_share, idle_share, host_gap_ms,
     busy_time_ms, exposed_time_ms, host_percentile, mfu,
+    scope_time_ms, host_activity_ms,
 )}
 
 
@@ -452,8 +724,11 @@ def breakdown(trace: Trace, top: int = 10) -> dict | None:
 
 
 def describe(path: str, top: int = 25) -> None:
-    """Print what a trace holds: planes, lines, spans, busiest names."""
-    for plane in _profile(path).planes:
+    """Print what a trace holds: planes, lines, spans, busiest names; then
+    what a metric's needles are chosen from: per chip the scope paths that
+    took most self time and the share that has none, and the host events
+    that were open longest inside the ``bench/`` spans."""
+    for plane in _profile(_xspace(path)).planes:
         print(f"plane {plane.name!r}")
         for line in plane.lines:
             events = list(line.events)
@@ -466,6 +741,33 @@ def describe(path: str, top: int = 25) -> None:
                     n.startswith(SPAN_PREFIX) for n in names):
                 for name, secs in head:
                     print(f"    {secs:12.6f} s  {name[:100]}")
+    trace = load_trace(path)
+    win = window(trace)
+    for chip, ops in enumerate(trace.devices):
+        by_scope: dict[tuple[str, str], float] = {}
+        for o in ops:
+            key = ("/".join(o.scope.split("/")[-3:]), o.pass_)
+            by_scope[key] = by_scope.get(key, 0.0) + o.self_s
+        spent = sum(by_scope.values()) or 1.0
+        bare = sum(s for (scope, _), s in by_scope.items() if not scope)
+        print(f"chip {chip}: scope paths by self time (last three "
+              f"components, pass); self time without a scope: "
+              f"{100 * bare / spent:.1f}%")
+        for (scope, pass_), secs in sorted(by_scope.items(),
+                                           key=lambda x: -x[1])[:top]:
+            print(f"    {secs:12.6f} s  {pass_:9s} {scope or '(none)'}")
+    if win is None:
+        return
+    open_: dict[str, Intervals] = {}
+    for name, a, b in trace.host:
+        open_.setdefault(name, []).append((a, b))
+    held = {name: total(overlap(merged(spans), [win]))
+            for name, spans in open_.items()}
+    print(f"host events by the time they are open inside the bench/ spans "
+          f"({win[1] - win[0]:.6f} s; the host's clock, which a capture "
+          f"aligns with the device's only to about a millisecond)")
+    for name, secs in sorted(held.items(), key=lambda x: -x[1])[:15]:
+        print(f"    {secs:12.6f} s  {name[:100]}")
 
 
 if __name__ == "__main__":
