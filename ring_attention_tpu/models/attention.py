@@ -361,13 +361,15 @@ class RingAttention(nn.Module):
         """prenorm + fused qkv -> heads-major (b, h|hk, n, dh), and the
         output gate's logits ``(b, n, h * dh)`` (None without one)."""
         h, kvh, dh = self.heads, self._kv_heads(), self.dim_head
-        normed = self.prenorm(x)
-        qkv = self.to_qkv(normed)
-        q, k, v = jnp.split(qkv, [h * dh, (h + kvh) * dh], axis=-1)
-        b, n, _ = x.shape
-        q = q.reshape(b, n, h, dh).transpose(0, 2, 1, 3)
-        k = k.reshape(b, n, kvh, dh).transpose(0, 2, 1, 3)
-        v = v.reshape(b, n, kvh, dh).transpose(0, 2, 1, 3)
+        with jax.named_scope("attn/qkv_proj"):
+            normed = self.prenorm(x)
+            qkv = self.to_qkv(normed)
+        with jax.named_scope("attn/heads"):
+            q, k, v = jnp.split(qkv, [h * dh, (h + kvh) * dh], axis=-1)
+            b, n, _ = x.shape
+            q = q.reshape(b, n, h, dh).transpose(0, 2, 1, 3)
+            k = k.reshape(b, n, kvh, dh).transpose(0, 2, 1, 3)
+            v = v.reshape(b, n, kvh, dh).transpose(0, 2, 1, 3)
         if self.qk_norm:
             with jax.named_scope("attn/qk_norm"):
                 q, k = self.q_norm(q), self.k_norm(k)
@@ -376,19 +378,22 @@ class RingAttention(nn.Module):
             with jax.named_scope("attn/gate"):
                 gate = self.to_gate(normed)
         if self.softmax_scale is not None:
-            q = q * jnp.asarray(self.softmax_scale * dh ** 0.5, q.dtype)
+            with jax.named_scope("attn/heads"):
+                q = q * jnp.asarray(self.softmax_scale * dh ** 0.5, q.dtype)
         return q, k, v, gate
 
     def _project_out(self, out: jax.Array, gate: jax.Array | None):
         """heads-major attention output ``(b, h, n, dh)`` -> ``(b, n, dim)``,
         through the output gate where the layer has one."""
         b, _, n, _ = out.shape
-        out = out.transpose(0, 2, 1, 3).reshape(b, n, -1)
+        with jax.named_scope("attn/heads"):
+            out = out.transpose(0, 2, 1, 3).reshape(b, n, -1)
         if gate is not None:
             with jax.named_scope("attn/gate"):
                 out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))).astype(out.dtype)
-        return self.to_out(out)
+        with jax.named_scope("attn/out_proj"):
+            return self.to_out(out)
 
     def __call__(
         self,
@@ -473,7 +478,8 @@ class RingAttention(nn.Module):
             mask = None  # ref asserts causal and key-pad mask are exclusive
 
         if ring:
-            out = self._sp_attend(q, k, v, mask, segment_ids)
+            with jax.named_scope("attn/kernel_io"):
+                out = self._sp_attend(q, k, v, mask, segment_ids)
         else:
             out = self._local_attend(q, k, v, mask, segment_ids)
 
@@ -489,8 +495,9 @@ class RingAttention(nn.Module):
         sequence axis), or as they are in a layer without positions."""
         if not self.rotary:
             return q, k
-        freqs = rotary_freqs(positions, self.dim_head, self.rotary_theta)
-        return apply_rotary(q, freqs), apply_rotary(k, freqs)
+        with jax.named_scope("attn/rotary"):
+            freqs = rotary_freqs(positions, self.dim_head, self.rotary_theta)
+            return apply_rotary(q, freqs), apply_rotary(k, freqs)
 
     def _local_attend(self, q, k, v, mask, segment_ids=None):
         n = q.shape[2]
@@ -513,28 +520,32 @@ class RingAttention(nn.Module):
         choice for the forward pass and ``prefill`` off the ring, so the
         two cannot drift; rotary is the caller's (``prefill`` keeps the
         rotated ``k`` for the cache)."""
-        window = self._eff_lookback()
-        doc_ids = (None if doc_starts is None
-                   else _doc_runtime_ids(doc_starts, q.shape[2], q.shape[0]))
-        if self.force_regular_attn and window is None:
-            return default_attention(
-                q, k, v, mask, causal=causal,
-                softclamp_value=self.softclamp_value,
+        # what the call runs around its kernel (the kernel's grouped
+        # layout, lane padding, masks, the backward's row sums) is named;
+        # the kernel goes by its own name and the XLA scan by flash/*
+        with jax.named_scope("attn/kernel_io"):
+            window = self._eff_lookback()
+            doc_ids = (None if doc_starts is None
+                       else _doc_runtime_ids(doc_starts, q.shape[2], q.shape[0]))
+            if self.force_regular_attn and window is None:
+                return default_attention(
+                    q, k, v, mask, causal=causal,
+                    softclamp_value=self.softclamp_value,
+                    segment_ids=segment_ids if doc_ids is None else doc_ids,
+                )
+            if self._use_pallas():
+                return pallas_flash_attention(
+                    q, k, v, mask, causal=causal, window=window,
+                    softclamp_value=self.softclamp_value,
+                    head_chunks=self.pallas_head_chunks,
+                    segment_ids=segment_ids, doc_starts=doc_starts,
+                    compute_dtype=self._compute_dtype(),
+                )
+            return flash_attention(
+                q, k, v, mask, causal=causal, bucket_size=self.bucket_size,
+                window=window, softclamp_value=self.softclamp_value,
                 segment_ids=segment_ids if doc_ids is None else doc_ids,
             )
-        if self._use_pallas():
-            return pallas_flash_attention(
-                q, k, v, mask, causal=causal, window=window,
-                softclamp_value=self.softclamp_value,
-                head_chunks=self.pallas_head_chunks,
-                segment_ids=segment_ids, doc_starts=doc_starts,
-                compute_dtype=self._compute_dtype(),
-            )
-        return flash_attention(
-            q, k, v, mask, causal=causal, bucket_size=self.bucket_size,
-            window=window, softclamp_value=self.softclamp_value,
-            segment_ids=segment_ids if doc_ids is None else doc_ids,
-        )
 
     def _sp_attend(self, q, k, v, mask, segment_ids=None):
         """Dispatch to the configured context-parallel scheme."""
@@ -773,9 +784,10 @@ class RingAttention(nn.Module):
         # lookback layers (RingTransformer.init_cache sizes them so)
         if not ring and self.quantize_cache:
             size = cache_k[0].shape[2]
-            cache_k, cache_v = self._quantized_write(
-                cache_k, cache_v, k, v, pos % size
-            )
+            with jax.named_scope("attn/cache_write"):
+                cache_k, cache_v = self._quantized_write(
+                    cache_k, cache_v, k, v, pos % size
+                )
             kv = QuantizedKV(*cache_k, *cache_v)
             kv_mask = self._buffer_mask(size, pos, x.shape[0])
             if self._use_pallas():
@@ -790,9 +802,10 @@ class RingAttention(nn.Module):
                 )
         elif not ring:
             size = cache_k.shape[2]
-            slot = pos % size
-            cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), slot, axis=2)
-            cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), slot, axis=2)
+            with jax.named_scope("attn/cache_write"):
+                slot = pos % size
+                cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), slot, axis=2)
+                cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), slot, axis=2)
             kv_mask = self._buffer_mask(size, pos, x.shape[0])
             if self._use_pallas():
                 # single-sweep decode kernel: each cache byte read once per
@@ -898,23 +911,24 @@ class RingAttention(nn.Module):
             out = self._ring_prefill_attend(q, k, v)
         else:
             out = self._attend(q, k, v, causal=True)
-        if n > size:
-            # keep the last `size` rows, rolled into ring-buffer slot
-            # order: cache[s] = row at position p ≡ s (mod size)
-            k_rows = jnp.roll(k[:, :, n - size:], n % size, axis=2)
-            v_rows = jnp.roll(v[:, :, n - size:], n % size, axis=2)
-        else:
-            k_rows, v_rows = k, v  # slots [0, n) are the positions [0, n)
-        if self.quantize_cache:
-            # attention over the prompt ran on the exact K/V above; only
-            # the cache (what later decode steps read) is quantized
-            cache_k, cache_v = self._quantized_write(
-                cache_k, cache_v, k_rows, v_rows, 0
-            )
-        else:
-            zeros = (0, 0, 0, 0)
-            cache_k = lax.dynamic_update_slice(cache_k, k_rows.astype(cache_k.dtype), zeros)
-            cache_v = lax.dynamic_update_slice(cache_v, v_rows.astype(cache_v.dtype), zeros)
+        with jax.named_scope("attn/cache_write"):
+            if n > size:
+                # keep the last `size` rows, rolled into ring-buffer slot
+                # order: cache[s] = row at position p ≡ s (mod size)
+                k_rows = jnp.roll(k[:, :, n - size:], n % size, axis=2)
+                v_rows = jnp.roll(v[:, :, n - size:], n % size, axis=2)
+            else:
+                k_rows, v_rows = k, v  # slots [0, n) are the positions [0, n)
+            if self.quantize_cache:
+                # attention over the prompt ran on the exact K/V above; only
+                # the cache (what later decode steps read) is quantized
+                cache_k, cache_v = self._quantized_write(
+                    cache_k, cache_v, k_rows, v_rows, 0
+                )
+            else:
+                zeros = (0, 0, 0, 0)
+                cache_k = lax.dynamic_update_slice(cache_k, k_rows.astype(cache_k.dtype), zeros)
+                cache_v = lax.dynamic_update_slice(cache_v, v_rows.astype(cache_v.dtype), zeros)
 
         if not ring and self._use_pallas():
             # tie the cache write to its layer: free of it, XLA schedules
@@ -1000,9 +1014,10 @@ class RingAttention(nn.Module):
             local_pos = pos % n_local
 
             def write(c, new):
-                return lax.dynamic_update_slice_in_dim(
-                    c, new.astype(c.dtype), local_pos, axis=2
-                )
+                with jax.named_scope("attn/cache_write"):
+                    return lax.dynamic_update_slice_in_dim(
+                        c, new.astype(c.dtype), local_pos, axis=2
+                    )
 
             if quant:
                 kq, ks, vq, vs = quantize_kv_cache(k, v)
@@ -1119,6 +1134,12 @@ class LatentAttention(RingAttention):
             (self.kv_latent_dim, h * (self.qk_nope_dim + self.v_dim)))
         self.to_out = dense(self.dim)
 
+    def _prenormed(self, x):
+        # the pre-norm feeds both bottlenecks: it stands where
+        # RingAttention's does, in front of the first products
+        with jax.named_scope("attn/qkv_proj"):
+            return self.prenorm(x)
+
     def _query_latents(self, normed):
         with jax.named_scope("attn/latent_q"):
             return self.q_latent_norm(self.to_q_latent(normed))
@@ -1161,22 +1182,29 @@ class LatentAttention(RingAttention):
 
     def _rotate_rope(self, x, positions):
         """``x: (..., n, qk_rope_dim)`` rotated to ``positions``."""
-        freqs = rotary_freqs(positions, self.qk_rope_dim, self.rotary_theta,
-                             self.rope_scaling)
-        x = apply_rotary(x, freqs)
-        m = 1.0 if self.rope_scaling is None else (
-            self.rope_scaling.rotation_mscale)
-        return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+        with jax.named_scope("attn/rotary"):
+            freqs = rotary_freqs(positions, self.qk_rope_dim,
+                                 self.rotary_theta, self.rope_scaling)
+            x = apply_rotary(x, freqs)
+            m = 1.0 if self.rope_scaling is None else (
+                self.rope_scaling.rotation_mscale)
+            return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
 
     def _rotate(self, q, k, positions):
         """The rotary columns of ``(b, h, n, dim_head)`` queries and
         expanded keys (the last ``qk_rope_dim``) rotated to ``positions``."""
-        def rotated(x):
-            nope, rope = jnp.split(x, [self.qk_nope_dim], axis=-1)
-            return jnp.concatenate(
-                [nope, self._rotate_rope(rope, positions)], axis=-1)
+        return (self._rotated_columns(q, positions),
+                self._rotated_columns(k, positions))
 
-        return rotated(q), rotated(k)
+    def _rotated_columns(self, x, positions):
+        """``x: (..., n, dim_head)`` with its last ``qk_rope_dim`` columns
+        rotated to ``positions``: the split and the concatenation are head
+        layout, the rotation between them is rotary's."""
+        with jax.named_scope("attn/heads"):
+            nope, rope = jnp.split(x, [self.qk_nope_dim], axis=-1)
+        rope = self._rotate_rope(rope, positions)
+        with jax.named_scope("attn/heads"):
+            return jnp.concatenate([nope, rope], axis=-1)
 
     def _expand(self, c, k_r):
         """Per-head keys ``(b, h, n, dim_head)`` (k_nope | the shared
@@ -1198,12 +1226,14 @@ class LatentAttention(RingAttention):
     def _project_qkv(self, x: jax.Array):
         """The forward's expanded q, k and v; the caller's path rotates
         (``_rotate``), so ``k_r`` is expanded unrotated here."""
-        normed = self.prenorm(x)
+        normed = self._prenormed(x)
         k, v = self._expand(*self._latents(normed))
         return self._queries(self._query_latents(normed)), k, v, None
 
     def _project_out(self, out: jax.Array, gate=None):
-        return super()._project_out(out[..., :self.v_dim], None)
+        with jax.named_scope("attn/heads"):
+            out = out[..., :self.v_dim]
+        return super()._project_out(out, None)
 
     def _off_the_ring(self, call: str) -> None:
         if (self.use_ring and not self.force_regular_attn
@@ -1221,7 +1251,7 @@ class LatentAttention(RingAttention):
         if n > size:
             raise ValueError(
                 f"prefill: prompt ({n}) longer than the latent cache ({size})")
-        normed = self.prenorm(x)
+        normed = self._prenormed(x)
         positions = jnp.arange(n)
         c, k_r = self._latents(normed, positions)
         cache_k, cache_v = self._write(cache_k, cache_v, c, k_r, 0)
@@ -1231,12 +1261,11 @@ class LatentAttention(RingAttention):
         def one_session(args):
             # (n, q_latent_dim), (n, kv_latent_dim), (1, n, qk_rope_dim)
             c_q, c, k_r = (a[None] for a in args)
-            q_n, q_r = jnp.split(self._queries(c_q, w_q),
-                                 [self.qk_nope_dim], axis=-1)
-            q = jnp.concatenate(
-                [q_n, self._rotate_rope(q_r, positions)], axis=-1)
+            q = self._rotated_columns(self._queries(c_q, w_q), positions)
             k, v = self._expand(c, k_r)
-            return self._attend(q, k, v, causal=True)[0, ..., :self.v_dim]
+            out = self._attend(q, k, v, causal=True)
+            with jax.named_scope("attn/heads"):
+                return out[0, ..., :self.v_dim]
 
         # a session at a time: expanded to every head, 16,384 tokens of q,
         # of k and of v are 1 GB each (192 columns pad to 256 lanes)
@@ -1254,7 +1283,7 @@ class LatentAttention(RingAttention):
         self._off_the_ring("decode_step")
         b, h = x.shape[0], self.heads
         dn, dv, dl = self.qk_nope_dim, self.v_dim, self.kv_latent_dim
-        normed = self.prenorm(x)
+        normed = self._prenormed(x)
         position = jnp.reshape(pos, (1,))
         c, k_r = self._latents(normed, position)
         cache_k, cache_v = self._write(cache_k, cache_v, c, k_r, pos)
@@ -1285,4 +1314,5 @@ class LatentAttention(RingAttention):
             out = jnp.einsum("bhl,lhv->bhv", o_lat, w_uv,
                              preferred_element_type=jnp.float32)
             out = out.astype(q_n.dtype).reshape(b, 1, h * dv)
-        return self.to_out(out), cache_k, cache_v
+        with jax.named_scope("attn/out_proj"):
+            return self.to_out(out), cache_k, cache_v

@@ -235,7 +235,9 @@ class RoutedFeedForward(nn.Module):
 
         def body(carry):
             lo, acc = carry
-            return lo + rows, acc + one_pass(lo)
+            y = one_pass(lo)
+            with jax.named_scope("moe/combine_sum"):
+                return lo + rows, acc + y
 
         _, out = lax.while_loop(
             lambda carry: carry[0] < n_here, body,

@@ -421,18 +421,20 @@ class RingTransformer(nn.Module):
         cfg = self._config()
         sandwich = cfg.sandwich_norm
 
-        def scaled(y):  # a sub-block's output on its way to the residual
-            return y if cfg.residual_scale == 1.0 else y * jnp.asarray(
-                cfg.residual_scale, y.dtype)
+        def residual(y, x):  # a sub-block's output joins the stream
+            with jax.named_scope("block/residual"):
+                if cfg.residual_scale != 1.0:
+                    y = y * jnp.asarray(cfg.residual_scale, y.dtype)
+                return y + x
 
         for i, (attn, ff) in enumerate(zip(self.attn_layers, self.ff_layers)):
             a = attend(i, attn, x)
             if not self.is_initializing():
                 self.sow(PROBES, f"attn_out_{i}", a, init_fn=lambda: None,
                          reduce_fn=lambda _, new: new)
-            x = scaled(self.post_attn_norms[i](a) if sandwich else a) + x
+            x = residual(self.post_attn_norms[i](a) if sandwich else a, x)
             f = ff(x)
-            x = scaled(self.post_ff_norms[i](f) if sandwich else f) + x
+            x = residual(self.post_ff_norms[i](f) if sandwich else f, x)
         return self.final_norm(x)
 
     def _cached_blocks(self, x, cache, attend):

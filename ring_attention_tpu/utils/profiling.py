@@ -393,6 +393,9 @@ STAGES: list[tuple[str, str, str, str, str | None]] = [
     ("moe/dispatch", "expert dispatch", _C, "experts", None),
     ("moe/experts", "expert products", _C, "experts", None),
     ("moe/shared", "shared expert", _C, "experts", None),
+    # the add of a pass's rows into the layer's output, ahead of the
+    # needle it holds
+    ("moe/combine_sum", "expert combine sum", _C, "experts", None),
     ("moe/combine", "expert combine", _C, "experts", None),
     # latent attention's own parts (models/attention.py LatentAttention);
     # its decode kernel is a flash kernel by its name (flash_decode_latent)
@@ -410,12 +413,30 @@ STAGES: list[tuple[str, str, str, str, str | None]] = [
     ("ssm/step", "state-space recurrent step", _C, "state space", None),
     ("ssm/gate_norm", "state-space gate and norm", _C, "state space", None),
     ("ssm/out_proj", "state-space output projection", _C, "state space", None),
+    # an attention module's own parts (models/attention.py), behind the
+    # latent rows: a latent layer's positional keys are rotated inside
+    # attn/latent_kv and stay there.  What no scope names falls to the
+    # attn_layers_ row below
     ("attn/qk_norm", "q/k norm", _C, "attention projections", None),
     ("attn/gate", "attention output gate", _C, "attention projections", None),
+    ("attn/qkv_proj", "qkv product", _C, "attention projections", None),
+    ("attn/heads", "head layout", _C, "attention projections", None),
+    ("attn/rotary", "rotary", _C, "attention projections", None),
+    ("attn/cache_write", "cache write", _C, "attention projections", None),
+    ("attn/out_proj", "output product", _C, "attention projections", None),
+    # around the attention call's kernel; the sequence-parallel scopes,
+    # flash/* and the kernels' own names stand ahead of it
+    ("attn/kernel_io", "kernel operand layout", _C, "attention projections",
+     None),
     ("post_attn_norms_", "post-attention norm", _C, "attention projections", None),
     ("post_ff_norms_", "post-feed-forward norm", _C, "feed-forward", None),
     ("ff_layers_", "feed-forward", _C, "feed-forward", None),
     ("attn_layers_", "attention projections", _C, "attention projections", None),
+    # the stack walker's own adds (models/transformer.py _blocks), then
+    # what else runs at its level in no module's scope: the residual
+    # stream's gradient sums (JAX's add_any) and remat's copies
+    ("block/residual", "residual add", _C, "residual", None),
+    ("_blocks/", "stack walker", _C, "residual", None),
     ("embed", "embedding", _C, "embed", None),
 ]
 
@@ -830,16 +851,20 @@ def overlap_report(
 # Every device millisecond to a layer and a pass
 # ----------------------------------------------------------------------
 
-# What the host was doing while the device idled: the first activity that
-# any event open at that instant matches.  A launch still being enqueued
-# keeps the device waiting whatever else the host does: dispatch is first.
+# What the host was doing: the first activity that any event open at an
+# instant matches.  A launch still being enqueued keeps the device waiting
+# whatever else the host does: dispatch is first.  ``np.asarray`` is in
+# neither: it is open for the whole device step it waits for.
 HOST_ACTIVITIES: list[tuple[str, tuple[str, ...]]] = [
     ("dispatch", ("PjitFunction", "LoadedExecutable", "ExecuteHelper",
                   "ExecuteLaunch", "IssueSequencedEvent", "EnqueueProgram",
                   "EnqueueContinuation", "AllocateAndFillTupleIndexTable")),
-    ("fetch", ("np.asarray", "D2H", "TransferFromDevice", "ToLiteral",
-               "Delinearize")),
+    ("fetch", ("D2H", "TransferFromDevice", "ToLiteral", "Delinearize")),
 ]
+# the idle row is split by host activity only where the capture's two
+# clocks are known to sit this close together
+ALIGNED_NS = 100_000
+_LAUNCH, _BLOCKING_FETCH = "PjitFunction(", "np.asarray"
 
 
 def _extent(capture: Capture, window) -> tuple[int, int] | None:
@@ -850,6 +875,82 @@ def _extent(capture: Capture, window) -> tuple[int, int] | None:
         (s, s + d) for n, s, d in [*capture.host, *(p[1:] for p in capture.programs)]
         if n in names]
     return (min(s for s, _ in spans), max(e for _, e in spans)) if spans else None
+
+
+def _activity_of(names) -> str:
+    return next((a for a, needles in HOST_ACTIVITIES
+                 if any(n in name for name in names for n in needles)),
+                "other")
+
+
+def _host_activity(host, lo, hi) -> dict[str, int]:
+    """Nanoseconds of ``[lo, hi]`` by what the host was doing, on the
+    host's clock alone (no device timestamp enters): the union over its
+    threads of the events of each of :data:`HOST_ACTIVITIES`, the first
+    that matches at an instant (what the benchmark's
+    ``entry.dispatch_ms_per_token`` / ``entry.fetch_ms_per_token`` read)."""
+    edges = []
+    for h in host:
+        act = _activity_of([h.name])
+        a, b = max(h.start_ns, lo), min(h.start_ns + h.dur_ns, hi)
+        if act != "other" and a < b:
+            edges += [(a, 1, act), (b, -1, act)]
+    edges.sort()
+    out = {a: 0 for a, _ in HOST_ACTIVITIES}
+    open_ = dict.fromkeys(out, 0)
+    at = lo
+    for t, step, act in edges:
+        now = next((a for a in out if open_[a]), None)
+        if now is not None:
+            out[now] += t - at
+        at = t
+        open_[act] += step
+    return out
+
+
+def _offset_bounds(capture: Capture, lo, hi, chip):
+    """``(lower, upper)`` nanoseconds, either ``None`` where nothing
+    bounds it: what may be added to the device's timestamps to put them
+    on the host's clock without breaking causality.  A program cannot
+    start on the device before the host call that launched it began (the
+    ``i``-th ``PjitFunction(jit(f))`` is the ``i``-th ``jit_f`` on the
+    chip): the largest such lower bound over the window's programs.  A
+    blocking fetch (``np.asarray``) that began after a program's launch
+    and before the next cannot end before that program has: the smallest
+    such upper bound.  A CPU capture's ops are host events: ``(0, 0)``."""
+    if not capture.programs:
+        return 0, 0
+    calls: dict[str, list[tuple[int, int]]] = {}
+    for h in capture.host:
+        if h.name.startswith(_LAUNCH):
+            name = re.sub(r"\W+", "_", h.name[len(_LAUNCH):]).strip("_")
+            calls.setdefault(name, []).append(
+                (h.start_ns, h.start_ns + h.dur_ns))
+    launches = {n: _merge_intervals(spans) for n, spans in calls.items()}
+    every = sorted(s for spans in launches.values() for s, _ in spans)
+    fetches = sorted((h.start_ns, h.start_ns + h.dur_ns)
+                     for h in capture.host
+                     if h.name.startswith(_BLOCKING_FETCH))
+    ran: dict[str, list[tuple[int, int]]] = {}
+    for c, name, start, dur in sorted(capture.programs, key=lambda p: p[2]):
+        if c == chip:
+            ran.setdefault(name, []).append((start, start + dur))
+    lower = upper = None
+    for name, runs in ran.items():
+        if len(launches.get(name, ())) != len(runs):
+            continue  # a capture that began or ended inside a call
+        for (start, end), (called, _) in zip(runs, launches[name]):
+            if not lo <= start < hi:
+                continue
+            lower = called - start if lower is None else max(
+                lower, called - start)
+            nxt = bisect.bisect_right(every, called)
+            until = every[nxt] if nxt < len(every) else float("inf")
+            done = [e for s, e in fetches if called <= s < until]
+            if done:
+                upper = min(done) - end if upper is None else min(
+                    upper, min(done) - end)
+    return lower, upper
 
 
 def _host_split(idle, host, lo, hi) -> dict[tuple[str, str], int]:
@@ -869,11 +970,8 @@ def _host_split(idle, host, lo, hi) -> dict[tuple[str, str], int]:
         if idling and t > at:
             names = [host[j].name for j in sorted(
                 open_, key=lambda j: (host[j].start_ns, -host[j].dur_ns))]
-            activity = next(
-                (a for a, needles in HOST_ACTIVITIES
-                 if any(n in name for name in names for n in needles)),
-                "other")
-            key = (names[-1] if names else "(no host event)", activity)
+            key = (names[-1] if names else "(no host event)",
+                   _activity_of(names))
             out[key] = out.get(key, 0) + t - at
         at = max(at, t)
         if i is None:
@@ -899,9 +997,22 @@ def layer_breakdown(capture: str | Capture, window=None, *, per=None,
     ``other`` rows for ops whose scope matched nothing (counted, never
     dropped; ``other_ops`` names the largest) and an ``idle`` row; their
     ``ms`` sum to ``window_ms`` and ``transfer_ms`` is the collectives'
-    part of a row.  ``idle_host`` splits the idle row by the innermost
-    host event open across it, whoever's it is, and ``idle_activity``
-    sums that into dispatch, fetch and other (:data:`HOST_ACTIVITIES`).
+    part of a row.  ``stages``: the same time by ``{layer, stage, pass}``
+    (:data:`STAGES`' labels) with each stage's five largest instruction
+    names in ``top``.  An operation is one fusion and a fusion has one
+    path, its root's: where XLA fuses two scopes' work into one
+    instruction the pair's time falls to one stage, so neighbouring
+    stages' sum is firm and their split is the compiler's.
+
+    ``host_activity``: the host's dispatch and fetch time a unit, on the
+    host's clock alone.  ``offset_bounds_ms``: the causal bounds on what
+    separates the capture's two clocks (:func:`_offset_bounds`).  Only
+    where they are closer than :data:`ALIGNED_NS` is the idle row cut by
+    host activity: ``idle_host`` splits it by the innermost host event
+    open across it, whoever's it is, and ``idle_activity`` sums that into
+    dispatch, fetch and other (:data:`HOST_ACTIVITIES`); otherwise both
+    are ``None`` (a TPU capture's clocks sit 0.3 to 0.8 ms apart, more
+    than a launch takes).
     """
     if isinstance(capture, str):
         capture = read_capture(capture)
@@ -929,14 +1040,17 @@ def layer_breakdown(capture: str | Capture, window=None, *, per=None,
     units = per or 1
     scale = 1e-6 / units
     cells: dict[tuple[str, str], list[int]] = {}
+    stages: dict[tuple[str, str, str], dict[str, int]] = {}
     unscoped: dict[str, float] = {}
     for (o, _, _), t in zip(clipped[chip], times[chip]):
         cell = cells.setdefault((o.layer, o.pass_), [0, 0, 0])
         cell[0] += t
         cell[1] += 1
         cell[2] += t if o.kind == "transfer" else 0
+        key = _SUFFIX.sub("", o.name)
+        names = stages.setdefault((o.layer, o.stage, o.pass_), {})
+        names[key] = names.get(key, 0) + t
         if o.layer == "other":
-            key = _SUFFIX.sub("", o.name)
             unscoped[key] = unscoped.get(key, 0.0) + t * scale
     cells["idle", ""] = [(hi - lo) - sum(times[chip]), 0, 0]
     rows = [{"layer": layer, "pass": pass_, "ms": ns * scale,
@@ -947,17 +1061,31 @@ def layer_breakdown(capture: str | Capture, window=None, *, per=None,
     spans = _merge_intervals([(a, b) for _, a, b in clipped[chip]])
     edges = [lo, *(t for span in spans for t in span), hi]
     idle = [(a, b) for a, b in zip(edges[::2], edges[1::2]) if a < b]
-    split = _host_split(idle, capture.host, lo, hi)
-    activity: dict[str, float] = {}
-    for (_, act), ns in split.items():
-        activity[act] = activity.get(act, 0.0) + ns * scale
+    bounds = _offset_bounds(capture, lo, hi, chip)
+    activity = idle_host = None
+    if None not in bounds and 0 <= bounds[1] - bounds[0] < ALIGNED_NS:
+        split = _host_split(idle, capture.host, lo, hi)
+        activity = {}
+        for (_, act), ns in split.items():
+            activity[act] = activity.get(act, 0.0) + ns * scale
+        idle_host = sorted(
+            ({"event": e, "activity": a, "ms": ns * scale}
+             for (e, a), ns in split.items()), key=lambda r: -r["ms"])
     return {
         "window_ms": (hi - lo) * scale, "units": units, "chip": chip,
         "busy_ms_by_chip": {c: sum(t) * scale for c, t in sorted(times.items())},
         "rows": rows,
+        "stages": sorted(
+            ({"layer": layer, "stage": stage, "pass": pass_,
+              "ms": sum(names.values()) * scale,
+              "top": [(n, ns * scale) for n, ns in sorted(
+                  names.items(), key=lambda x: -x[1])[:5]]}
+             for (layer, stage, pass_), names in stages.items()),
+            key=lambda r: (r["layer"], -r["ms"])),
         "other_ops": sorted(unscoped.items(), key=lambda x: -x[1])[:10],
-        "idle_host": sorted(
-            ({"event": e, "activity": a, "ms": ns * scale}
-             for (e, a), ns in split.items()), key=lambda r: -r["ms"]),
+        "host_activity": {a: ns * scale for a, ns in _host_activity(
+            capture.host, lo, hi).items()},
+        "offset_bounds_ms": [None if b is None else b * 1e-6 for b in bounds],
+        "idle_host": idle_host,
         "idle_activity": activity,
     }

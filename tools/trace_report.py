@@ -4,8 +4,11 @@
 ``--xprof DIR`` (``benchmarks/run.py --trace 1`` leaves a capture under
 ``benchmarks/.trace/<cell>``, ``utils.profiling.trace`` anywhere) prints,
 per program or per ``--window``, every device millisecond by layer and
-pass with ``other`` and ``idle`` rows, the idle row split by what the host
-was doing (``utils.profiling.layer_breakdown``); then the per-stage table,
+pass with ``other`` and ``idle`` rows, the capture's clock-offset bounds and
+the idle row split by what the host was doing where they allow it, the
+host's own dispatch and fetch time, the same window by stage with each
+stage's largest instructions (``utils.profiling.layer_breakdown``); then
+the whole capture's per-stage table,
 the per-hop compute-vs-transfer timeline and the MEASURED overlap, beside
 the analytic ``hop_overlap_fraction`` of the metrics rows when both exist
 (disagreement beyond ``--overlap-tolerance`` is a FINDING line).
@@ -191,6 +194,11 @@ def diff_report(old_path: str, new_path: str, out: list[str]) -> None:
         )
 
 
+def _pairs(out: list[str], label: str, items) -> None:
+    out.append(f"  {label}: " + ", ".join(
+        f"{n} {ms:.4f}" for n, ms in sorted(items, key=lambda x: -x[1])))
+
+
 def layer_report(capture, out: list[str], windows: list, per, chip) -> None:
     """The layer-and-pass table of each window; with none named, of each
     program that took at least 1% of the device's time, per execution."""
@@ -216,13 +224,33 @@ def layer_report(capture, out: list[str], windows: list, per, chip) -> None:
         out += [f"  {r['layer']:24s} {r['pass']:10s} {r['ms']:11.4f} "
                 f"{100 * r['share']:6.2f}% {r['ops']:8d} "
                 f"{r['transfer_ms']:12.4f}" for r in got["rows"]]
-        for label, pairs in (("other holds", got["other_ops"]),
-                             ("idle by host activity",
-                              got["idle_activity"].items())):
-            out.append(f"  {label}: " + ", ".join(
-                f"{n} {ms:.4f}" for n, ms in sorted(pairs, key=lambda x: -x[1])))
-        out += [f"    {r['ms']:11.4f}  {r['activity']:9s} {r['event']}"
-                for r in got["idle_host"][:12]]
+        _pairs(out, "other holds", got["other_ops"])
+        lower, upper = (
+            "none" if b is None else f"{b:+.4f}" for b in got["offset_bounds_ms"])
+        idle = (f"  idle {got['rows'][-1]['ms']:.4f} ms; host clock = device "
+                f"clock + [{lower}, {upper}] ms")
+        if got["idle_activity"] is None:
+            # the device's clock and the host's are not known to agree to
+            # 0.1 ms: cutting the idle time by host events would print the
+            # offset, not the host
+            out.append(idle + ": no split by host activity")
+        else:
+            out.append(idle)
+            _pairs(out, "idle by host activity", got["idle_activity"].items())
+            out += [f"    {r['ms']:11.4f}  {r['activity']:9s} {r['event']}"
+                    for r in got["idle_host"][:12]]
+        _pairs(out, "host activity on the host's clock",
+               got["host_activity"].items())
+        out += ["", f"  stage and pass: {what}",
+                f"  {'layer':24s} {'stage':34s} {'pass':10s} {'ms':>11s}  "
+                f"largest instructions"]
+        out += [f"  {r['layer']:24s} {r['stage']:34s} {r['pass']:10s} "
+                f"{r['ms']:11.4f}  " + ", ".join(
+                    f"{n} {ms:.3f}" for n, ms in r["top"])
+                for r in got["stages"]]
+        out.append("  (an instruction is one fusion and has one path, its "
+                   "root's: neighbouring stages' sum is firm, their split "
+                   "is the compiler's)")
 
 
 def xprof_report(trace_dir: str, out: list[str], *,
