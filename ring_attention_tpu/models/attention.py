@@ -25,6 +25,7 @@ import warnings
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -50,6 +51,7 @@ from ..ops.rotary import (
     hybrid_positions,
     ring_positions,
     rotary_freqs,
+    turn,
 )
 from ..parallel.hybrid import hybrid_attention
 from ..parallel.mesh import (
@@ -1181,30 +1183,31 @@ class LatentAttention(RingAttention):
                     cache_v, c[:, None].astype(cache_v.dtype), (0, 0, at, 0)))
 
     def _rotate_rope(self, x, positions):
-        """``x: (..., n, qk_rope_dim)`` rotated to ``positions``."""
+        """``x: (..., n, width)`` with its last ``qk_rope_dim`` columns
+        rotated to ``positions``: the shared ``k_r`` alone, or a whole head
+        in one pass, whose ``qk_nope_dim`` columns before them pass as they
+        are (cos 1, sin 0)."""
         with jax.named_scope("attn/rotary"):
             freqs = rotary_freqs(positions, self.qk_rope_dim,
                                  self.rotary_theta, self.rope_scaling)
-            x = apply_rotary(x, freqs)
+            cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+            nope = x.shape[-1] - self.qk_rope_dim
+            if nope:
+                cos = jnp.pad(cos, [(0, 0), (nope, 0)], constant_values=1.0)
+                sin = jnp.pad(sin, [(0, 0), (nope, 0)])
+            x = turn(x, cos, sin, self.qk_rope_dim)
             m = 1.0 if self.rope_scaling is None else (
                 self.rope_scaling.rotation_mscale)
-            return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+            if m == 1.0:
+                return x
+            scale = np.r_[np.ones(nope), np.full(self.qk_rope_dim, m)]  # ra: allow(RA009 a constant of the static widths, never traced)
+            return x * jnp.asarray(scale, x.dtype)
 
     def _rotate(self, q, k, positions):
         """The rotary columns of ``(b, h, n, dim_head)`` queries and
         expanded keys (the last ``qk_rope_dim``) rotated to ``positions``."""
-        return (self._rotated_columns(q, positions),
-                self._rotated_columns(k, positions))
-
-    def _rotated_columns(self, x, positions):
-        """``x: (..., n, dim_head)`` with its last ``qk_rope_dim`` columns
-        rotated to ``positions``: the split and the concatenation are head
-        layout, the rotation between them is rotary's."""
-        with jax.named_scope("attn/heads"):
-            nope, rope = jnp.split(x, [self.qk_nope_dim], axis=-1)
-        rope = self._rotate_rope(rope, positions)
-        with jax.named_scope("attn/heads"):
-            return jnp.concatenate([nope, rope], axis=-1)
+        return (self._rotate_rope(q, positions),
+                self._rotate_rope(k, positions))
 
     def _expand(self, c, k_r):
         """Per-head keys ``(b, h, n, dim_head)`` (k_nope | the shared
@@ -1261,7 +1264,7 @@ class LatentAttention(RingAttention):
         def one_session(args):
             # (n, q_latent_dim), (n, kv_latent_dim), (1, n, qk_rope_dim)
             c_q, c, k_r = (a[None] for a in args)
-            q = self._rotated_columns(self._queries(c_q, w_q), positions)
+            q = self._rotate_rope(self._queries(c_q, w_q), positions)
             k, v = self._expand(c, k_r)
             out = self._attend(q, k, v, causal=True)
             with jax.named_scope("attn/heads"):

@@ -27,7 +27,7 @@ from .pallas_flash import (
 )
 from . import quant
 from .quant import QuantizedBlockKV
-from .rotary import apply_rotary, ring_positions, rotary_freqs, rotate_half
+from .rotary import apply_rotary, ring_positions, rotary_freqs
 from .. import masks as _masks
 
 
@@ -228,5 +228,4 @@ __all__ = [
     "apply_rotary",
     "ring_positions",
     "rotary_freqs",
-    "rotate_half",
 ]

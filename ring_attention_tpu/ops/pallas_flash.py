@@ -1921,7 +1921,7 @@ def _bwd_tile(offs_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         sT = _softclamp(sT, softclamp_value, exp2)
 
     ex = jnp.exp2 if exp2 else jnp.exp
-    pT = ex(sT - jnp.swapaxes(lse_ref[0], 0, 1))
+    pT = ex(sT - lse_ref[0])
     keep = _tile_keep(
         offs_ref, row0, col0, (bk, bq), 1, causal, windowed,
         kvm_ref if masked else None,
@@ -1941,7 +1941,7 @@ def _bwd_tile(offs_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         v_ref[0], dob, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    dsT = pT * (dpT - jnp.swapaxes(delta_ref[0], 0, 1))
+    dsT = pT * (dpT - delta_ref[0])
     if softclamp_value is not None:
         dsT = dsT * _softclamp_grad_factor(sT, softclamp_value, exp2)
     if scale != 1.0:  # folded q̃ makes dsT·q̃ carry the factor exactly,
@@ -2129,19 +2129,24 @@ def pallas_flash_backward(
         exp2=exp2,
     )
 
+    def q_row_map(*args):
+        return q_map(*args)[:2]
+
+    # lse and delta are rows, as the k-major tiles use them: as (bq, 1)
+    # columns they were laid out lane-padded, 128 times their bytes
     in_specs = [
         pl.BlockSpec((1, bq, d), q_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, bq, d), q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq, 1), q_map, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bq, 1), q_map, memory_space=pltpu.VMEM),
+        _token_spec(bq, False, q_row_map),
+        _token_spec(bq, False, q_row_map),
         pl.BlockSpec((1, bk, d), kv_map, memory_space=pltpu.VMEM),
         pl.BlockSpec((1, bk, d), kv_map, memory_space=pltpu.VMEM),
     ]
     inputs = [
         q.reshape(b * h, nq, d),
         do.reshape(b * h, nq, d).astype(q.dtype),
-        lse.reshape(b * h, nq, 1),
-        delta.reshape(b * h, nq, 1),
+        _token_vectors(lse.reshape(b * h, nq), False),
+        _token_vectors(delta.reshape(b * h, nq), False),
         k.reshape(b * hk, nk, d),
         v.reshape(b * hk, nk, d),
     ]

@@ -16,15 +16,28 @@ positions (optionally inside ``shard_map`` using ``lax.axis_index``) and
 feeds them to ``rotary_freqs`` -> ``apply_rotary``.  Rotary math is always
 float32 (the reference forces fp32 via autocast-off, ref
 ``ring_attention.py:128,167``).
+
+The half rotation ``(a, b) -> (-b, a)`` is a product by a fixed signed
+permutation, not a split, a negation and a concatenation: XLA:TPU made
+those separate passes over half-width buffers padded to 128 lanes (8 to 20
+times the rotation's bytes floor, PERF.md s5, PR 37), where a product's
+elementwise epilogue is one pass.  Every column of the permutation holds
+one +-1, so the product is exact: a bfloat16 row goes into the MXU as it
+is, and a wider one at the highest precision.  The backward is the same
+product turning the cotangent by ``-theta`` (``turn``'s ``custom_vjp``), so
+the gradient is the split form's bit for bit in bfloat16 too.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 
 def ring_positions(n_local: int, rank: jax.Array | int, *, striped: bool, world: int) -> jax.Array:
@@ -136,23 +149,57 @@ class YarnScaling:
 
 def rotary_freqs(positions: jax.Array, dim: int, theta: float = 10000.0,
                  scaling: YarnScaling | None = None) -> jax.Array:
-    """Angles ``(n, dim)`` for NeoX-style (half-rotation) rotary embedding;
-    ``scaling`` blends the frequencies as yarn does."""
+    """Angles ``(n, dim)`` for NeoX-style (half-rotation) rotary embedding,
+    the two halves of a row alike; ``scaling`` blends the frequencies as
+    yarn does."""
     if scaling is None:
         inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     else:
         inv_freq = scaling.inv_freq(dim, theta)
-    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.concatenate([freqs, freqs], axis=-1)
+    return positions.astype(jnp.float32)[:, None] * jnp.tile(inv_freq, 2)
 
 
-def rotate_half(x: jax.Array) -> jax.Array:
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([-x2, x1], axis=-1)
+def _half_turn(width: int, d: int, dtype) -> jax.Array:
+    """The ``d x d`` signed permutation that takes a row whose last
+    ``width`` columns are the halves ``(a, b)`` to ``(-b, a)`` there, and
+    every column before them to zero."""
+    r = np.zeros((d, d), np.float32)  # ra: allow(RA009 a constant of the static width, never traced)
+    h = width // 2
+    lo = np.arange(d - width, d - h)  # ra: allow(RA009 a constant of the static width, never traced)
+    r[lo + h, lo] = -1.0
+    r[lo, lo + h] = 1.0
+    return jnp.asarray(r, dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def turn(x: jax.Array, cos: jax.Array, sin: jax.Array, width: int) -> jax.Array:
+    """``x * cos + x @ R * sin`` in float32, cast back to ``x``'s type, where
+    ``R`` turns the last ``width`` columns of a row and takes any before them
+    to zero: tables of 1 and 0 there pass those columns as they are (a
+    latent head's non-positional part)."""
+    turned = jnp.dot(
+        x, _half_turn(width, x.shape[-1], x.dtype),
+        precision=lax.Precision.HIGHEST if x.dtype.itemsize > 2 else None,
+        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + turned * sin).astype(x.dtype)
+
+
+def _turn_fwd(x, cos, sin, width):
+    return turn(x, cos, sin, width), (cos, sin)
+
+
+def _turn_bwd(width, tables, g):
+    """The cotangent turned by ``-theta``: ``R``'s transpose is ``-R`` and
+    a row's two angle halves are alike, so ``g * cos - g @ R * sin`` is the
+    split form's gradient term for term, summed in float32 as it was (under
+    autodiff the two terms would each be rounded to ``g``'s type first)."""
+    cos, sin = tables
+    return turn(g, cos, -sin, width), None, None
+
+
+turn.defvjp(_turn_fwd, _turn_bwd)
 
 
 def apply_rotary(x: jax.Array, freqs: jax.Array) -> jax.Array:
     """Apply rotary embedding.  ``x: (..., n, d)``, ``freqs: (n, d)``."""
-    xf = x.astype(jnp.float32)
-    out = xf * jnp.cos(freqs) + rotate_half(xf) * jnp.sin(freqs)
-    return out.astype(x.dtype)
+    return turn(x, jnp.cos(freqs), jnp.sin(freqs), x.shape[-1])

@@ -291,6 +291,21 @@ def test_train_step_has_the_stage_in_the_pass(train_capture, stage, pass_):  # n
     assert {o.layer for o in hits} == {"attention projections"}
 
 
+@pytest.mark.parametrize("pass_", ["forward", "recompute", "backward"])
+def test_the_permutation_product_is_rotary_in_every_pass(train_capture, pass_):  # noqa: F811
+    """ISSUE 38: the half rotation is a product by a signed permutation
+    (``ops/rotary.py``).  The product, and whatever is fused with it, keeps
+    ``attn/rotary`` in the forward, in remat's recompute and in the
+    backward, so that ``*_rotary_ms`` reads what took rotate_half's place."""
+    ops = [o for o in train_capture.ops
+           if o.pass_ == pass_ and "attn/rotary/" in o.scope]
+    assert ops, f"no operation of the step's {pass_} is inside attn/rotary"
+    assert {(o.layer, o.stage) for o in ops} == {
+        ("attention projections", "rotary")}
+    assert any(o.scope.endswith("attn/rotary/dot_general") for o in ops), {
+        o.scope.rsplit("/", 1)[-1] for o in ops}
+
+
 def test_train_step_leaves_nothing_of_the_model_in_other(train_capture):  # noqa: F811
     """The stack walker's adds, the residual stream's gradient sums and
     remat's copies are the layer ``residual``.  What the CPU step still has

@@ -507,17 +507,20 @@ STARCODER2_TOY = dict(
 # programs on purpose records the new digest here and says so.  PR 35 did,
 # for the four ``afmoe.*``: the routed layer's combine lost its mask over the
 # products' output (a select by each slot's ``mine`` stands in the sum) and
-# the layer sows ``combine_rows_copied``; the four ``starcoder2.*`` are the
-# parent's of PR 32 still.
+# the layer sows ``combine_rows_copied``.  PR 38 did, for all eight: every
+# rotary site turns q and k by a product with a signed permutation
+# (``ops/rotary.py::apply_rotary``) in place of rotate_half's split,
+# negation and concatenation, and the angle table's halves are tiled, not
+# concatenated; the numbers are the same (``tests/test_rotary.py``).
 PARENT_TEXT = {
-    "afmoe.forward": "629a6614c80f67e3",
-    "afmoe.loss": "f96114b242b4eac2",
-    "afmoe.prefill": "869731c1d354bfe2",
-    "afmoe.decode_step": "fb612a400546567c",
-    "starcoder2.forward": "02b8f88897eba8fd",
-    "starcoder2.loss": "ec2608c6fb97c0e5",
-    "starcoder2.prefill": "a5e0fd7bcc0ed9f4",
-    "starcoder2.decode_step": "af45dcfa59608165",
+    "afmoe.forward": "a0071562c4e72b69",
+    "afmoe.loss": "36b8f1fc1c10045a",
+    "afmoe.prefill": "ceeacd2a08fb8a0e",
+    "afmoe.decode_step": "923c1b6c51c51418",
+    "starcoder2.forward": "1fe7a7d1b6964d6d",
+    "starcoder2.loss": "009adadee0165a28",
+    "starcoder2.prefill": "84715156cbd091ba",
+    "starcoder2.decode_step": "3943076a98e7bff6",
 }
 
 

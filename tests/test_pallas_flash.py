@@ -464,6 +464,25 @@ _BWD_CASES = {
 }
 
 
+def test_backward_takes_lse_and_delta_as_rows():
+    """PR 38: the k-major tiles use ``lse`` and ``delta`` as rows, so the
+    launch passes them as ``(b·h, 1, n)`` rows; as ``(b·h, n, 1)`` columns
+    XLA:TPU laid them out lane-padded, 128 times their bytes, live at the
+    train step's memory peak."""
+    from ring_attention_tpu.ops.pallas_flash import pallas_flash_backward
+
+    b, h, n, d = 1, 2, 64, 16
+    x = jnp.zeros((b, h, n, d), jnp.float32)
+    vec = jnp.zeros((b, h, n), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda do, q, k, v, lse, delta: pallas_flash_backward(
+        do, q, k, v, lse, delta, scale=d ** -0.5, block_q=16, block_k=16,
+        interpret=True))(x, x, x, x, vec, vec)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    shapes = [v.aval.shape for v in calls[0].invars]
+    assert shapes.count((b * h, 1, n)) == 2 and (b * h, n, 1) not in shapes
+
+
 @pytest.mark.parametrize("case", sorted(_BWD_CASES))
 def test_one_pass_backward_parity(rng, case):
     """dq, dk, dv of the one-pass kernel against the XLA blockwise
