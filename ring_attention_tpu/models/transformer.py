@@ -836,11 +836,14 @@ class RingTransformer(nn.Module):
         x, cache = self._cached_blocks(
             self._embed(tokens), cache,
             lambda attn, x, k, v: attn.prefill(x, k, v))
-        if self._config().tie_embeddings:
-            # XLA moves the slice through nn.Dense's product by itself and
-            # not through ``attend``'s: (b, n, vocab) logits otherwise
-            x = x[:, -1:]
-        return self._logits(x)[:, -1], cache
+        # the head reads one row a sequence, the last b rows with positions
+        # leading: a slice of the (b, n, d) stream makes XLA:TPU rebuild the
+        # residual sum in that shape from every layer's output, all held to
+        # the last layer; and positions leading, a sequence-sharded stream
+        # gives its last rows without being gathered
+        b, n, d = x.shape
+        x = jnp.swapaxes(x, 0, 1).reshape(n * b, d)[-b:]
+        return self._logits(x), cache
 
     def generate(
         self,

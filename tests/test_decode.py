@@ -6,12 +6,14 @@ through ``decode_step`` against a (ring-sharded) KV cache must reproduce
 the full-sequence causal forward logits at every step.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ring_attention_tpu.models import RingTransformer
+from ring_attention_tpu.models import ModelConfig, RingTransformer
 from ring_attention_tpu.parallel import create_mesh
 
 ATOL = 3e-5
@@ -501,6 +503,33 @@ def test_prefill_lowers_to_declared_kernel(rng, use_pallas):
     assert ("flash/fwd" in text) == (not use_pallas)
     # only the kernel path pins each layer's cache write to its layer
     assert ("optimization_barrier" in jaxpr) == use_pallas
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_prefill_head_reads_the_last_row(rng, tied):
+    """The prefill slices to the last position before the head, whether
+    the head is its own matrix or the embedding's transpose: its lowered
+    text holds no (b, n, vocab) logits, and its logits are the last row of
+    ``__call__``'s."""
+    config = dataclasses.replace(
+        ModelConfig.uniform(num_tokens=VOCAB, dim=32, depth=2, heads=4,
+                            dim_head=8, kv_heads=2, ffn_dim=64),
+        tie_embeddings=tied)
+    model = RingTransformer.from_config(
+        config, mesh=None, use_ring=False, bucket_size=4)
+    tokens = jnp.asarray(rng.integers(0, VOCAB, (2, 10)), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    assert ("to_logits" in params["params"]) == (not tied)
+    cache = model.apply(params, 2, 16, method=RingTransformer.init_cache)
+
+    def prefill(p, t, c):
+        return model.apply(p, t, c, method=RingTransformer.prefill)
+
+    text = jax.jit(prefill).lower(params, tokens, cache).as_text()
+    assert f"2x10x{VOCAB}x" not in text
+    logits, _ = jax.jit(prefill)(params, tokens, cache)
+    np.testing.assert_allclose(
+        logits, model.apply(params, tokens)[:, -1], atol=ATOL)
 
 
 def test_generate_edge_asserts(rng):
